@@ -67,6 +67,7 @@ import time
 from pathlib import Path
 
 from repro.core.sim.engine import UseAfterFree
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import SLOSpec, SLOTracker, TimeSeriesSampler, Tracer, \
     engine_probes
 from repro.runtime.block_pool import BlockPool
@@ -362,6 +363,7 @@ def main(argv=None) -> list:
     ap.add_argument("--save-workloads", default=None,
                     help="directory to save the generated workload traces")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     schemes = tuple(args.schemes) if args.schemes else (
         QUICK_SCHEMES if args.quick else DEFAULT_SCHEMES)

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
+from repro.launch.compile_cache import enable_compile_cache
 
 PEAK = 197e12
 HBM = 819e9
@@ -55,8 +56,8 @@ def bench_linear_scan(B=2, S=2048, H=8, K=64, Vd=64):
 
 def bench_paged(B=8, P=512, page=16, Hkv=8, D=128, max_pages=64):
     q = jnp.ones((B, Hkv * 2, D), jnp.bfloat16)
-    kp = jnp.ones((P, page, Hkv, D), jnp.bfloat16)
-    vp = jnp.ones((P, page, Hkv, D), jnp.bfloat16)
+    kp = jnp.ones((Hkv, P, page, D), jnp.bfloat16)
+    vp = jnp.ones((Hkv, P, page, D), jnp.bfloat16)
     bt = jnp.tile(jnp.arange(max_pages, dtype=jnp.int32)[None], (B, 1))
     lens = jnp.full((B,), page * max_pages, jnp.int32)
     f = jax.jit(lambda q, kp, vp, bt, l: ref.paged_attention_ref(q, kp, vp, bt, l))
@@ -140,6 +141,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="results/kernel_bench.json")
     args = ap.parse_args()
+    enable_compile_cache()
     rows = [bench_flash(), bench_linear_scan(), bench_paged()]
     rows += bench_page_scatter()
     for r in rows:

@@ -25,6 +25,8 @@ def _quiet(fn, *a, **kw):
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     csv = ["name,us_per_call,derived"]
     summaries = []          # (suite, rows, headline) -- printed at the end
 

@@ -91,6 +91,7 @@ import time
 from pathlib import Path
 
 from repro.core.sim.engine import Costs, UseAfterFree
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Tracer
 from repro.runtime.block_pool import BlockPool, OutOfBlocks
 from repro.runtime.reclaim import is_simulated, make_policy
@@ -674,6 +675,7 @@ def main():
                     help="print the per-row latency/stall percentile "
                          "columns as a summary table")
     args = ap.parse_args()
+    enable_compile_cache()
     tracer = Tracer() if args.trace else None
     engines = (args.engines,) if args.engines else None
     chunks = (args.prefill_chunk,) if args.prefill_chunk else (4, 16)

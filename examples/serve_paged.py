@@ -22,7 +22,8 @@ feature).
 
 ``--kv-store paged`` stores K/V physically in the POP-managed block pool
 (runtime/kv_store.py) and decodes through the Pallas paged-attention kernel
-(interpret mode on CPU, compiled on TPU); a prefix-cache hit then installs
+(interpret mode on CPU, compiled Pallas on a TPU; the engine's
+``kernel_impl`` says which); a prefix-cache hit then installs
 NO copies -- the shared pages enter the request's block table directly.
 ``--kv-storage`` picks where those pages live: ``device`` (default --
 resident jax arrays updated in place by donated scatters, zero
@@ -43,6 +44,7 @@ import time
 import jax
 
 from repro.configs.base import ArchConfig, dense_stack
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.obs import Tracer
 from repro.runtime.block_pool import BlockPool
@@ -119,6 +121,7 @@ def main():
                          "(TTFT, per-token, queue wait, ping stall)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = ArchConfig(name="serve-demo", d_model=64, n_heads=4, n_kv_heads=2,
                      d_ff=128, vocab=128, groups=dense_stack(2), remat="none",
                      dtype="float32")
@@ -153,8 +156,11 @@ def main():
     prefix = [1, 9, 42, 7, 3, 5, 2, 8]
     reqs = [eng.submit(prefix + [1 + i % 16], max_new=8)
             for i in range(args.requests)]
+    unfinished = 0
     for i, r in enumerate(reqs):
-        r.done.wait(timeout=300)
+        # a failing engine releases its waiters early: done means all tokens
+        if not (r.done.wait(timeout=300) and len(r.out) >= r.max_new):
+            unfinished += 1
         print(f"req {i}: prompt={r.prompt} -> {r.out}")
     eng.stop()
     pool.evict_prefixes(0)
@@ -217,6 +223,8 @@ def main():
               f"(open in ui.perfetto.dev)")
     if eng.error is not None:
         raise SystemExit(f"ENGINE FAILED: {type(eng.error).__name__}: {eng.error}")
+    if unfinished:
+        raise SystemExit(f"{unfinished} of {len(reqs)} requests did not finish")
     print("use-after-free: none (hard error if one had occurred)")
     print(f"no leaks: {pool.check_no_leaks()}")
 

@@ -7,6 +7,13 @@ physical page of the pool to DMA into VMEM next, so the gather happens in
 the memory pipeline (double-buffered page fetches), not as a materialized
 (B, max_pages*page, ...) tensor in HBM like the XLA reference.
 
+Page layout: ``(Hkv, P, page, D)`` per layer -- head-major, so one grid
+step's block ``(1, 1, page, D)`` has the array's own last two dimensions.
+That is what Mosaic's tiling rule asks of a block (last two dims divisible
+by (8, 128) or equal to the array's), so the kernel lowers at any Hkv,
+page size and head dim; a token-major ``(P, page, Hkv, D)`` pool would need
+a ``(page, 1, D)`` block, which the TPU compiler refuses whenever Hkv > 1.
+
 grid = (B, Hkv, n_pages); pages are the sequential axis with the online
 softmax state (m, l, acc) in VMEM scratch.  Dead table entries (-1) are
 masked and their DMA redirected to page 0.
@@ -34,6 +41,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def paged_impl() -> str:
+    """Kernel mode of the paged path on this process's backend: ``"pallas"``
+    (compiled) on a TPU, ``"interpret"`` (the kernel body run by the Pallas
+    interpreter) anywhere else.  The one place that decides; callers that
+    need the chip check the returned mode instead of trusting it."""
+    return "pallas" if jax.default_backend() == "tpu" else "interpret"
 
 
 def build_block_table(
@@ -84,8 +99,8 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)       # (page, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)       # (page, Dv)
+    k = k_ref[0, 0].astype(jnp.float32)          # (page, D)
+    v = v_ref[0, 0].astype(jnp.float32)          # (page, Dv)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -115,10 +130,11 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("softcap", "scale", "interpret"))
 def paged_attention_pallas(
     q: jnp.ndarray,               # (B, H, D)
-    k_pages: jnp.ndarray,         # (P, page, Hkv, D)
-    v_pages: jnp.ndarray,         # (P, page, Hkv, Dv)
+    k_pages: jnp.ndarray,         # (Hkv, P, page, D)
+    v_pages: jnp.ndarray,         # (Hkv, P, page, Dv)
     block_table: jnp.ndarray,     # (B, max_pages) int32, -1 padded
     lengths: jnp.ndarray,         # (B,) int32
     *,
@@ -127,7 +143,7 @@ def paged_attention_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, H, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    Hkv, P, page, _ = k_pages.shape
     Dv = v_pages.shape[-1]
     G = H // Hkv
     max_pages = block_table.shape[1]
@@ -147,10 +163,10 @@ def paged_attention_pallas(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, D), lambda b, h, p, tbl, lens: (b, h, 0, 0)),
-                pl.BlockSpec((1, page, 1, D),
-                             lambda b, h, p, tbl, lens: (tbl[b, p], 0, h, 0)),
-                pl.BlockSpec((1, page, 1, Dv),
-                             lambda b, h, p, tbl, lens: (tbl[b, p], 0, h, 0)),
+                pl.BlockSpec((1, 1, page, D),
+                             lambda b, h, p, tbl, lens: (h, tbl[b, p], 0, 0)),
+                pl.BlockSpec((1, 1, page, Dv),
+                             lambda b, h, p, tbl, lens: (h, tbl[b, p], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, Dv),
                                    lambda b, h, p, tbl, lens: (b, h, 0, 0)),
@@ -167,14 +183,26 @@ def paged_attention_pallas(
     return out.reshape(B, H, Dv)
 
 
-def _scatter_kernel(blk_ref, slot_ref, vals_ref, pages_ref, out_ref):
-    # grid=(T,): the index maps already steered this block to
-    # pages[blk[t], slot[t]]; the body just lands the token's vector
-    out_ref[0, 0] = vals_ref[0]
+def _scatter_kernel(blk_ref, slot_ref, vals_ref, page_ref, out_ref):
+    # grid=(Hkv, T): the index maps steered this step to the whole
+    # (page, D) tile pages[h, blk[t]].  Tokens bound for one page arrive
+    # back to back, and while the output block index stays put Pallas keeps
+    # the tile in VMEM and skips the refetch -- so copy the page in on the
+    # first token of a run, then overwrite one row per token.
+    t = pl.program_id(1)
+    first = jnp.logical_or(t == 0, blk_ref[jnp.maximum(t - 1, 0)] != blk_ref[t])
+
+    @pl.when(first)
+    def _load():
+        out_ref[...] = page_ref[...]
+
+    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[2:], 0)
+    out_ref[0, 0] = jnp.where(row == slot_ref[t], vals_ref[0, 0],
+                              out_ref[0, 0])
 
 
 def paged_scatter_pallas(
-    pages: jnp.ndarray,           # (P, page, Hkv, D) physical page pool
+    pages: jnp.ndarray,           # (Hkv, P, page, D) physical page pool
     block_idx: jnp.ndarray,       # (T,) destination page per token
     slot_idx: jnp.ndarray,        # (T,) destination slot per token
     vals: jnp.ndarray,            # (T, Hkv, D) token K (or V) vectors
@@ -182,30 +210,41 @@ def paged_scatter_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Token scatter as a Pallas kernel: write T token vectors into the
-    physical page pool at ``pages[block_idx[t], slot_idx[t]]`` -- the write
-    half of the block-table contract :func:`paged_attention_pallas` reads.
+    physical page pool at ``pages[:, block_idx[t], slot_idx[t]]`` -- the
+    write half of the block-table contract :func:`paged_attention_pallas`
+    reads.
 
-    The destination indices are scalar-prefetch operands driving the output
-    BlockSpec's index map (the same trick the gather kernel uses for its
-    page DMA), and ``input_output_aliases`` makes the pool buffer the
-    output buffer: untouched pages are preserved and -- when the caller
+    The destination indices are scalar-prefetch operands driving the
+    BlockSpec index maps (the same trick the gather kernel uses for its
+    page DMA).  Each grid step moves a whole ``(page, D)`` tile, the only
+    block shape over this layout that the TPU tiling rule admits, and
+    rewrites one row of it.  ``input_output_aliases`` makes the pool buffer
+    the output buffer: untouched pages are preserved and -- when the caller
     donates ``pages`` under jit, as the device KV storage does -- the
-    update is genuinely in place, O(tokens) moved instead of O(pool).
+    update is in place, O(tokens * page) moved instead of O(pool).
+
+    Tokens may come in any order.  The kernel keeps a page resident only
+    while consecutive tokens target it -- a page revisited after another
+    would be refetched while its first write may still be in flight -- so
+    the tokens are first stably sorted by page: each page's writes become
+    one run, and of two writes to one slot the later still wins.
     """
     T, Hkv, D = vals.shape
+    page = pages.shape[2]
+    order = jnp.argsort(block_idx, stable=True)
+    block_idx, slot_idx, vals = block_idx[order], slot_idx[order], vals[order]
     return pl.pallas_call(
         _scatter_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,        # block_idx + slot_idx
-            grid=(T,),
+            grid=(Hkv, T),
             in_specs=[
-                pl.BlockSpec((1, Hkv, D), lambda t, blk, slot: (t, 0, 0)),
-                pl.BlockSpec((1, 1, Hkv, D),
-                             lambda t, blk, slot: (blk[t], slot[t], 0, 0)),
+                pl.BlockSpec((1, 1, 1, D), lambda h, t, blk, slot: (t, h, 0, 0)),
+                pl.BlockSpec((1, 1, page, D),
+                             lambda h, t, blk, slot: (h, blk[t], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, Hkv, D),
-                                   lambda t, blk, slot: (blk[t], slot[t],
-                                                         0, 0)),
+            out_specs=pl.BlockSpec((1, 1, page, D),
+                                   lambda h, t, blk, slot: (h, blk[t], 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
         # flattened input index 3 = pages (after the 2 prefetch operands
@@ -213,4 +252,4 @@ def paged_scatter_pallas(
         input_output_aliases={3: 0},
         interpret=interpret,
     )(block_idx.astype(jnp.int32), slot_idx.astype(jnp.int32),
-      vals.astype(pages.dtype), pages)
+      vals.astype(pages.dtype)[:, :, None, :], pages)

@@ -8,6 +8,13 @@ initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int):
+    # the repo's specs are GSPMD hints (with_sharding_constraint, jit
+    # outside a mesh context): that is Auto axes, not make_mesh's default
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(shape=None, axes=("data", "model")):
@@ -26,4 +33,4 @@ def make_host_mesh(shape=None, axes=("data", "model")):
     n = len(jax.devices())
     if shape is None:
         shape = (n, 1)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))

@@ -15,6 +15,7 @@ import time
 import jax
 
 from repro.configs.registry import ARCHS, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.runtime.block_pool import BlockPool
 from repro.serve.engine import ServeEngine
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=8)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))
     pool = BlockPool(256, n_engines=1, reclaim_threshold=8)
@@ -37,13 +39,21 @@ def main():
     t0 = time.time()
     reqs = [eng.submit([rng.randrange(1, cfg.vocab) for _ in range(4)],
                        max_new=args.max_new) for _ in range(args.requests)]
-    done = sum(r.done.wait(timeout=600) for r in reqs)
+    # a request counts as done only with all of its tokens: a failing
+    # engine releases its waiters early
+    done = sum(r.done.wait(timeout=600) and len(r.out) >= r.max_new
+               for r in reqs)
     eng.stop()
     s = pool.stats
     print(f"[launch.serve] {cfg.name}: {done}/{len(reqs)} requests in "
           f"{time.time()-t0:.1f}s | pool freed={s.freed} "
           f"epoch_reclaims={s.epoch_reclaims} pings={s.pings} "
           f"no_leaks={pool.check_no_leaks()}")
+    if eng.error is not None:
+        raise SystemExit(f"ENGINE FAILED: {type(eng.error).__name__}: "
+                         f"{eng.error}")
+    if done < len(reqs):
+        raise SystemExit(f"{len(reqs) - done} requests did not finish")
 
 
 if __name__ == "__main__":

@@ -50,7 +50,7 @@ def _flash(q, k, v, *, cfg, causal, window, softcap_v, scale):
         return kops.flash_attention(q, k, v, causal=causal, window=window,
                                     softcap=softcap_v, scale=scale)
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S = q.shape[0], q.shape[1]
@@ -72,7 +72,7 @@ def _flash(q, k, v, *, cfg, causal, window, softcap_v, scale):
         body, mesh=mesh,
         in_specs=(P(bspec, "model"), P(bspec), P(bspec)),
         out_specs=P(bspec, "model"),
-        check_rep=False)(qp, k, v)
+        check_vma=False)(qp, k, v)
     return out[:, :S]
 
 

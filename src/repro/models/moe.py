@@ -73,7 +73,7 @@ def apply_moe_ep(p, x, *, cfg, mesh):
     local experts, and all-to-alls the outputs home -- NO full-activation
     all-reduce (the GSPMD-derived path moved 17.9 GB/layer on deepseek-v3).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -117,7 +117,7 @@ def apply_moe_ep(p, x, *, cfg, mesh):
         in_specs=(P(bspec, "model", None), P(), P("model"), P("model"),
                   P("model"), shared_spec),
         out_specs=(P(bspec, "model", None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"].astype(jnp.float32), p["wi_gate"], p["wi_up"], p["wo"],
       shared_p)
     return out, aux

@@ -20,7 +20,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _quantize(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -84,7 +84,7 @@ def compressed_psum(x: jnp.ndarray, mesh: Mesh, axis: str = "data"):
     flat = jnp.pad(flat, (0, pad))
 
     fn = shard_map(functools.partial(ring_allreduce_int8, axis=axis),
-                   mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+                   mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
     out = fn(flat)
     return out[: x.size].reshape(x.shape)
 

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def pipeline_forward(
@@ -77,7 +77,7 @@ def pipeline_forward(
         per_stage, mesh=mesh,
         in_specs=(P(stage_axis), P()),      # params split by stage; x replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)
 

@@ -6,7 +6,8 @@ allocation, ownership, reader sessions, and (through the pluggable
 retired block may be recycled.  :class:`PagedKVStore` owns the block
 *contents*: one physical K page and one V page per (layer, block id), laid
 out exactly as ``kernels/paged_attention.py`` consumes them --
-``(num_blocks, page, Hkv, hd)`` per layer -- so a decode step gathers
+``(Hkv, num_blocks, page, hd)`` per layer, head-major so the kernel's
+``(page, hd)`` tile is a whole trailing block -- so a decode step gathers
 shared prefix pages physically through the block table instead of
 replaying a per-request dense cache.
 
@@ -108,7 +109,7 @@ class _HostPages:
     kind = "host"
 
     def __init__(self, L, num_blocks, page, Hkv, hd, dtype):
-        self.k = np.zeros((L, num_blocks, page, Hkv, hd), dtype)
+        self.k = np.zeros((L, Hkv, num_blocks, page, hd), dtype)
         self.v = np.zeros_like(self.k)
         self.bytes_h2d = 0
         self.bytes_d2h = 0
@@ -126,17 +127,18 @@ class _HostPages:
         k, v = np.asarray(k), np.asarray(v)
         blk = np.asarray(blk, np.int64)
         slot = np.asarray(slot, np.int64)
+        # token-major (.., T, Hkv, hd) values -> the head-major pages
         if layer is None:
-            self.k[:, blk, slot] = k
-            self.v[:, blk, slot] = v
+            self.k[:, :, blk, slot] = np.swapaxes(k, 1, 2)
+            self.v[:, :, blk, slot] = np.swapaxes(v, 1, 2)
         else:
-            self.k[layer, blk, slot] = k
-            self.v[layer, blk, slot] = v
+            self.k[layer][:, blk, slot] = np.swapaxes(k, 0, 1)
+            self.v[layer][:, blk, slot] = np.swapaxes(v, 0, 1)
 
     def fill(self, blocks, value: float) -> None:
         bl = list(blocks)
-        self.k[:, bl] = value
-        self.v[:, bl] = value
+        self.k[:, :, bl] = value
+        self.v[:, :, bl] = value
 
     def layer(self, li):
         import jax.numpy as jnp
@@ -163,14 +165,16 @@ def _device_fns():
     aliases the incoming pool buffer into the output, so a scatter/fill is
     a true in-place update of the resident pages, not an O(pool) copy."""
     import jax
+    import jax.numpy as jnp
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def scatter(pages, blk, slot, vals):
-        return pages.at[blk, slot].set(vals)
+        # vals (T, Hkv, hd) -> pages[:, blk[t], slot[t]] (Hkv, T, hd)
+        return pages.at[:, blk, slot].set(jnp.swapaxes(vals, 0, 1))
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def fill(pages, blk, value):
-        return pages.at[blk].set(value)
+        return pages.at[:, blk].set(value)
 
     return scatter, fill
 
@@ -179,9 +183,9 @@ def _device_fns():
 def _pallas_scatter_fn():
     import jax
 
-    from repro.kernels.paged_attention import paged_scatter_pallas
+    from repro.kernels.paged_attention import paged_impl, paged_scatter_pallas
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = paged_impl() == "interpret"
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def scatter(pages, blk, slot, vals):
@@ -216,9 +220,9 @@ class _DevicePages:
         self.L = L
         self.dtype = dtype
         self.scatter_impl = scatter_impl
-        self.k = [jnp.zeros((num_blocks, page, Hkv, hd), dtype)
+        self.k = [jnp.zeros((Hkv, num_blocks, page, hd), dtype)
                   for _ in range(L)]
-        self.v = [jnp.zeros((num_blocks, page, Hkv, hd), dtype)
+        self.v = [jnp.zeros((Hkv, num_blocks, page, hd), dtype)
                   for _ in range(L)]
         self.bytes_h2d = 0
         self.bytes_d2h = 0
@@ -486,7 +490,7 @@ class PagedKVStore:
                                  min_pages=min_pages)
 
     def layer_pages(self, layer: int):
-        """The (num_blocks, page, Hkv, hd) K and V page arrays of one
+        """The (Hkv, num_blocks, page, hd) K and V page arrays of one
         layer, as jax arrays ready for the kernel.  Host storage uploads
         the layer (and meters it as ``bytes_h2d``); device storage returns
         the resident arrays -- zero bytes moved."""

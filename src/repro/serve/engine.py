@@ -152,9 +152,18 @@ class ServeEngine:
         # registered as a pool block listener so frees poison pages and
         # (re)allocations clear them -- under whichever SMR policy decides
         self.kv_store: Optional[PagedKVStore] = None
+        #: the paged-attention kernel mode every worker runs ("pallas" |
+        #: "interpret" | "xla"; None on the dense path) -- what a caller
+        #: checks to know the compiled kernel is really in use
+        self.kernel_impl: Optional[str] = None
         if kv_store == "paged":
+            from repro.kernels.paged_attention import paged_impl
             from repro.serve.paged_model import check_paged_support
             check_paged_support(cfg)
+            self.kernel_impl = kernel_impl or paged_impl()
+            if self.kernel_impl not in ("pallas", "interpret", "xla"):
+                raise ValueError(f"kernel_impl must be 'pallas', 'interpret' "
+                                 f"or 'xla', got {self.kernel_impl!r}")
             self.kv_store = PagedKVStore(cfg, pool.num_blocks, page_size,
                                          storage=kv_storage)
             pool.add_block_listener(self.kv_store)
@@ -174,7 +183,8 @@ class ServeEngine:
             EngineWorker(i, cfg, params, pool, self._decode,
                          max_batch=max_batch, page_size=page_size,
                          max_seq=max_seq, prefix_cache=prefix_cache,
-                         kv_store=self.kv_store, kernel_impl=kernel_impl,
+                         kv_store=self.kv_store,
+                         kernel_impl=self.kernel_impl,
                          evict_policy=evict_policy,
                          prefill_chunk=prefill_chunk,
                          tracer=trace, metrics=self.metrics,
@@ -186,7 +196,8 @@ class ServeEngine:
             PrefillWorker(n_engines + j, cfg, params, pool, self._decode,
                           page_size=page_size, max_seq=max_seq,
                           prefix_cache=prefix_cache, kv_store=self.kv_store,
-                          kernel_impl=kernel_impl, evict_policy=evict_policy,
+                          kernel_impl=self.kernel_impl,
+                          evict_policy=evict_policy,
                           prefill_chunk=prefill_chunk,
                           tracer=trace, metrics=self.metrics)
             for j in range(prefill_workers)]

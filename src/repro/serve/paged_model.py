@@ -19,9 +19,12 @@ clear error at engine construction, not silent wrong math.
 
 The per-layer loop runs at host level (a page-store write sits between the
 projection math and the kernel call), so this is NOT one jitted function;
-the projection/MLP pieces are small jnp ops and the kernel runs compiled on
-TPU or in interpret mode on CPU.  The data plane, however, is storage-aware
-end to end: the new K/V stay jax arrays from projection to
+the projection and MLP halves of a layer are jitted programs shared by all
+layers, and the kernel runs in the mode each entry point's required
+``impl`` names (``kernels.paged_attention.paged_impl()`` gives compiled
+Pallas on a TPU and interpret mode elsewhere; there is no default).  The
+data plane, however, is storage-aware end to end: the new K/V stay jax
+arrays from projection to
 :meth:`PagedKVStore.append_tokens`/``write_prefill`` (under device storage
 that is a donated in-place scatter with ZERO host traffic), and
 ``layer_pages`` hands the kernel the store's resident arrays -- no
@@ -33,6 +36,7 @@ can never invalidate the pages mid-dispatch.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, Sequence, Tuple
 
@@ -47,7 +51,7 @@ from repro.models.model import apply_model
 from repro.runtime.kv_store import PagedKVStore, kv_layer_order
 
 __all__ = ["check_paged_support", "prefill_kv", "prefill_kv_chunked",
-           "prefill_chunk_step", "paged_decode_step", "paged_impl"]
+           "prefill_chunk_step", "paged_decode_step"]
 
 
 def check_paged_support(cfg: ArchConfig) -> None:
@@ -74,18 +78,6 @@ def check_paged_support(cfg: ArchConfig) -> None:
         raise ValueError(
             "config not supported by the paged KV path (use kv_store="
             "'dense'): " + "; ".join(problems))
-
-
-def paged_impl() -> str:
-    """Kernel implementation for this host: compiled Pallas on TPU,
-    interpret mode (kernel body executed on CPU) everywhere else."""
-    return "pallas" if jax.default_backend() == "tpu" else "interpret"
-
-
-def _layer_params(params, gi: int, pi: int, rep: int):
-    """Slice one physical layer's weights out of the stacked group params."""
-    gp = params["groups"][f"g{gi}"][f"p{pi}"]
-    return jax.tree.map(lambda a: a[rep], gp)
 
 
 # ----------------------------------------------------------------------------
@@ -120,6 +112,73 @@ def prefill_kv(params, cfg: ArchConfig,
 # ----------------------------------------------------------------------------
 
 
+# The layer math around the page write and the kernel call, jitted per
+# half-layer.  ``gp`` is a group's stacked weights and ``rep`` a traced
+# layer index, so one program serves every layer and the layer's weights
+# are sliced inside it: an eager ``a[rep]`` would copy each layer's weights
+# per call, and a host loop running ahead of the device would hold many
+# such copies at once.
+
+
+def _layer(gp, rep):
+    return jax.tree.map(lambda a: a[rep], gp)
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _embed(embed, toks, *, cfg: ArchConfig):
+    x = jnp.take(embed, toks[:, None], axis=0).astype(cfg.dtype)  # (B,1,D)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
+    return x
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _qkv(gp, rep, x, positions, *, cfg: ArchConfig):
+    """norm -> q/k/v projections -> qk-norm -> rope, for one row per
+    batch entry: ``(q (B,H,hd) f32, k (B,Hkv,hd), v (B,Hkv,hd))``."""
+    lp = _layer(gp, rep)
+    h = rms_norm(x, lp["norm1"], cfg.norm_eps)
+    ap = lp["attn"]
+    q = jnp.einsum("bsd,dhe->bshe", h, ap["wq"])
+    k = jnp.einsum("bsd,dhe->bshe", h, ap["wk"])
+    v = jnp.einsum("bsd,dhe->bshe", h, ap["wv"])
+    if cfg.qk_norm:
+        q = rms_norm(q, ap["q_scale"], cfg.norm_eps)
+        k = rms_norm(k, ap["k_scale"], cfg.norm_eps)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_pct)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_pct)
+    return q[:, 0].astype(jnp.float32), k[:, 0], v[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _out_mlp(gp, rep, x, attn, *, cfg: ArchConfig):
+    """Output projection + residual, then the MLP block + residual."""
+    lp = _layer(gp, rep)
+    B, H, hd = attn.shape
+    o = jnp.einsum("bshe,hed->bsd",
+                   attn.reshape(B, 1, H, hd).astype(cfg.dtype),
+                   lp["attn"]["wo"])
+    if cfg.post_norms:
+        o = rms_norm(o, lp["post_norm1"], cfg.norm_eps)
+    x = x + o
+    h = rms_norm(x, lp["norm2"], cfg.norm_eps)
+    o = L.mlp_apply(lp["mlp"], h, cfg.act)
+    if cfg.post_norms:
+        o = rms_norm(o, lp["post_norm2"], cfg.norm_eps)
+    return x + o
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _logits(final_norm, head, x, *, cfg: ArchConfig):
+    x = rms_norm(x, final_norm, cfg.norm_eps)
+    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
+    logits = L.softcap(logits, cfg.logit_softcap)
+    if cfg.vocab_padded != cfg.vocab:
+        pad_mask = jnp.arange(cfg.vocab_padded) < cfg.vocab
+        logits = jnp.where(pad_mask, logits, -1e30)
+    return logits[:, 0]
+
+
 def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
                    blocks, lens, tokens, *, impl: str,
                    write_layer) -> jnp.ndarray:
@@ -137,35 +196,18 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
     """
     from repro.kernels import ops as kops
 
-    B = len(blocks)
-    dt = jnp.dtype(cfg.dtype)
     lens_np = np.asarray(lens, np.int64)
     table, _ = store.gather_table(blocks, [n + 1 for n in lens_np])
     att_lens = jnp.asarray(lens_np + 1, jnp.int32)
     positions = jnp.asarray(lens_np, jnp.int32)[:, None]     # (B,1)
-
-    toks = jnp.asarray(list(tokens), jnp.int32)[:, None]       # (B,1)
-    x = jnp.take(params["embed"], toks, axis=0).astype(dt)     # (B,1,D)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
-
-    H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
+    x = _embed(params["embed"], jnp.asarray(list(tokens), jnp.int32),
+               cfg=cfg)                                      # (B,1,D)
     scale = (1.0 / math.sqrt(cfg.attn_scale) if cfg.attn_scale
-             else 1.0 / math.sqrt(hd))
+             else 1.0 / math.sqrt(cfg.head_dim_))
 
     for li, (gi, pi, rep) in enumerate(store.layer_order):
-        lp = _layer_params(params, gi, pi, rep)
-        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
-        ap = lp["attn"]
-        q = jnp.einsum("bsd,dhe->bshe", h, ap["wq"])
-        k = jnp.einsum("bsd,dhe->bshe", h, ap["wk"])
-        v = jnp.einsum("bsd,dhe->bshe", h, ap["wv"])
-        if cfg.qk_norm:
-            q = rms_norm(q, ap["q_scale"], cfg.norm_eps)
-            k = rms_norm(k, ap["k_scale"], cfg.norm_eps)
-        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_pct)
-        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_pct)
-
+        gp = params["groups"][f"g{gi}"][f"p{pi}"]
+        q, k, v = _qkv(gp, rep, x, positions, cfg=cfg)
         # physical write: every row's K/V lands in its page BEFORE the
         # gather, so each new position attends to itself (and, in a prefill
         # chunk, to its chunk-mates) exactly like the dense path -- model
@@ -175,34 +217,17 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
         # concurrent writer's buffer donation from invalidating the pages
         # between fetch and kernel dispatch.
         with store.write_guard():
-            write_layer(li, k[:, 0], v[:, 0])                # (B, Hkv, hd)
+            write_layer(li, k, v)                            # (B, Hkv, hd)
             k_pages, v_pages = store.layer_pages(li)
             out = kops.paged_attention(
-                q[:, 0].astype(jnp.float32),                 # (B, H, hd)
-                k_pages, v_pages, table, att_lens,
+                q, k_pages, v_pages, table, att_lens,        # q (B, H, hd)
                 softcap=cfg.attn_softcap, scale=scale, impl=impl)
-        out = out.reshape(B, 1, H, hd).astype(dt)
-        o = jnp.einsum("bshe,hed->bsd", out, ap["wo"])
-        if cfg.post_norms:
-            o = rms_norm(o, lp["post_norm1"], cfg.norm_eps)
-        x = x + o
+        x = _out_mlp(gp, rep, x, out, cfg=cfg)
 
-        h = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        o = L.mlp_apply(lp["mlp"], h, cfg.act)
-        if cfg.post_norms:
-            o = rms_norm(o, lp["post_norm2"], cfg.norm_eps)
-        x = x + o
-
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head")
     if head is None:
         head = params["embed"].T
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(dt))
-    logits = L.softcap(logits, cfg.logit_softcap)
-    if cfg.vocab_padded != cfg.vocab:
-        pad_mask = jnp.arange(cfg.vocab_padded) < cfg.vocab
-        logits = jnp.where(pad_mask, logits, -1e30)
-    return logits[:, 0]
+    return _logits(params["final_norm"], head, x, cfg=cfg)
 
 
 # ----------------------------------------------------------------------------
@@ -218,7 +243,7 @@ def paged_decode_step(
     lens: Sequence[int],                 # tokens already stored per request
     last_tokens: Sequence[int],          # token fed this step, per request
     *,
-    impl: str = "interpret",
+    impl: str,
 ) -> jnp.ndarray:
     """One batched decode step for a ragged batch of requests.
 
@@ -254,7 +279,7 @@ def prefill_chunk_step(
     tokens: Sequence[int],               # the chunk's prompt tokens
     start: int,                          # sequence position of tokens[0]
     *,
-    impl: str = "interpret",
+    impl: str,
 ) -> jnp.ndarray:
     """One chunked-prefill forward: the chunk's positions become batch ROWS
     over one shared block table (the ROADMAP's "q block x page gather").
@@ -287,7 +312,7 @@ def prefill_kv_chunked(
     chunk: int,
     *,
     start: int = 0,
-    impl: str = "interpret",
+    impl: str,
 ):
     """Chunked paged prefill of ``prompt[start:]``: a generator issuing one
     batched forward per ``chunk`` tokens and yielding ``(end, logits)``

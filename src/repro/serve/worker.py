@@ -187,8 +187,7 @@ class _PoolActor:
         # per-request caches (None = dense, the historical path)
         self.kv_store = kv_store
         if kv_store is not None and kernel_impl is None:
-            from repro.serve.paged_model import paged_impl
-            kernel_impl = paged_impl()
+            raise ValueError("a paged worker needs an explicit kernel_impl")
         self.kernel_impl = kernel_impl
         self._stop = threading.Event()
         self.prefill_tokens = 0

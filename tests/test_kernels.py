@@ -118,8 +118,8 @@ PAGED_CASES = [
 def test_paged_attention_kernel_vs_oracle(case, dtype):
     B, H, Hkv, D, P, page, max_pages = case
     q = _rand((B, H, D), dtype)
-    k_pages = _rand((P, page, Hkv, D), dtype)
-    v_pages = _rand((P, page, Hkv, D), dtype)
+    k_pages = _rand((Hkv, P, page, D), dtype)
+    v_pages = _rand((Hkv, P, page, D), dtype)
     # build random block tables + lengths
     lengths = jnp.asarray(RNG.integers(1, page * max_pages, (B,)), jnp.int32)
     table = np.full((B, max_pages), -1, np.int32)
@@ -145,13 +145,13 @@ def test_paged_kernel_ignores_dead_table_entries():
     """Pages past a sequence's length (or -1 slots) must not contribute."""
     B, H, D, P, page, mp = 1, 2, 32, 8, 8, 4
     q = _rand((B, H, D), jnp.float32)
-    kp = _rand((P, page, H, D), jnp.float32)
-    vp = _rand((P, page, H, D), jnp.float32)
+    kp = _rand((H, P, page, D), jnp.float32)
+    vp = _rand((H, P, page, D), jnp.float32)
     table = jnp.asarray([[3, 5, -1, -1]], jnp.int32)
     lengths = jnp.asarray([12], jnp.int32)
     got = paged_attention_pallas(q, kp, vp, table, lengths, interpret=True)
     # poison the dead pages: result must be identical
-    kp2 = kp.at[6].set(1e9)
-    vp2 = vp.at[6].set(1e9)
+    kp2 = kp.at[:, 6].set(1e9)
+    vp2 = vp.at[:, 6].set(1e9)
     got2 = paged_attention_pallas(q, kp2, vp2, table, lengths, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(got2))

@@ -191,7 +191,7 @@ def test_poison_on_unsafe_free_trips_gather(storage):
     with pytest.raises(UseAfterFree):
         store.assert_alive(1, blocks)
     # the page contents themselves are poisoned too (belt and braces)
-    assert float(np.max(store.k[:, blocks[0]])) >= PagedKVStore.POISON
+    assert float(np.max(store.k[:, :, blocks[0]])) >= PagedKVStore.POISON
 
 
 def test_safe_policy_keeps_pages_alive_under_session():
@@ -288,13 +288,49 @@ def test_pallas_scatter_matches_jnp_scatter():
     identical pools (and both leave untouched pages untouched)."""
     from repro.kernels.paged_attention import paged_scatter_pallas
     P, page, Hkv, D, T = 6, 4, 2, 16, 7
-    pages = jnp.asarray(RNG.normal(size=(P, page, Hkv, D)), jnp.float32)
+    pages = jnp.asarray(RNG.normal(size=(Hkv, P, page, D)), jnp.float32)
     blk = jnp.asarray(RNG.integers(0, P, T), jnp.int32)
     slot = jnp.asarray(RNG.integers(0, page, T), jnp.int32)
     vals = jnp.asarray(RNG.normal(size=(T, Hkv, D)), jnp.float32)
-    want = pages.at[blk, slot].set(vals)
+    want = pages.at[:, blk, slot].set(jnp.swapaxes(vals, 0, 1))
     got = paged_scatter_pallas(pages, blk, slot, vals, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_pallas_scatter_takes_pages_in_any_order():
+    """Tokens whose pages interleave (a page revisited after another) land
+    where the jnp scatter puts them; the kernel keeps a page resident only
+    across consecutive tokens, so the wrapper must regroup them."""
+    from repro.kernels.paged_attention import paged_scatter_pallas
+    P, page, Hkv, D = 5, 4, 2, 16
+    pages = jnp.asarray(RNG.normal(size=(Hkv, P, page, D)), jnp.float32)
+    blk = jnp.asarray([3, 1, 3, 0, 1, 3], jnp.int32)
+    slot = jnp.asarray([0, 2, 1, 3, 0, 2], jnp.int32)
+    vals = jnp.asarray(RNG.normal(size=(6, Hkv, D)), jnp.float32)
+    want = pages.at[:, blk, slot].set(jnp.swapaxes(vals, 0, 1))
+    got = paged_scatter_pallas(pages, blk, slot, vals, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_device_store_pallas_scatter_matches_jnp_scatter():
+    """The store-level wiring of the Pallas write path: a device store
+    writing through ``scatter_impl="pallas"`` holds the same pages as one
+    writing through the jnp scatter, prefill and decode alike."""
+    cfg = CFG_FANCY
+    params = init_params(cfg, jax.random.PRNGKey(9))
+    prompt = [4, 2, 6, 1, 9, 3]
+    stores = [PagedKVStore(cfg, num_blocks=4, page_size=PAGE,
+                           storage="device", scatter_impl=impl)
+              for impl in ("jnp", "pallas")]
+    k, v = prefill_kv(params, cfg, prompt)
+    for st in stores:
+        st.write_prefill([2, 0], k, v)
+        paged_decode_step(params, cfg, st, [[2, 0]], [len(prompt)], [5],
+                          impl="interpret")
+    np.testing.assert_array_equal(np.asarray(stores[0].k),
+                                  np.asarray(stores[1].k))
+    np.testing.assert_array_equal(np.asarray(stores[0].v),
+                                  np.asarray(stores[1].v))
 
 
 def test_host_storage_pays_per_step_upload_device_does_not():
@@ -333,7 +369,7 @@ def test_block_table_empty_request_row_yields_zeros():
     (not NaN, not a mean over masked junk)."""
     P, page, H, D = 8, 4, 2, 32
     q = _rand((2, H, D))
-    kp, vp = _rand((P, page, H, D)), _rand((P, page, H, D))
+    kp, vp = _rand((H, P, page, D)), _rand((H, P, page, D))
     table, lens = build_block_table([[], [3, 5]], [0, 6], page=page)
     out = paged_attention_pallas(q, kp, vp, table, lens, interpret=True)
     assert np.all(np.isfinite(np.asarray(out)))
@@ -346,7 +382,7 @@ def test_block_table_all_empty_batch():
     assert table.shape == (2, 1)               # min_pages floor
     assert np.all(np.asarray(table) == -1)
     q = _rand((2, 2, 32))
-    kp = _rand((4, 4, 2, 32))
+    kp = _rand((2, 4, 4, 32))                  # (Hkv, P, page, D)
     out = paged_attention_pallas(q, kp, kp, table, lens, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), 0.0)
 
@@ -366,7 +402,7 @@ def test_single_token_tail_page_and_max_pages_parity():
     from repro.kernels import ref
     P, page, H, D = 16, 4, 2, 32
     q = _rand((2, H, D))
-    kp, vp = _rand((P, page, H, D)), _rand((P, page, H, D))
+    kp, vp = _rand((H, P, page, D)), _rand((H, P, page, D))
     blocks = [[3], [8, 9, 10, 11]]
     lens = [1, 16]                             # tail page of 1; max pages
     table, lengths = build_block_table(blocks, lens, page=page)

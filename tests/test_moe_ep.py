@@ -11,6 +11,7 @@ ROOT = Path(__file__).resolve().parents[1]
 def test_ep_matches_dense_dispatch():
     code = '''
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs.base import ArchConfig, MoEConfig
 from repro.models.moe import apply_moe, moe_specs
 from repro.models import layers as L
@@ -23,7 +24,8 @@ params = L.materialize(moe_specs(cfg), jax.random.PRNGKey(0))
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64), jnp.float32) * 0.5
 sh.set_mesh(None)
 ref, _ = apply_moe(params, x, cfg=cfg)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 sh.set_mesh(mesh)
 out, aux = jax.jit(lambda p, x: apply_moe(p, x, cfg=cfg))(params, x)
 err = float(jnp.abs(out - ref).max())

@@ -23,8 +23,10 @@ def _run(code: str) -> str:
 def test_pipeline_parallel_matches_sequential():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro.parallel.pipeline import pipeline_forward, make_mlp_stage
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(AxisType.Auto,))
     d, n_micro, mb = 32, 8, 4
     stage_fn, init = make_mlp_stage(d)
     params = init(jax.random.PRNGKey(0), 4)
@@ -43,8 +45,10 @@ def test_pipeline_parallel_matches_sequential():
 def test_int8_compressed_allreduce_close_to_exact():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro.parallel.compression import compressed_psum, compress_with_feedback
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(0), (4096,)) * 0.01
     out = compressed_psum(x, mesh, "data")
     exact = x * 8.0                       # replicated input: psum = 8x
@@ -67,6 +71,7 @@ def test_sharded_train_step_runs_on_8_devices():
     512-device dry-run lowers, but actually executed."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro.configs.base import ArchConfig, dense_stack
     from repro.models.model import init_params, params_logical_axes
     from repro.optim.adamw import adamw_init
@@ -74,7 +79,8 @@ def test_sharded_train_step_runs_on_8_devices():
     from repro.train.train_step import make_train_step
     cfg = ArchConfig(name="t8", d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
                      vocab=256, groups=dense_stack(2), remat="none")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
     sh.set_mesh(mesh)
     params = init_params(cfg, jax.random.PRNGKey(0))
     p_sh = sh.tree_shardings(mesh, params_logical_axes(cfg),
@@ -94,9 +100,11 @@ def test_sharded_train_step_runs_on_8_devices():
 def test_long500k_sequence_parallel_spec():
     _run("""
     import jax
+    from jax.sharding import AxisType
     from repro.launch.mesh import make_production_mesh
     from repro.parallel.sharding import spec_for
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
     # batch=1 -> kv_seq claims ("pod","data")
     spec = spec_for(mesh, ("batch", "kv_seq", "kv_heads", None), (1, 1024, 8, 64))
     assert spec == jax.sharding.PartitionSpec(None, ("pod", "data"), "model"), spec

@@ -75,7 +75,7 @@ def test_chunked_vs_full_prefill_page_and_logit_parity(cfg, chunk):
     chunked = PagedKVStore(cfg, num_blocks=4, page_size=PAGE)
     last = None
     for end, logits in prefill_kv_chunked(params, cfg, chunked, blocks,
-                                          prompt, chunk):
+                                          prompt, chunk, impl="interpret"):
         last = logits
     np.testing.assert_allclose(full.k, chunked.k, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(full.v, chunked.v, atol=2e-5, rtol=2e-5)
@@ -88,9 +88,9 @@ def test_chunked_vs_full_prefill_page_and_logit_parity(cfg, chunk):
 
     # and the next decode step over either store agrees on the token
     a = paged_decode_step(params, cfg, full, [blocks], [len(prompt)],
-                          [prompt[-1]])
+                          [prompt[-1]], impl="interpret")
     b = paged_decode_step(params, cfg, chunked, [blocks], [len(prompt)],
-                          [prompt[-1]])
+                          [prompt[-1]], impl="interpret")
     assert int(jnp.argmax(a[0])) == int(jnp.argmax(b[0]))
 
 
@@ -102,15 +102,17 @@ def test_chunked_prefill_resumes_from_start():
     prompt = [5, 3, 9, 1, 2, 6, 4, 8, 7, 2]
     blocks = [0, 1, 2]
     whole = PagedKVStore(cfg, num_blocks=4, page_size=PAGE)
-    for _ in prefill_kv_chunked(params, cfg, whole, blocks, prompt, chunk):
+    for _ in prefill_kv_chunked(params, cfg, whole, blocks, prompt, chunk,
+                                impl="interpret"):
         pass
     split = PagedKVStore(cfg, num_blocks=4, page_size=PAGE)
-    gen = prefill_kv_chunked(params, cfg, split, blocks, prompt, chunk)
+    gen = prefill_kv_chunked(params, cfg, split, blocks, prompt, chunk,
+                             impl="interpret")
     end, _ = next(gen)                    # one chunk, then abandon
     gen.close()
     assert 0 < end < len(prompt)
     for _ in prefill_kv_chunked(params, cfg, split, blocks, prompt, chunk,
-                                start=end):
+                                start=end, impl="interpret"):
         pass
     np.testing.assert_allclose(whole.k, split.k, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(whole.v, split.v, atol=2e-5, rtol=2e-5)
@@ -184,7 +186,8 @@ def test_partial_prefill_resumable_across_workers():
     pool.add_block_listener(store)
     mk = lambda eid: PrefillWorker(eid, cfg, params, pool, None,  # noqa: E731
                                    page_size=PAGE, max_seq=32,
-                                   kv_store=store, prefill_chunk=chunk)
+                                   kv_store=store, prefill_chunk=chunk,
+                                   kernel_impl="interpret")
     w0, w1 = mk(0), mk(1)
 
     r = Request(1, list(prompt), max_new=4)
@@ -208,7 +211,7 @@ def test_partial_prefill_resumable_across_workers():
     k, v = prefill_kv(params, cfg, prompt)
     ref.write_prefill(r.all_blocks, k, v)
     for b_idx in r.all_blocks:
-        np.testing.assert_allclose(ref.k[:, b_idx], store.k[:, b_idx],
+        np.testing.assert_allclose(ref.k[:, :, b_idx], store.k[:, :, b_idx],
                                    atol=2e-5, rtol=2e-5)
     pool.retire(1, r.blocks)
     pool.reclaim(1)

@@ -1,0 +1,166 @@
+"""Compile the paged serving path for a TPU v5e without one.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a *described* ``v5e:2x2`` topology, and refuses what the chip's
+compiler would refuse -- a block shape that breaks Mosaic's tiling rule, a
+kernel over its VMEM budget, a program larger than the device's memory.
+Interpret-mode tests cannot see any of that.
+
+Covered, at the published widths of two configs the paged path serves
+(bf16 pages, 16-token pages):
+
+* the paged-attention kernel and the paged token scatter at starcoder2-7b
+  (Hkv=4, D=128) and stablelm-12b (Hkv=8, D=160);
+* one whole paged decode step of starcoder2-7b cut to 16 layers, against
+  a full 4096-page pool, whose ``memory_analysis()`` must fit one v5e's
+  16 GiB.
+
+The topology is described inside module fixtures, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+import contextlib
+import os
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from repro.configs.base import dense_stack  # noqa: E402
+from repro.configs.registry import get_config  # noqa: E402
+from repro.kernels.paged_attention import (build_block_table,  # noqa: E402
+                                           paged_attention_pallas,
+                                           paged_scatter_pallas)
+from repro.models.model import abstract_params  # noqa: E402
+from repro.runtime.kv_store import _device_fns, kv_layer_order  # noqa: E402
+from repro.serve.paged_model import paged_decode_step  # noqa: E402
+
+GiB = 1 << 30
+PAGE = 16
+POOL_PAGES = 4096
+DECODE_LAYERS = 16
+
+# (name, n_heads, n_kv_heads, head_dim) -- the published widths
+WIDTHS = [("starcoder2-7b", 36, 4, 128), ("stablelm-12b", 32, 8, 160)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # the TPU library loads below; without this it logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described-topology compile can be written to the persistent cache
+    # but never read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means "no TPU compiler"
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+@pytest.mark.parametrize("name,H,Hkv,D", WIDTHS, ids=[w[0] for w in WIDTHS])
+def test_paged_attention_compiles_for_v5e(one_chip, name, H, Hkv, D):
+    B, max_pages = 8, 32
+    args = (_sds((B, H, D), "float32", one_chip),            # q (served f32)
+            _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
+            _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
+            _sds((B, max_pages), "int32", one_chip),
+            _sds((B,), "int32", one_chip))
+    compiled = jax.jit(paged_attention_pallas).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,H,Hkv,D", WIDTHS, ids=[w[0] for w in WIDTHS])
+def test_paged_scatter_compiles_for_v5e(one_chip, name, H, Hkv, D):
+    T = 64                                   # one prefill chunk of tokens
+    fn = jax.jit(paged_scatter_pallas, donate_argnums=(0,))
+    compiled = fn.lower(_sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
+                        _sds((T,), "int32", one_chip),
+                        _sds((T,), "int32", one_chip),
+                        _sds((T, Hkv, D), "bfloat16", one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+class _TracedPages:
+    """The page-store surface ``paged_decode_step`` uses, over functional
+    page arrays, so the whole step traces into one program.  Writes go
+    through the device storage's own donated scatter."""
+
+    def __init__(self, cfg, k, v):
+        self.layer_order = kv_layer_order(cfg)
+        self.page = PAGE
+        self.k, self.v = list(k), list(v)
+
+    def write_guard(self):
+        return contextlib.nullcontext()
+
+    def gather_table(self, blocks, lengths, *, min_pages=1):
+        return build_block_table(blocks, lengths, page=self.page,
+                                 min_pages=min_pages)
+
+    def append_tokens(self, blocks, slots, k, v, layer):
+        scatter = _device_fns()[0]
+        blk, slot = jnp.asarray(blocks, jnp.int32), jnp.asarray(slots, jnp.int32)
+        self.k[layer] = scatter(self.k[layer], blk, slot, k)
+        self.v[layer] = scatter(self.v[layer], blk, slot, v)
+
+    def layer_pages(self, li):
+        return self.k[li], self.v[li]
+
+
+def test_starcoder2_decode_step_fits_one_v5e(one_chip):
+    """One batched paged decode step of starcoder2-7b at full width, 16
+    layers, 8 requests of 512 tokens each over a 4096-page pool: the
+    program compiles for the chip and its weights + pool + temporaries fit
+    16 GiB (the pool is donated, so it is not counted twice)."""
+    cfg = get_config("starcoder2_7b").scaled(
+        groups=dense_stack(DECODE_LAYERS))
+    L = len(kv_layer_order(cfg))
+    B, ctx = 8, 512
+    per = ctx // PAGE + 1
+    blocks = [list(range(b * per, (b + 1) * per)) for b in range(B)]
+    lens, last = [ctx] * B, [1] * B
+
+    def step(params, k, v):
+        store = _TracedPages(cfg, k, v)
+        logits = paged_decode_step(params, cfg, store, blocks, lens, last,
+                                   impl="pallas")
+        return logits, store.k, store.v
+
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                          abstract_params(cfg))
+    pages = [_sds((cfg.n_kv_heads, POOL_PAGES, PAGE, cfg.head_dim_),
+                  cfg.dtype, one_chip)] * L
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pages, pages).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= L
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    weights = sum(s.size * s.dtype.itemsize
+                  for s in jax.tree.leaves(params))
+    assert weights > 7 * GiB                      # really full width
+    assert total < 16 * GiB, (
+        f"decode step needs {total / GiB:.2f} GiB: args "
+        f"{m.argument_size_in_bytes / GiB:.2f}, temp "
+        f"{m.temp_size_in_bytes / GiB:.2f}")
