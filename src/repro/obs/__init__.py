@@ -24,8 +24,10 @@ from repro.obs.metrics import Counter, Histogram, MetricsRegistry, \
     summary_keys
 from repro.obs.slo import SLOSpec, SLOTracker, TimeSeriesSampler, \
     engine_probes
-from repro.obs.trace import PID_SIM, PID_WALL, Tracer, validate_trace
+from repro.obs.trace import NULL_TRACER, PID_SIM, PID_WALL, Tracer, \
+    validate_trace
 
-__all__ = ["Counter", "Histogram", "MetricsRegistry", "PID_SIM", "PID_WALL",
-           "SLOSpec", "SLOTracker", "TimeSeriesSampler", "Tracer",
-           "engine_probes", "summary_keys", "validate_trace"]
+__all__ = ["Counter", "Histogram", "MetricsRegistry", "NULL_TRACER",
+           "PID_SIM", "PID_WALL", "SLOSpec", "SLOTracker",
+           "TimeSeriesSampler", "Tracer", "engine_probes", "summary_keys",
+           "validate_trace"]

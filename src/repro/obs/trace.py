@@ -36,9 +36,18 @@ Real threads get a track (tid) named after their thread name on first
 event; synthetic tracks (e.g. one per SMR reader slot for the per-reader
 publish spans of a ping pass) come from :meth:`tid_named`.
 
+**The profiler's clock.**  An enabled tracer's :meth:`Tracer.span` also
+enters a ``jax.profiler.TraceAnnotation`` of the same name, so while a JAX
+profile is being taken the span lands on the profiler's host plane too, on
+the device trace's own clock (one line per Python thread).  The Chrome
+event and the annotation open and close together; the annotation carries
+only the name (no args, no string formatting on the hot path).
+
 Disabled tracers are free: every recording method returns immediately and
-``span`` hands back a shared no-op context manager, so the tracing-off
-serve path allocates nothing per event (tests/test_obs.py guards this).
+``span`` hands back a shared no-op context manager (no annotation either),
+so the tracing-off serve path allocates nothing per event
+(tests/test_obs.py guards this).  :data:`NULL_TRACER` is the one shared
+disabled tracer call sites fall back on when they were given none.
 """
 
 from __future__ import annotations
@@ -49,7 +58,7 @@ import time
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Tracer", "validate_trace", "PID_WALL", "PID_SIM"]
+__all__ = ["NULL_TRACER", "Tracer", "validate_trace", "PID_WALL", "PID_SIM"]
 
 PID_WALL = 1    # wall-clock domain (real serving threads)
 PID_SIM = 2     # simulated-cycle domain (gen/vec sim engines, 1 GHz)
@@ -72,14 +81,18 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_args(self, args: dict) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Context manager emitting one X event on the current thread."""
+    """Context manager emitting one X event on the current thread, and one
+    profiler annotation of the same name around it."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, args):
         self._tr = tr
@@ -87,16 +100,26 @@ class _Span:
         self._cat = cat
         self._args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        self._ann = self._tr._annotation(self._name)
+        self._ann.__enter__()
         self._t0 = self._tr.now_us()
         return self
 
     def __exit__(self, *exc):
         tr = self._tr
-        tr.complete(self._name, self._t0, tr.now_us() - self._t0,
-                    cat=self._cat, args=self._args)
+        dur = tr.now_us() - self._t0
+        self._ann.__exit__(None, None, None)
+        tr.complete(self._name, self._t0, dur, cat=self._cat,
+                    args=self._args)
         return False
+
+    def set_args(self, args: dict) -> None:
+        """Args known only when the span's work is done (counts of what
+        it did); replaces those given to :meth:`Tracer.span`."""
+        self._args = args
 
 
 class Tracer:
@@ -113,6 +136,7 @@ class Tracer:
         self._next_tid = 1
         self._next_async = 1
         self._meta_pids: set = set()
+        self._trace_annotation = None   # jax.profiler.TraceAnnotation
 
     # -- clocks --
 
@@ -236,11 +260,22 @@ class Tracer:
         self._buffer().append(ev)
 
     def span(self, name: str, *, cat: str = "", args: Optional[dict] = None):
-        """Context manager timing a block as an X event on this thread.
-        Returns a shared no-op object when disabled (zero allocation)."""
+        """Context manager timing a block as an X event on this thread,
+        and as a profiler annotation (:meth:`_annotation`).  Returns a
+        shared no-op object when disabled (zero allocation)."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, cat, args)
+
+    def _annotation(self, name: str):
+        """A ``jax.profiler.TraceAnnotation(name)``: it records only while
+        a JAX profile is being taken.  JAX is imported on the first span,
+        so the tracer itself imports without it."""
+        cls = self._trace_annotation
+        if cls is None:
+            from jax.profiler import TraceAnnotation as cls
+            self._trace_annotation = cls
+        return cls(name)
 
     # -- publish on flush --
 
@@ -288,6 +323,11 @@ class Tracer:
         p.parent.mkdir(parents=True, exist_ok=True)
         p.write_text(json.dumps(obj))
         return obj
+
+
+#: the shared disabled tracer: call sites given no tracer use this one, so
+#: a tracing-off step costs one no-op span per site and no allocation
+NULL_TRACER = Tracer(enabled=False)
 
 
 def validate_trace(obj: Any) -> List[dict]:

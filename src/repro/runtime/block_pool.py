@@ -42,7 +42,7 @@ from typing import (Any, Callable, Dict, Hashable, List, Optional, Sequence,
                     Set, Tuple)
 
 from repro.core.sim.engine import UseAfterFree
-from repro.obs import MetricsRegistry, Tracer
+from repro.obs import NULL_TRACER, MetricsRegistry, Tracer
 from repro.runtime.reclaim import EpochPOPPolicy, ReclaimPolicy
 
 
@@ -176,7 +176,7 @@ class BlockPool:
         # here (one registry per pool), the tracer is shared with the serve
         # engine when one is attached
         self.metrics = metrics if metrics is not None else MetricsRegistry()
-        self.tracer: Optional[Tracer] = None
+        self.tracer: Tracer = NULL_TRACER
         self.policy = policy or EpochPOPPolicy()
         self.policy.attach(self)
         if tracer is not None:
@@ -585,16 +585,16 @@ class BlockPool:
 
     def reclaim(self, engine: Optional[int] = None) -> int:
         """Ask the policy for a reclamation pass.  Returns # blocks freed.
-        Every pass is timed into the pool's ``reclaim_pass_s`` histogram;
-        passes that freed something additionally leave a trace span."""
-        t0 = time.monotonic()
-        freed = self.policy.reclaim(engine)
-        dur = time.monotonic() - t0
-        self.metrics.record("reclaim_pass_s", dur)
+        Every pass is timed into the pool's ``reclaim_pass_s`` histogram
+        and traced as a ``reclaim_pass`` span, whether it freed or not."""
         tr = self.tracer
-        if tr is not None and tr.enabled and freed:
-            tr.complete("reclaim_pass", tr.wall_ts(t0), dur * 1e6, cat="smr",
-                        args={"freed": freed, "engine": engine})
+        with tr.span("reclaim_pass", cat="smr") as sp:
+            t0 = time.monotonic()
+            freed = self.policy.reclaim(engine)
+            dur = time.monotonic() - t0
+            if tr.enabled:
+                sp.set_args({"freed": freed, "engine": engine})
+        self.metrics.record("reclaim_pass_s", dur)
         return freed
 
     def _return_blocks_if(self, pred: Callable[[int, int], bool]) -> int:

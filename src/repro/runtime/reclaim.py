@@ -198,12 +198,13 @@ class EpochPOPPolicy(ReclaimPolicy):
         # decode handoff), and copying a set mid-mutation is an error; the
         # published-set swap itself is atomic under the GIL
         pool = self.pool
-        with pool._lock:
-            published = (set(pool._live_local[engine])
-                         | set(pool._session[engine]))
-        self._live_published[engine] = published
-        self._publish_counter[engine] += 1
-        pool.stats.publishes += 1
+        with pool.tracer.span("pop_publish", cat="smr"):
+            with pool._lock:
+                published = (set(pool._live_local[engine])
+                             | set(pool._session[engine]))
+            self._live_published[engine] = published
+            self._publish_counter[engine] += 1
+            pool.stats.publishes += 1
 
     def on_engine_crash(self, engine: int) -> None:
         """Dead engines leave the protocol: their stale announcement no
@@ -325,8 +326,8 @@ class EpochPOPPolicy(ReclaimPolicy):
         (``smr reader e<i>``, so the per-reader windows stack visually in
         Perfetto), and a closing ``pop_ack`` instant.  Spans are linked by a
         shared ``pass`` id in args."""
-        tr = getattr(self.pool, "tracer", None)
-        if tr is None or not tr.enabled:
+        tr = self.pool.tracer
+        if not tr.enabled:
             return
         ts0 = tr.wall_ts(t_ping)
         aid = tr.next_async_id()

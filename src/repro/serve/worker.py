@@ -70,7 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.model import init_cache
-from repro.obs import MetricsRegistry, Tracer
+from repro.obs import NULL_TRACER, MetricsRegistry, Tracer
 from repro.runtime.block_pool import BlockPool, OutOfBlocks, StaleHandoff
 from repro.runtime.kv_store import PagedKVStore
 
@@ -180,7 +180,7 @@ class _PoolActor:
         self.prefix_cache = prefix_cache
         self.evict_policy = evict_policy
         self.prefill_chunk = prefill_chunk
-        self.tracer = tracer
+        self.tracer = NULL_TRACER if tracer is None else tracer
         self.metrics = metrics
         self._decode = decode
         # paged KV mode: physical pages + Pallas kernel instead of dense
@@ -191,6 +191,7 @@ class _PoolActor:
         self.kernel_impl = kernel_impl
         self._stop = threading.Event()
         self.prefill_tokens = 0
+        self.prefill_chunks = 0
         self.prefill_tokens_skipped = 0
         # bytes of KV installed into per-request private storage at
         # admission, split by prefix-cache outcome (the benchmark's
@@ -428,20 +429,20 @@ class _PoolActor:
         store = self.kv_store
         hit = r.cache_hit
         tr = self.tracer
-        t_chunk = time.monotonic()
-        for end, _ in prefill_kv_chunked(
-                self.params, self.cfg, store, r.all_blocks, r.prompt,
-                self.prefill_chunk, start=r.prefilled,
-                impl=self.kernel_impl):
+        chunks = prefill_kv_chunked(
+            self.params, self.cfg, store, r.all_blocks, r.prompt,
+            self.prefill_chunk, start=r.prefilled, impl=self.kernel_impl)
+        while r.prefilled < len(r.prompt):
+            # the host's enqueue of one chunk: nothing in it waits for the
+            # device, which runs the chunk later
+            with tr.span("prefill_chunk", cat="serve") as sp:
+                end, _ = next(chunks)
+                if tr.enabled:
+                    sp.set_args({"rid": r.rid, "start": r.prefilled,
+                                 "end": end})
             written = (end - r.prefilled) * store.token_bytes
             self.prefill_tokens += end - r.prefilled
-            if tr is not None and tr.enabled:
-                now = time.monotonic()
-                tr.complete("prefill_chunk", tr.wall_ts(t_chunk),
-                            (now - t_chunk) * 1e6, cat="serve",
-                            args={"rid": r.rid, "start": r.prefilled,
-                                  "end": end})
-                t_chunk = now
+            self.prefill_chunks += 1
             r.prefilled = end
             if hit:
                 self.kv_bytes_copied_hit += written
@@ -633,48 +634,47 @@ class EngineWorker(_PoolActor):
 
     # -- decode step (POP reader) --
 
-    def _step(self) -> None:
+    def _step(self) -> int:
+        """One decode step over every running request; returns the rows
+        it decoded (0: nothing was running)."""
         if not self.running:
             time.sleep(0.001)
-            return
-        t_step = time.monotonic()
-        batch = len(self.running)
-        # one batched reader session over the whole step's working set: the
-        # paper's traversal-retention argument at serving granularity (one
-        # publish on ping instead of a fence per block)
-        session = [b for r in self.running.values() for b in r.all_blocks]
-        self.pool.reserve(self.engine_id, session)
-        if self.stall_every and self.steps % self.stall_every == \
-                self.stall_every - 1:
-            self.injected_stalls += 1
-            tr = self.tracer
-            if tr is None or not tr.enabled:
-                time.sleep(self.stall_s)
-            else:
-                t0 = time.monotonic()
-                time.sleep(self.stall_s)
-                tr.complete("desched_stall", tr.wall_ts(t0),
-                            (time.monotonic() - t0) * 1e6, cat="fault",
-                            args={"engine": self.engine_id})
-        if self.kv_store is not None:
-            finished = self._step_paged()
-        else:
-            finished = self._step_dense()
-        for rid in finished:
-            r = self.running.pop(rid)
-            self._caches.pop(rid, None)
-            self.pool.retire(self.engine_id, r.blocks)      # -> SMR
-            if r.shared_blocks:
-                self.pool.release_shared(self.engine_id, r.shared_blocks)
-            r.blocks, r.shared_blocks = [], []
-            self._finish_trace(r)
-            r.done.set()
-        self.steps += 1
+            return 0
         tr = self.tracer
-        if tr is not None and tr.enabled:
-            tr.complete("decode_step", tr.wall_ts(t_step),
-                        (time.monotonic() - t_step) * 1e6, cat="serve",
-                        args={"batch": batch, "finished": len(finished)})
+        batch = len(self.running)
+        with tr.span("decode_step", cat="serve") as sp:
+            # one batched reader session over the whole step's working
+            # set: the paper's traversal-retention argument at serving
+            # granularity (one publish on ping instead of a fence per block)
+            with tr.span("pool_reserve", cat="smr"):
+                session = [b for r in self.running.values()
+                           for b in r.all_blocks]
+                self.pool.reserve(self.engine_id, session)
+            if self.stall_every and self.steps % self.stall_every == \
+                    self.stall_every - 1:
+                self.injected_stalls += 1
+                with tr.span("desched_stall", cat="fault"):
+                    time.sleep(self.stall_s)
+            if self.kv_store is not None:
+                finished = self._step_paged()
+            else:
+                finished = self._step_dense()
+            # retire may run a whole publish-on-ping pass inline
+            with tr.span("pool_retire", cat="smr"):
+                for rid in finished:
+                    r = self.running.pop(rid)
+                    self._caches.pop(rid, None)
+                    self.pool.retire(self.engine_id, r.blocks)      # -> SMR
+                    if r.shared_blocks:
+                        self.pool.release_shared(self.engine_id,
+                                                 r.shared_blocks)
+                    r.blocks, r.shared_blocks = [], []
+                    self._finish_trace(r)
+                    r.done.set()
+            self.steps += 1
+            if tr.enabled:
+                sp.set_args({"batch": batch, "finished": len(finished)})
+        return batch
 
     def _step_dense(self) -> List[int]:
         """Per-request decode against private dense caches."""
@@ -699,17 +699,24 @@ class EngineWorker(_PoolActor):
         physical pages -- ragged lengths, prefix pages included in place."""
         from repro.serve.paged_model import paged_decode_step
 
+        tr = self.tracer
         rs = list(self.running.values())
-        gather = [b for r in rs for b in r.all_blocks]
-        self.pool.touch(self.engine_id, gather)             # pool tripwire
-        self.kv_store.assert_alive(self.engine_id, gather)  # page tripwire
-        blocks = [r.all_blocks for r in rs]
-        lens = [len(r.prompt) + len(r.out) for r in rs]
-        last = [r.out[-1] if r.out else r.prompt[-1] for r in rs]
-        logits = paged_decode_step(self.params, self.cfg, self.kv_store,
-                                   blocks, lens, last,
-                                   impl=self.kernel_impl)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with tr.span("pool_tripwire", cat="smr"):
+            gather = [b for r in rs for b in r.all_blocks]
+            self.pool.touch(self.engine_id, gather)             # pool
+            self.kv_store.assert_alive(self.engine_id, gather)  # pages
+        # the host builds the inputs, the table and every launch; the
+        # device runs behind it
+        with tr.span("dispatch", cat="serve"):
+            blocks = [r.all_blocks for r in rs]
+            lens = [len(r.prompt) + len(r.out) for r in rs]
+            last = [r.out[-1] if r.out else r.prompt[-1] for r in rs]
+            logits = paged_decode_step(self.params, self.cfg, self.kv_store,
+                                       blocks, lens, last,
+                                       impl=self.kernel_impl)
+        # the host waits for the device to finish the step
+        with tr.span("token_sync", cat="serve"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
         now = time.monotonic()
         finished = []
         for r, tok in zip(rs, nxt):
@@ -720,12 +727,19 @@ class EngineWorker(_PoolActor):
         return finished
 
     def _loop(self) -> None:
+        tr = self.tracer
         try:
             while not self._stop.is_set():
-                self.pool.start_step(self.engine_id)  # announce + safepoint
-                self._admit()
-                self._step()
-                self.pool.end_step(self.engine_id)    # closes the session
+                with tr.span("engine_step", cat="serve") as sp:
+                    chunks = self.prefill_chunks
+                    self.pool.start_step(self.engine_id)  # announce+safepoint
+                    with tr.span("admit", cat="serve"):
+                        self._admit()
+                    rows = self._step()
+                    self.pool.end_step(self.engine_id)    # closes the session
+                    if tr.enabled:
+                        sp.set_args({"rows": rows, "prefill_chunks":
+                                     self.prefill_chunks - chunks})
         except BaseException as e:  # noqa: BLE001 -- UseAfterFree et al.
             # fail FAST: record the error and release every waiter instead
             # of dying silently and leaving clients to hit done.wait timeouts

@@ -93,11 +93,22 @@ def test_publish_on_flush_and_concurrent_export(tmp_path):
     validate_trace(json.loads(out.read_text()))   # round-trips through disk
 
 
-def test_disabled_tracer_is_free():
+def test_disabled_tracer_is_free(monkeypatch):
+    import jax.profiler
+
+    made = []
+
+    class CountingAnnotation(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountingAnnotation)
     tr = Tracer(enabled=False)
     # the disabled span is one shared singleton: no per-call allocation
     assert tr.span("a") is tr.span("b")
-    with tr.span("a"):
+    with tr.span("a") as sp:
+        sp.set_args({"n": 1})
         tr.instant("x")
         tr.complete("y", 0.0, 1.0)
         tr.async_begin("z", 1)
@@ -105,6 +116,42 @@ def test_disabled_tracer_is_free():
     assert tr.events == 0
     # no private buffer was ever created for this thread
     assert tr._buffers == []
+    # and no profiler annotation, where an enabled span makes one
+    assert made == []
+    with Tracer().span("on"):
+        pass
+    assert made == ["on"]
+
+
+def test_span_lands_in_the_profiler_trace_from_a_thread(tmp_path):
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+
+    def work():
+        with tr.span("worker_span", cat="t"):
+            time.sleep(0.03)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        th = threading.Thread(target=work, name="engine-7")
+        th.start()
+        th.join()
+    finally:
+        jax.profiler.stop_trace()
+    x = [e for e in tr.to_dict()["traceEvents"]
+         if e["ph"] == "X" and e["name"] == "worker_span"]
+    assert len(x) == 1
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    host = [e for p in ProfileData.from_file(str(path)).planes
+            if p.name.startswith("/host:") for ln in p.lines
+            for e in ln.events if e.name == "worker_span"]
+    assert len(host) == 1
+    # the same span on the profiler's clock: its length agrees
+    assert host[0].duration_ns / 1e3 == pytest.approx(x[0]["dur"], rel=0.1)
 
 
 def test_validate_trace_rejects_malformed():
