@@ -2,30 +2,42 @@
 POP-managed KV block pool (DESIGN.md §2.3).
 
 The block table produced by the host-side ``runtime/block_pool.py`` is a
-*scalar-prefetch* operand: the BlockSpec index_map reads it to decide which
-physical page of the pool to DMA into VMEM next, so the gather happens in
-the memory pipeline (double-buffered page fetches), not as a materialized
-(B, max_pages*page, ...) tensor in HBM like the XLA reference.
+*scalar-prefetch* operand: the kernel reads it to decide which physical
+pages of the pool to DMA into VMEM next, so the gather happens in the
+memory pipeline, not as a materialized (B, max_pages*page, ...) tensor in
+HBM like the XLA reference.
 
-Page layout: ``(Hkv, P, page, D)`` per layer -- head-major, so one grid
-step's block ``(1, 1, page, D)`` has the array's own last two dimensions.
-That is what Mosaic's tiling rule asks of a block (last two dims divisible
-by (8, 128) or equal to the array's), so the kernel lowers at any Hkv,
-page size and head dim; a token-major ``(P, page, Hkv, D)`` pool would need
-a ``(page, 1, D)`` block, which the TPU compiler refuses whenever Hkv > 1.
+Page layout: ``(Hkv, P, page, D)`` per layer -- head-major, so a page of
+one head is a ``(page, D)`` tile with the array's own last two dimensions,
+which is what Mosaic's tiling rule asks of a block at any Hkv.
 
-grid = (B, Hkv, n_pages); pages are the sequential axis with the online
-softmax state (m, l, acc) in VMEM scratch.  Dead table entries (-1) are
-masked and their DMA redirected to page 0.
+grid = (B, blocks): each grid step folds one block of ``ppb`` pages of a
+row into its online softmax.  ``ppb`` follows from the shapes
+(:func:`_pages_per_block`: 128 tokens' worth, within a VMEM budget).  The
+block's pages are ``ppb`` K and ``ppb`` V operands whose index maps read a
+scalar-prefetched page list, so the pipeline double-buffers their DMAs,
+one DMA per page of every KV head.  Each row fetches its **live pages
+only** -- ``ceil(len / page)`` of them (:func:`live_pages`), not the
+table's width, which the batch's longest row sets: past them an operand
+keeps the page it holds, so the pipeline moves nothing, and the block is
+skipped.  Positions past the length inside the last page are masked.  A -1
+entry inside a row's length reads page 0, as the XLA reference does;
+:func:`build_block_table` never writes one there.  The online softmax
+(m, l, acc) and both matmuls run in f32, all KV heads of a block in one
+batched matmul for the scores and one for the values.
+
+Every (rows, table width) of a call is a program of its own; the serving
+path builds its inputs at :func:`call_shape`'s buckets so that calls share
+programs.
 
 Ragged decode batches: a serving step batches requests whose block lists
 have wildly different lengths (fresh single-page requests next to
 max-pages ones, and rows that hold zero tokens).  :func:`build_block_table`
 packs such ragged lists into the kernel's padded ``(B, max_pages)`` layout
--- table width is the BATCH max, not the engine max, so short batches do
-not pay dead grid iterations -- and the kernel itself guarantees a
-fully-dead row (length 0, all entries -1) produces exact zeros instead of
-NaN garbage, so empty requests ride through the batched call unharmed.
+-- table width is the BATCH max, not the engine max -- and the kernel
+itself guarantees a fully-dead row (length 0, all entries -1) produces
+exact zeros instead of NaN garbage, so empty requests ride through the
+batched call unharmed.
 """
 
 from __future__ import annotations
@@ -51,6 +63,22 @@ def paged_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "interpret"
 
 
+def live_pages(lengths, page: int):
+    """Pages that rows of ``lengths`` tokens reach: the pages a row's table
+    entries cover, and all that :func:`paged_attention_pallas` fetches of
+    it.  Takes ints and numpy or jax arrays alike."""
+    return -(-lengths // page)
+
+
+def table_pages(lengths: Sequence[int], page: int) -> Tuple[int, int]:
+    """``(live, slots)`` for rows holding ``lengths`` tokens: the pages the
+    rows reach, all that the kernel fetches, and rows x the widest row's
+    pages, the slots of the table :func:`build_block_table` packs for them
+    at its default ``min_pages`` (before any :func:`call_shape` padding)."""
+    live = [live_pages(int(n), page) for n in lengths]
+    return sum(live), len(live) * max([1] + live)
+
+
 def build_block_table(
     blocks: Sequence[Sequence[int]],
     lengths: Sequence[int],
@@ -71,7 +99,7 @@ def build_block_table(
     """
     rows: List[List[int]] = []
     for i, (bl, ln) in enumerate(zip(blocks, lengths)):
-        used = -(-int(ln) // page)          # pages holding actual tokens
+        used = live_pages(int(ln), page)    # pages holding actual tokens
         if len(bl) < used:
             # silent truncation would mask positions the caller claims
             # exist -- wrong attention with no error; fail loudly instead
@@ -87,46 +115,85 @@ def build_block_table(
             jnp.asarray(np.asarray(lengths, np.int32)))
 
 
-def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page, n_pages, scale, softcap, g):
-    b = pl.program_id(0)
-    pi = pl.program_id(2)
+#: tokens one block of pages should carry at least: one MXU-wide K tile
+_BLOCK_TOKENS = 128
+#: VMEM the K/V block buffers may take (two slots each of K and V)
+_BLOCK_VMEM = 4 << 20
 
-    @pl.when(pi == 0)
-    def _init():
+
+def _pages_per_block(page: int, hkv: int, d: int, itemsize: int,
+                     width: int) -> int:
+    """Pages one block of the kernel fetches and computes: enough for
+    :data:`_BLOCK_TOKENS` tokens, within :data:`_BLOCK_VMEM` of K/V
+    buffers (a page of every KV head, lanes padded to 128), and no more
+    than the table is wide."""
+    lanes = -(-d // 128) * 128
+    per_page = 2 * 2 * hkv * page * lanes * itemsize     # K, V x two slots
+    cap = max(1, _BLOCK_VMEM // per_page)
+    return max(1, min(-(-_BLOCK_TOKENS // page), cap, width))
+
+
+def call_shape(rows: int, width: int, *, page: int, hkv: int, d: int,
+               itemsize: int) -> Tuple[int, int]:
+    """The (rows, table width) to build a call's inputs at: rows padded to
+    a multiple of 8 (padded rows empty) and the width to one of the block's
+    pages.  Each shape of the inputs is a program of its own, loaded in
+    set-up, and the serving path meets hundreds; padded to these, calls
+    share one program per bucket."""
+    ppb = _pages_per_block(page, hkv, d, itemsize, width)
+    return -(-rows // 8) * 8, -(-width // ppb) * ppb
+
+
+def _paged_kernel(fetch_ref, len_ref, live_ref,                   # SMEM
+                  q_ref, *refs, page, ppb, scale, softcap):
+    """grid = (B, blocks): the pipeline brings block i's pages as ``ppb``
+    K and ``ppb`` V operands, every KV head of a page in one DMA; a block
+    past the row's live pages keeps the pages already resident (no DMA)
+    and is skipped."""
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, m_scr, l_scr, acc_scr = refs[2 * ppb:]
+    b, i = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-    k = k_ref[0, 0].astype(jnp.float32)          # (page, D)
-    v = v_ref[0, 0].astype(jnp.float32)          # (page, Dv)
+    @pl.when(i * ppb < live_ref[b])
+    def _():
+        bk = ppb * page
+        valid = (jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) + i * bk
+                 < len_ref[b])
+        # every KV head at once: q (Hkv, G, D), k (Hkv, bk, D), v (Hkv,
+        # bk, Dv), all in f32
+        k, v = (jnp.concatenate([r[...].astype(jnp.float32) for r in refs_],
+                                axis=1) for refs_ in (k_refs, v_refs))
+        q = q_ref[0].astype(jnp.float32) * scale
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+        if softcap:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev, l_prev = m_scr[...], l_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        # explicit dead-position zeroing: while a row has no valid position
+        # (an empty request in a ragged batch), m_new stays at NEG_INF and
+        # exp(s - m_new) would be exp(0) = 1 for every dead slot -- the
+        # masked weights must be zero so the row accumulates nothing and
+        # the final normalization (l == 0) yields exact zeros
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m_scr[...] = m_new
+        l_scr[...] = l_prev * corr + jnp.sum(p, axis=2, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if softcap:
-        s = softcap * jnp.tanh(s / softcap)
-    pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + pi * page
-    valid = (pos < len_ref[b]) & (table_ref[b, pi] >= 0)
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev, l_prev = m_scr[...], l_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    # explicit dead-position zeroing: when a row has NO valid position at all
-    # (empty request in a ragged batch), m_new stays at NEG_INF and
-    # exp(s - m_new) would be exp(0) = 1 for every dead slot -- the masked
-    # weights must be forced to zero so the row accumulates nothing and the
-    # final normalization (l == 0) yields exact zeros, not a mean over junk
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    m_scr[...] = m_new
-    l_scr[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(pi == n_pages - 1)
-    def _fin():
-        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
+    # an empty row attends nothing and finishes as exact zeros (l == 0)
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _():
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -145,41 +212,53 @@ def paged_attention_pallas(
     B, H, D = q.shape
     Hkv, P, page, _ = k_pages.shape
     Dv = v_pages.shape[-1]
-    G = H // Hkv
     max_pages = block_table.shape[1]
+    G = H // Hkv
+    ppb = _pages_per_block(page, Hkv, max(D, Dv), k_pages.dtype.itemsize,
+                           max_pages)
+    nb = -(-max_pages // ppb)                    # blocks a row walks
+    table = jnp.pad(block_table.astype(jnp.int32),
+                    ((0, 0), (0, nb * ppb - max_pages)), constant_values=-1)
+    lens = lengths.astype(jnp.int32)
+    live = jnp.clip(live_pages(lens, page), 0, max_pages)
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    # fetch[s, j]: the page K/V operand j holds at grid step s (row-major
+    # over (B, nb)).  Past the row's live pages it is the page the operand
+    # already holds, so the pipeline moves nothing; a -1 entry inside a
+    # row's length reads page 0
+    steps = jnp.arange(B * nb, dtype=jnp.int32)[:, None]
+    held = (jnp.arange(nb * ppb, dtype=jnp.int32)[None, :]
+            < live[:, None]).reshape(B * nb, ppb)
+    last = jax.lax.cummax(jnp.where(held, steps, -1), axis=0)
+    fetch = jnp.take_along_axis(table.reshape(B * nb, ppb),
+                                jnp.maximum(last, 0), axis=0)
+    fetch = jnp.where(last >= 0, jnp.maximum(fetch, 0), 0)
 
-    qh = q.reshape(B, Hkv, G, D)
-    safe_table = jnp.maximum(block_table, 0).astype(jnp.int32)
-
-    grid = (B, Hkv, max_pages)
-    kernel = functools.partial(_paged_kernel, page=page, n_pages=max_pages,
-                               scale=scale, softcap=softcap, g=G)
+    def page_spec(j, d):
+        return pl.BlockSpec(
+            (Hkv, None, page, d),
+            lambda b, i, f, *_: (0, f[(b * nb + i) * ppb + j], 0, 0))
 
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_paged_kernel, page=page, ppb=ppb, scale=scale,
+                          softcap=softcap),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,        # block table + lengths
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, h, p, tbl, lens: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, page, D),
-                             lambda b, h, p, tbl, lens: (h, tbl[b, p], 0, 0)),
-                pl.BlockSpec((1, 1, page, Dv),
-                             lambda b, h, p, tbl, lens: (h, tbl[b, p], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, Dv),
-                                   lambda b, h, p, tbl, lens: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, Dv), jnp.float32),
-            ],
+            num_scalar_prefetch=3,
+            grid=(B, nb),
+            in_specs=[pl.BlockSpec((1, Hkv, G, D),
+                                   lambda b, i, *_: (b, 0, 0, 0))]
+            + [page_spec(j, D) for j in range(ppb)]
+            + [page_spec(j, Dv) for j in range(ppb)],
+            out_specs=pl.BlockSpec((1, Hkv, G, Dv),
+                                   lambda b, i, *_: (b, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                            pltpu.VMEM((Hkv, G, Dv), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
         interpret=interpret,
-    )(safe_table, lengths.astype(jnp.int32), qh, k_pages, v_pages)
-
+    )(fetch.reshape(-1), lens, live, q.reshape(B, Hkv, G, D),
+      *[k_pages] * ppb, *[v_pages] * ppb)
     return out.reshape(B, H, Dv)
 
 

@@ -132,10 +132,11 @@ def _embed(embed, toks, *, cfg: ArchConfig):
     return x
 
 
-@functools.partial(jax.jit, static_argnames="cfg")
-def _qkv(gp, rep, x, positions, *, cfg: ArchConfig):
+@functools.partial(jax.jit, static_argnames=("cfg", "rows"))
+def _qkv(gp, rep, x, positions, *, cfg: ArchConfig, rows: int):
     """norm -> q/k/v projections -> qk-norm -> rope, for one row per
-    batch entry: ``(q (B,H,hd) f32, k (B,Hkv,hd), v (B,Hkv,hd))``."""
+    batch entry: ``(q (rows,H,hd) f32, k (B,Hkv,hd), v (B,Hkv,hd))``, q
+    padded with zero rows to the attention call's ``rows``."""
     lp = _layer(gp, rep)
     h = rms_norm(x, lp["norm1"], cfg.norm_eps)
     ap = lp["attn"]
@@ -147,14 +148,19 @@ def _qkv(gp, rep, x, positions, *, cfg: ArchConfig):
         k = rms_norm(k, ap["k_scale"], cfg.norm_eps)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_pct)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_pct)
-    return q[:, 0].astype(jnp.float32), k[:, 0], v[:, 0]
+    q = q[:, 0].astype(jnp.float32)
+    return (jnp.pad(q, ((0, rows - q.shape[0]), (0, 0), (0, 0))),
+            k[:, 0], v[:, 0])
 
 
 @functools.partial(jax.jit, static_argnames="cfg")
 def _out_mlp(gp, rep, x, attn, *, cfg: ArchConfig):
-    """Output projection + residual, then the MLP block + residual."""
+    """Output projection + residual, then the MLP block + residual;
+    ``attn``'s rows past ``x``'s are padding, dropped."""
     lp = _layer(gp, rep)
-    B, H, hd = attn.shape
+    B = x.shape[0]
+    attn = attn[:B]
+    H, hd = attn.shape[1:]
     o = jnp.einsum("bshe,hed->bsd",
                    attn.reshape(B, 1, H, hd).astype(cfg.dtype),
                    lp["attn"]["wo"])
@@ -195,10 +201,20 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
     i attends only to positions < lens[i] + 1.
     """
     from repro.kernels import ops as kops
+    from repro.kernels.paged_attention import call_shape, live_pages
 
     lens_np = np.asarray(lens, np.int64)
-    table, _ = store.gather_table(blocks, [n + 1 for n in lens_np])
-    att_lens = jnp.asarray(lens_np + 1, jnp.int32)
+    B, att = len(lens_np), lens_np + 1
+    # the table and lengths (and q, in _qkv) at the attention call's bucket
+    # of shapes, padded rows empty: calls share one program per bucket
+    rows, width = call_shape(B, int(live_pages(att, store.page).max()),
+                             page=store.page, hkv=cfg.n_kv_heads,
+                             d=cfg.head_dim_,
+                             itemsize=jnp.dtype(cfg.dtype).itemsize)
+    att = np.pad(att, (0, rows - B))
+    table, _ = store.gather_table(list(blocks) + [[]] * (rows - B), att,
+                                  min_pages=width)
+    att_lens = jnp.asarray(att, jnp.int32)
     positions = jnp.asarray(lens_np, jnp.int32)[:, None]     # (B,1)
     x = _embed(params["embed"], jnp.asarray(list(tokens), jnp.int32),
                cfg=cfg)                                      # (B,1,D)
@@ -207,7 +223,7 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
 
     for li, (gi, pi, rep) in enumerate(store.layer_order):
         gp = params["groups"][f"g{gi}"][f"p{pi}"]
-        q, k, v = _qkv(gp, rep, x, positions, cfg=cfg)
+        q, k, v = _qkv(gp, rep, x, positions, cfg=cfg, rows=rows)
         # physical write: every row's K/V lands in its page BEFORE the
         # gather, so each new position attends to itself (and, in a prefill
         # chunk, to its chunk-mates) exactly like the dense path -- model
@@ -233,6 +249,16 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
 # ----------------------------------------------------------------------------
 # decode: batched step over block tables
 # ----------------------------------------------------------------------------
+
+
+def decode_table_pages(store: PagedKVStore,
+                       lens: Sequence[int]) -> Tuple[int, int]:
+    """:func:`~repro.kernels.paged_attention.table_pages` of a
+    :func:`paged_decode_step` over requests holding ``lens`` tokens, each
+    of which attends its fed token too."""
+    from repro.kernels.paged_attention import table_pages
+
+    return table_pages([n + 1 for n in lens], store.page)
 
 
 def paged_decode_step(

@@ -697,7 +697,8 @@ class EngineWorker(_PoolActor):
         """ONE batched (table, lens, q) decode through the paged kernel:
         every running request becomes a block-table row over the shared
         physical pages -- ragged lengths, prefix pages included in place."""
-        from repro.serve.paged_model import paged_decode_step
+        from repro.serve.paged_model import (decode_table_pages,
+                                             paged_decode_step)
 
         tr = self.tracer
         rs = list(self.running.values())
@@ -707,13 +708,16 @@ class EngineWorker(_PoolActor):
             self.kv_store.assert_alive(self.engine_id, gather)  # pages
         # the host builds the inputs, the table and every launch; the
         # device runs behind it
-        with tr.span("dispatch", cat="serve"):
+        with tr.span("dispatch", cat="serve") as sp:
             blocks = [r.all_blocks for r in rs]
             lens = [len(r.prompt) + len(r.out) for r in rs]
             last = [r.out[-1] if r.out else r.prompt[-1] for r in rs]
             logits = paged_decode_step(self.params, self.cfg, self.kv_store,
                                        blocks, lens, last,
                                        impl=self.kernel_impl)
+            if tr.enabled:
+                live, slots = decode_table_pages(self.kv_store, lens)
+                sp.set_args({"live_pages": live, "table_pages": slots})
         # the host waits for the device to finish the step
         with tr.span("token_sync", cat="serve"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))

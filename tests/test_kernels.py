@@ -106,39 +106,131 @@ def test_linear_scan_kernel_matches_sequential_recurrence():
 # ----------------------------------------------------------------------------
 
 PAGED_CASES = [
-    # (B, H, Hkv, D, n_pool_pages, page, max_pages)
-    (2, 4, 2, 32, 16, 16, 4),
-    (3, 8, 8, 64, 32, 8, 6),
-    (1, 4, 4, 32, 8, 16, 3),
+    # (B, H, Hkv, D, n_pool_pages, page, max_pages, lengths, shared)
+    # lengths None: drawn at random; shared: every row reads one block list
+    (2, 4, 2, 32, 16, 16, 4, None, False),
+    (3, 8, 8, 64, 32, 8, 6, None, False),
+    (1, 4, 4, 32, 8, 16, 3, None, False),
+    # ragged: rows far shorter than the table is wide
+    (4, 8, 2, 128, 96, 16, 21, (1, 17, 40, 330), False),
+    # live pages not a multiple of the kernel's block: 11 and 7
+    (2, 4, 2, 128, 32, 16, 12, (170, 100), False),
+    # an empty row between live ones, at head dims 128 and 32
+    (3, 4, 2, 128, 16, 16, 4, (20, 0, 50), False),
+    (3, 4, 2, 32, 16, 16, 4, (20, 0, 50), False),
+    # starcoder2's head grouping (G = 9) and head dim
+    (2, 36, 4, 128, 24, 16, 5, None, False),
+    # stablelm-12b's KV heads and head dim
+    (2, 16, 8, 160, 16, 16, 3, None, False),
+    # a prefill chunk: 8 rows over one block list, lengths start+1..start+8
+    (8, 8, 2, 128, 16, 16, 6, tuple(range(71, 79)), True),
 ]
 
 
 @pytest.mark.parametrize("case", PAGED_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_kernel_vs_oracle(case, dtype):
-    B, H, Hkv, D, P, page, max_pages = case
+    B, H, Hkv, D, P, page, max_pages, lengths, shared = case
     q = _rand((B, H, D), dtype)
     k_pages = _rand((Hkv, P, page, D), dtype)
     v_pages = _rand((Hkv, P, page, D), dtype)
-    # build random block tables + lengths
-    lengths = jnp.asarray(RNG.integers(1, page * max_pages, (B,)), jnp.int32)
-    table = np.full((B, max_pages), -1, np.int32)
-    used = set()
-    for b in range(B):
-        n = int(np.ceil(int(lengths[b]) / page))
-        for i in range(n):
-            pid = int(RNG.integers(0, P))
-            while pid in used:
-                pid = (pid + 1) % P
-            used.add(pid)
-            table[b, i] = pid
+    drawn = lengths is None
+    if drawn:
+        lengths = RNG.integers(1, page * max_pages, (B,))
+    lengths = np.asarray(lengths)
+    live = -(-lengths // page)
+    # every table slot a distinct page (one list for all rows when shared)
+    ids = RNG.permutation(P)
+    if shared:
+        table = np.tile(ids[:max_pages], (B, 1)).astype(np.int32)
+    else:
+        table = ids[:B * max_pages].reshape(B, max_pages).astype(np.int32)
+    if drawn:
+        # build_block_table's -1 padding past each row's pages; the given
+        # lengths keep real pages there, which the kernel must not attend
+        table[np.arange(max_pages)[None, :] >= live[:, None]] = -1
     table = jnp.asarray(table)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    want = ref.paged_attention_ref(q, k_pages, v_pages, table, lengths)
+    # poison every page no row reaches: the output must not move
+    read = np.zeros(P, bool)
+    for b in range(B):
+        read[np.asarray(table)[b, :live[b]]] = True
+    dead = jnp.asarray(~read)[None, :, None, None]
+    k_pages = jnp.where(dead, jnp.asarray(1e9, dtype), k_pages)
+    v_pages = jnp.where(dead, jnp.asarray(1e9, dtype), v_pages)
     got = paged_attention_pallas(q, k_pages, v_pages, table, lengths,
                                  interpret=True)
-    want = ref.paged_attention_ref(q, k_pages, v_pages, table, lengths)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    empty = np.asarray(lengths) == 0
+    assert (got[empty] == 0).all()               # exact zeros, not NaN
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+    np.testing.assert_allclose(got[~empty], want[~empty], atol=tol, rtol=tol)
+
+
+def test_paged_kernel_under_tpu_interpreter_is_race_free():
+    """The TPU interpreter runs every DMA at its wait, fills VMEM the kernel
+    never wrote with NaN and checks each access against the DMAs in flight:
+    a block computed from pages not yet fetched, a buffer reused too early
+    or a read of unwritten VMEM would show as a race or as NaN."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, Hkv, D, P, page, mp = 5, 8, 2, 128, 64, 16, 12
+    q = _rand((B, H, D), jnp.bfloat16)
+    kp = _rand((Hkv, P, page, D), jnp.bfloat16)
+    vp = _rand((Hkv, P, page, D), jnp.bfloat16)
+    # empty rows, and 9, 1 and 12 pages: blocks of 8, some partial
+    lengths = np.asarray([0, 130, 7, 0, 185])
+    table = np.full((B, mp), -1, np.int32)
+    for b in range(B):
+        n = -(-int(lengths[b]) // page)
+        table[b, :n] = RNG.choice(P, n, replace=False)
+    table, lengths = jnp.asarray(table), jnp.asarray(lengths, jnp.int32)
+    params = pltpu.InterpretParams(detect_races=True,
+                                   uninitialized_memory="nan")
+    got = np.asarray(paged_attention_pallas(q, kp, vp, table, lengths,
+                                            interpret=params), np.float32)
+    assert not interpret_pallas_call.races.races_found
+    want = np.asarray(ref.paged_attention_ref(q, kp, vp, table, lengths),
+                      np.float32)
+    live = np.asarray(lengths) > 0
+    assert (got[~live] == 0).all()
+    np.testing.assert_allclose(got[live], want[live], atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("lengths", [(0, 0), (1, 16, 17, 0), (5, 300, 64)])
+def test_table_pages_counts_the_built_table(lengths):
+    """``table_pages`` gives the slots of the table ``build_block_table``
+    packs, and the pages of it that the rows' lengths reach."""
+    from repro.kernels.paged_attention import build_block_table, table_pages
+
+    page = 16
+    blocks = [list(range(100 * i, 100 * i + 20)) for i in range(len(lengths))]
+    table, _ = build_block_table(blocks, lengths, page=page)
+    live, slots = table_pages(lengths, page)
+    assert slots == table.size
+    assert live == int((np.asarray(table) >= 0).sum())
+
+
+def test_paged_kernel_reads_page_0_for_a_dead_entry_inside_a_row():
+    """A -1 entry inside a row's length reads page 0, unmasked, as the XLA
+    reference does: the kernel masks by length alone.  (The block-table
+    builder never writes a -1 there.)"""
+    B, H, Hkv, D, P, page = 2, 4, 2, 128, 8, 16
+    q = _rand((B, H, D), jnp.float32)
+    kp = _rand((Hkv, P, page, D), jnp.float32)
+    vp = _rand((Hkv, P, page, D), jnp.float32)
+    table = jnp.asarray([[3, -1, 5, -1], [-1, 6, -1, -1]], jnp.int32)
+    lengths = jnp.asarray([40, 20], jnp.int32)
+    got = paged_attention_pallas(q, kp, vp, table, lengths, interpret=True)
+    want = ref.paged_attention_ref(q, kp, vp, table, lengths)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    # page 0 is read: the same table with 0 in those slots agrees exactly
+    zeroed = paged_attention_pallas(q, kp, vp, jnp.maximum(table, 0),
+                                    lengths, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(zeroed))
 
 
 def test_paged_kernel_ignores_dead_table_entries():

@@ -446,6 +446,46 @@ def test_paged_serving_under_smr_policy(smr):
     assert pool.check_no_leaks()
 
 
+def test_dispatch_span_counts_live_and_table_pages():
+    """Each paged decode step's ``dispatch`` span carries the pages the
+    kernel fetches -- every row's own, up to its length with the fed token
+    -- and the padded table's slots, rows x the longest row's pages."""
+    from repro.obs.trace import Tracer
+
+    tr = Tracer()
+    params = init_params(CFG_PLAIN, jax.random.PRNGKey(6))
+    eng = _engine(CFG_PLAIN, params, "paged", trace=tr)
+    # queued before the engine starts, so the first step admits all three
+    reqs = [eng.submit(p, max_new=3) for p in ([1, 2], [3] * 9, [5] * 14)]
+    eng.start()
+    for r in reqs:
+        assert r.done.wait(timeout=300)
+    eng.stop()
+    assert eng.error is None, f"engine failed: {eng.error!r}"
+    got = [(e["args"]["live_pages"], e["args"]["table_pages"])
+           for e in tr.to_dict()["traceEvents"] if e["name"] == "dispatch"]
+    # stored lengths 2, 9, 14, one more each step; the kernel attends
+    # len+1 positions, so pages of 4 give 1+3+4 live of 3x4 slots twice,
+    # then 2+3+5 of 3x5
+    assert got == [(8, 12), (8, 12), (10, 15)]
+
+
+def test_decode_steps_in_one_bucket_share_a_kernel_program():
+    """Batches of 9 and 13 requests run the paged kernel at one bucketed
+    call shape (16 rows), so its jitted entry point compiles one program
+    for both: set-up loads a program per bucket, not per batch shape."""
+    params = init_params(CFG_PLAIN, jax.random.PRNGKey(8))
+    store = PagedKVStore(CFG_PLAIN, num_blocks=80, page_size=PAGE)
+    before = paged_attention_pallas._cache_size()
+    for B in (9, 13):
+        blocks = [list(range(5 * b, 5 * b + 5)) for b in range(B)]
+        lens = [17 + b % 3 for b in range(B)]            # 5 pages each
+        logits = paged_decode_step(params, CFG_PLAIN, store, blocks, lens,
+                                   [1] * B, impl="interpret")
+        assert logits.shape[0] == B
+    assert paged_attention_pallas._cache_size() == before + 1
+
+
 # ----------------------------------------------------------------------------
 # config gating
 # ----------------------------------------------------------------------------

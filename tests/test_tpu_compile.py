@@ -10,7 +10,8 @@ Covered, at the published widths of two configs the paged path serves
 (bf16 pages, 16-token pages):
 
 * the paged-attention kernel and the paged token scatter at starcoder2-7b
-  (Hkv=4, D=128) and stablelm-12b (Hkv=8, D=160);
+  (Hkv=4, D=128) and stablelm-12b (Hkv=8, D=160), the kernel also at
+  decode table widths 21 and 44;
 * one whole paged decode step of starcoder2-7b cut to 16 layers, against
   a full 4096-page pool, whose ``memory_analysis()`` must fit one v5e's
   16 GiB.
@@ -85,6 +86,22 @@ def test_paged_attention_compiles_for_v5e(one_chip, name, H, Hkv, D):
             _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
             _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
             _sds((B, max_pages), "int32", one_chip),
+            _sds((B,), "int32", one_chip))
+    compiled = jax.jit(paged_attention_pallas).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", [21, 44])
+@pytest.mark.parametrize("name,H,Hkv,D", WIDTHS, ids=[w[0] for w in WIDTHS])
+def test_paged_attention_compiles_at_decode_table_widths(one_chip, name, H,
+                                                         Hkv, D, width):
+    """A 32-row decode batch over tables as wide as the benchmark's
+    (20-44 pages), neither a multiple of the kernel's block of pages."""
+    B = 32
+    args = (_sds((B, H, D), "float32", one_chip),
+            _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
+            _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
+            _sds((B, width), "int32", one_chip),
             _sds((B,), "int32", one_chip))
     compiled = jax.jit(paged_attention_pallas).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
