@@ -7,24 +7,34 @@ pages of the pool to DMA into VMEM next, so the gather happens in the
 memory pipeline, not as a materialized (B, max_pages*page, ...) tensor in
 HBM like the XLA reference.
 
-Page layout: ``(Hkv, P, page, D)`` per layer -- head-major, so a page of
-one head is a ``(page, D)`` tile with the array's own last two dimensions,
-which is what Mosaic's tiling rule asks of a block at any Hkv.
+Page layout: ``(P, page, Hkv * D)`` per layer -- token-major, every KV head
+of a token side by side along the lanes.  A page is one contiguous
+``(page, Hkv * D)`` tile, one DMA, and its minor dimension is lane-dense
+whenever ``Hkv * D`` is a multiple of 128 (stablelm-12b: 8 x 160 = 1280,
+where a head-major ``(page, 160)`` block is eight DMAs and takes 256 lanes
+in VMEM).  Measured alone on a v5e, the kernel and the page scatter ran
+faster on this layout at head dims 160 and 128 alike.
 
 grid = (B, blocks): each grid step folds one block of ``ppb`` pages of a
 row into its online softmax.  ``ppb`` follows from the shapes
 (:func:`_pages_per_block`: 128 tokens' worth, within a VMEM budget).  The
 block's pages are ``ppb`` K and ``ppb`` V operands whose index maps read a
-scalar-prefetched page list, so the pipeline double-buffers their DMAs,
-one DMA per page of every KV head.  Each row fetches its **live pages
-only** -- ``ceil(len / page)`` of them (:func:`live_pages`), not the
-table's width, which the batch's longest row sets: past them an operand
-keeps the page it holds, so the pipeline moves nothing, and the block is
-skipped.  Positions past the length inside the last page are masked.  A -1
-entry inside a row's length reads page 0, as the XLA reference does;
-:func:`build_block_table` never writes one there.  The online softmax
-(m, l, acc) and both matmuls run in f32, all KV heads of a block in one
-batched matmul for the scores and one for the values.
+scalar-prefetched page list (:func:`fetch_plan`), so the pipeline
+double-buffers their DMAs.  Each row fetches its **live pages only** --
+``ceil(len / page)`` of them (:func:`live_pages`), not the table's width,
+which the batch's longest row sets: past them an operand keeps the page it
+holds, so the pipeline moves nothing, and the block is skipped;
+:func:`page_fetches` counts the DMAs a call makes.  Positions past the
+length inside the last page are masked.  A -1 entry inside a row's length
+reads page 0, as the XLA reference does; :func:`build_block_table` never
+writes one there.
+
+Every KV head is scored against its own lanes of a page by one matmul: the
+query enters block-diagonal, ``(H, Hkv * D)`` with row ``h * G + g``
+holding head ``(h, g)``'s query in head ``h``'s lanes and zeros elsewhere,
+and the output leaves as ``(H, Hkv * D)``, of which each row keeps its own
+head's lanes; both are laid out and taken apart by XLA around the kernel.
+The online softmax (m, l, acc) and both matmuls run in f32.
 
 Every (rows, table width) of a call is a program of its own; the serving
 path builds its inputs at :func:`call_shape`'s buckets so that calls share
@@ -85,7 +95,7 @@ def build_block_table(
     *,
     page: int,
     min_pages: int = 1,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[np.ndarray, np.ndarray]:
     """Pack per-request block lists into a padded ``(B, max_pages)`` table.
 
     ``blocks[b]`` is request b's physical page list (block-pool ids, prefix-
@@ -95,7 +105,8 @@ def build_block_table(
     premature gather of an unwritten page can never look valid.  Width is
     max(ceil(len/page)) over the batch, floored at ``min_pages`` so the
     kernel grid never gets a zero-sized axis (an all-empty batch still
-    produces a well-formed (B, min_pages) table of -1s).
+    produces a well-formed (B, min_pages) table of -1s).  Returns numpy
+    int32 ``(table, lengths)``.
     """
     rows: List[List[int]] = []
     for i, (bl, ln) in enumerate(zip(blocks, lengths)):
@@ -111,8 +122,7 @@ def build_block_table(
     table = np.full((len(rows), width), -1, np.int32)
     for i, r in enumerate(rows):
         table[i, :len(r)] = r
-    return (jnp.asarray(table),
-            jnp.asarray(np.asarray(lengths, np.int32)))
+    return table, np.asarray(lengths, np.int32)
 
 
 #: tokens one block of pages should carry at least: one MXU-wide K tile
@@ -127,8 +137,8 @@ def _pages_per_block(page: int, hkv: int, d: int, itemsize: int,
     :data:`_BLOCK_TOKENS` tokens, within :data:`_BLOCK_VMEM` of K/V
     buffers (a page of every KV head, lanes padded to 128), and no more
     than the table is wide."""
-    lanes = -(-d // 128) * 128
-    per_page = 2 * 2 * hkv * page * lanes * itemsize     # K, V x two slots
+    lanes = -(-(hkv * d) // 128) * 128
+    per_page = 2 * 2 * page * lanes * itemsize     # K, V x two slots
     cap = max(1, _BLOCK_VMEM // per_page)
     return max(1, min(-(-_BLOCK_TOKENS // page), cap, width))
 
@@ -144,12 +154,44 @@ def call_shape(rows: int, width: int, *, page: int, hkv: int, d: int,
     return -(-rows // 8) * 8, -(-width // ppb) * ppb
 
 
+def fetch_plan(table, lengths, page: int, ppb: int, xp=np):
+    """``fetch[s, j]``: the page K/V operand ``j`` holds at grid step ``s``
+    (row-major over (rows, blocks)) of a call over ``table`` and
+    ``lengths``.  Past a row's live pages it is the page the operand
+    already holds, so the pipeline moves nothing; a -1 entry inside a
+    row's length reads page 0.  ``xp`` is ``numpy`` or ``jax.numpy``: the
+    kernel steers its DMAs by the second, :func:`page_fetches` counts them
+    by the first."""
+    B, width = table.shape
+    nb = -(-width // ppb)                        # blocks a row walks
+    table = xp.pad(table, ((0, 0), (0, nb * ppb - width)),
+                   constant_values=-1)
+    live = xp.clip(live_pages(lengths, page), 0, width)
+    steps = xp.arange(B * nb, dtype=table.dtype)[:, None]
+    held = (xp.arange(nb * ppb, dtype=table.dtype)[None, :]
+            < live[:, None]).reshape(B * nb, ppb)
+    last = xp.maximum.accumulate(xp.where(held, steps, -1), axis=0)
+    fetch = xp.take_along_axis(table.reshape(B * nb, ppb),
+                               xp.maximum(last, 0), axis=0)
+    return xp.where(last >= 0, xp.maximum(fetch, 0), 0)
+
+
+def page_fetches(table: np.ndarray, lengths: np.ndarray, *, page: int,
+                 hkv: int, d: int, itemsize: int) -> int:
+    """Page DMAs of the K operands of one kernel call over ``table`` and
+    ``lengths`` (the V operands make as many): every operand at the first
+    grid step, then one wherever an operand's page differs from the step
+    before."""
+    ppb = _pages_per_block(page, hkv, d, itemsize, table.shape[1])
+    f = fetch_plan(np.asarray(table), np.asarray(lengths), page, ppb)
+    return int(f.shape[1] + (f[1:] != f[:-1]).sum())
+
+
 def _paged_kernel(fetch_ref, len_ref, live_ref,                   # SMEM
                   q_ref, *refs, page, ppb, scale, softcap):
     """grid = (B, blocks): the pipeline brings block i's pages as ``ppb``
-    K and ``ppb`` V operands, every KV head of a page in one DMA; a block
-    past the row's live pages keeps the pages already resident (no DMA)
-    and is skipped."""
+    K and ``ppb`` V operands; a block past the row's live pages keeps the
+    pages already resident (no DMA) and is skipped."""
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
     o_ref, m_scr, l_scr, acc_scr = refs[2 * ppb:]
     b, i = pl.program_id(0), pl.program_id(1)
@@ -165,18 +207,18 @@ def _paged_kernel(fetch_ref, len_ref, live_ref,                   # SMEM
         bk = ppb * page
         valid = (jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) + i * bk
                  < len_ref[b])
-        # every KV head at once: q (Hkv, G, D), k (Hkv, bk, D), v (Hkv,
-        # bk, Dv), all in f32
+        # q (H, Hkv*D) block-diagonal, k (bk, Hkv*D), v (bk, Hkv*Dv), all
+        # in f32: one matmul scores every head against its own lanes
         k, v = (jnp.concatenate([r[...].astype(jnp.float32) for r in refs_],
-                                axis=1) for refs_ in (k_refs, v_refs))
+                                axis=0) for refs_ in (k_refs, v_refs))
         q = q_ref[0].astype(jnp.float32) * scale
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
         s = jnp.where(valid, s, NEG_INF)
         m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # explicit dead-position zeroing: while a row has no valid position
         # (an empty request in a ragged batch), m_new stays at NEG_INF and
         # exp(s - m_new) would be exp(0) = 1 for every dead slot -- the
@@ -185,10 +227,9 @@ def _paged_kernel(fetch_ref, len_ref, live_ref,                   # SMEM
         p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         m_scr[...] = m_new
-        l_scr[...] = l_prev * corr + jnp.sum(p, axis=2, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+        l_scr[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
 
     # an empty row attends nothing and finishes as exact zeros (l == 0)
     @pl.when(i == pl.num_programs(1) - 1)
@@ -200,8 +241,8 @@ def _paged_kernel(fetch_ref, len_ref, live_ref,                   # SMEM
 @functools.partial(jax.jit, static_argnames=("softcap", "scale", "interpret"))
 def paged_attention_pallas(
     q: jnp.ndarray,               # (B, H, D)
-    k_pages: jnp.ndarray,         # (Hkv, P, page, D)
-    v_pages: jnp.ndarray,         # (Hkv, P, page, Dv)
+    k_pages: jnp.ndarray,         # (P, page, Hkv * D)
+    v_pages: jnp.ndarray,         # (P, page, Hkv * Dv)
     block_table: jnp.ndarray,     # (B, max_pages) int32, -1 padded
     lengths: jnp.ndarray,         # (B,) int32
     *,
@@ -210,34 +251,27 @@ def paged_attention_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, H, D = q.shape
-    Hkv, P, page, _ = k_pages.shape
-    Dv = v_pages.shape[-1]
+    P, page, KD = k_pages.shape
+    Hkv = KD // D
+    VD = v_pages.shape[-1]
+    Dv = VD // Hkv
     max_pages = block_table.shape[1]
     G = H // Hkv
     ppb = _pages_per_block(page, Hkv, max(D, Dv), k_pages.dtype.itemsize,
                            max_pages)
     nb = -(-max_pages // ppb)                    # blocks a row walks
-    table = jnp.pad(block_table.astype(jnp.int32),
-                    ((0, 0), (0, nb * ppb - max_pages)), constant_values=-1)
     lens = lengths.astype(jnp.int32)
     live = jnp.clip(live_pages(lens, page), 0, max_pages)
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    # fetch[s, j]: the page K/V operand j holds at grid step s (row-major
-    # over (B, nb)).  Past the row's live pages it is the page the operand
-    # already holds, so the pipeline moves nothing; a -1 entry inside a
-    # row's length reads page 0
-    steps = jnp.arange(B * nb, dtype=jnp.int32)[:, None]
-    held = (jnp.arange(nb * ppb, dtype=jnp.int32)[None, :]
-            < live[:, None]).reshape(B * nb, ppb)
-    last = jax.lax.cummax(jnp.where(held, steps, -1), axis=0)
-    fetch = jnp.take_along_axis(table.reshape(B * nb, ppb),
-                                jnp.maximum(last, 0), axis=0)
-    fetch = jnp.where(last >= 0, jnp.maximum(fetch, 0), 0)
+    fetch = fetch_plan(block_table.astype(jnp.int32), lens, page, ppb, jnp)
+    # the query block-diagonal over the KV heads: (B, H, Hkv * D)
+    eye = jnp.eye(Hkv, dtype=q.dtype)[None, :, None, :, None]
+    q_bd = (q.reshape(B, Hkv, G, 1, D) * eye).reshape(B, H, KD)
 
-    def page_spec(j, d):
+    def page_spec(j, w):
         return pl.BlockSpec(
-            (Hkv, None, page, d),
-            lambda b, i, f, *_: (0, f[(b * nb + i) * ppb + j], 0, 0))
+            (None, page, w),
+            lambda b, i, f, *_: (f[(b * nb + i) * ppb + j], 0, 0))
 
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page=page, ppb=ppb, scale=scale,
@@ -245,43 +279,43 @@ def paged_attention_pallas(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, nb),
-            in_specs=[pl.BlockSpec((1, Hkv, G, D),
-                                   lambda b, i, *_: (b, 0, 0, 0))]
-            + [page_spec(j, D) for j in range(ppb)]
-            + [page_spec(j, Dv) for j in range(ppb)],
-            out_specs=pl.BlockSpec((1, Hkv, G, Dv),
-                                   lambda b, i, *_: (b, 0, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((Hkv, G, 1), jnp.float32),
-                            pltpu.VMEM((Hkv, G, 1), jnp.float32),
-                            pltpu.VMEM((Hkv, G, Dv), jnp.float32)],
+            in_specs=[pl.BlockSpec((1, H, KD), lambda b, i, *_: (b, 0, 0))]
+            + [page_spec(j, KD) for j in range(ppb)]
+            + [page_spec(j, VD) for j in range(ppb)],
+            out_specs=pl.BlockSpec((1, H, VD), lambda b, i, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, VD), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, VD), q.dtype),
         interpret=interpret,
-    )(fetch.reshape(-1), lens, live, q.reshape(B, Hkv, G, D),
+    )(fetch.reshape(-1), lens, live, q_bd,
       *[k_pages] * ppb, *[v_pages] * ppb)
-    return out.reshape(B, H, Dv)
+    # a row's lanes of the other KV heads weigh their values by its own
+    # head's softmax: the mask keeps its own head's, a sum of one term
+    out = out.reshape(B, Hkv, G, Hkv, Dv) * eye.astype(out.dtype)
+    return out.sum(axis=3).reshape(B, H, Dv)
 
 
 def _scatter_kernel(blk_ref, slot_ref, vals_ref, page_ref, out_ref):
-    # grid=(Hkv, T): the index maps steered this step to the whole
-    # (page, D) tile pages[h, blk[t]].  Tokens bound for one page arrive
+    # grid=(T,): the index maps steered this step to the whole
+    # (page, Hkv * D) tile pages[blk[t]].  Tokens bound for one page arrive
     # back to back, and while the output block index stays put Pallas keeps
     # the tile in VMEM and skips the refetch -- so copy the page in on the
     # first token of a run, then overwrite one row per token.
-    t = pl.program_id(1)
+    t = pl.program_id(0)
     first = jnp.logical_or(t == 0, blk_ref[jnp.maximum(t - 1, 0)] != blk_ref[t])
 
     @pl.when(first)
     def _load():
         out_ref[...] = page_ref[...]
 
-    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[2:], 0)
-    out_ref[0, 0] = jnp.where(row == slot_ref[t], vals_ref[0, 0],
-                              out_ref[0, 0])
+    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 0)
+    out_ref[0] = jnp.where(row == slot_ref[t], vals_ref[0], out_ref[0])
 
 
 def paged_scatter_pallas(
-    pages: jnp.ndarray,           # (Hkv, P, page, D) physical page pool
+    pages: jnp.ndarray,           # (P, page, Hkv * D) physical page pool
     block_idx: jnp.ndarray,       # (T,) destination page per token
     slot_idx: jnp.ndarray,        # (T,) destination slot per token
     vals: jnp.ndarray,            # (T, Hkv, D) token K (or V) vectors
@@ -289,14 +323,13 @@ def paged_scatter_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Token scatter as a Pallas kernel: write T token vectors into the
-    physical page pool at ``pages[:, block_idx[t], slot_idx[t]]`` -- the
+    physical page pool at ``pages[block_idx[t], slot_idx[t]]`` -- the
     write half of the block-table contract :func:`paged_attention_pallas`
     reads.
 
     The destination indices are scalar-prefetch operands driving the
     BlockSpec index maps (the same trick the gather kernel uses for its
-    page DMA).  Each grid step moves a whole ``(page, D)`` tile, the only
-    block shape over this layout that the TPU tiling rule admits, and
+    page DMA).  Each grid step moves a whole ``(page, Hkv * D)`` tile and
     rewrites one row of it.  ``input_output_aliases`` makes the pool buffer
     the output buffer: untouched pages are preserved and -- when the caller
     donates ``pages`` under jit, as the device KV storage does -- the
@@ -308,22 +341,22 @@ def paged_scatter_pallas(
     the tokens are first stably sorted by page: each page's writes become
     one run, and of two writes to one slot the later still wins.
     """
-    T, Hkv, D = vals.shape
-    page = pages.shape[2]
+    T = vals.shape[0]
+    _, page, W = pages.shape
     order = jnp.argsort(block_idx, stable=True)
     block_idx, slot_idx, vals = block_idx[order], slot_idx[order], vals[order]
     return pl.pallas_call(
         _scatter_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,        # block_idx + slot_idx
-            grid=(Hkv, T),
+            grid=(T,),
             in_specs=[
-                pl.BlockSpec((1, 1, 1, D), lambda h, t, blk, slot: (t, h, 0, 0)),
-                pl.BlockSpec((1, 1, page, D),
-                             lambda h, t, blk, slot: (h, blk[t], 0, 0)),
+                pl.BlockSpec((1, 1, W), lambda t, blk, slot: (t, 0, 0)),
+                pl.BlockSpec((1, page, W),
+                             lambda t, blk, slot: (blk[t], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, page, D),
-                                   lambda h, t, blk, slot: (h, blk[t], 0, 0)),
+            out_specs=pl.BlockSpec((1, page, W),
+                                   lambda t, blk, slot: (blk[t], 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
         # flattened input index 3 = pages (after the 2 prefetch operands
@@ -331,4 +364,4 @@ def paged_scatter_pallas(
         input_output_aliases={3: 0},
         interpret=interpret,
     )(block_idx.astype(jnp.int32), slot_idx.astype(jnp.int32),
-      vals.astype(pages.dtype)[:, :, None, :], pages)
+      vals.astype(pages.dtype).reshape(T, 1, W), pages)

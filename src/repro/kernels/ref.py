@@ -142,8 +142,8 @@ def decode_attention_ref(
 
 def paged_attention_ref(
     q: jnp.ndarray,              # (B, H, D)
-    k_pages: jnp.ndarray,        # (Hkv, P, page, D)  -- the shared block pool
-    v_pages: jnp.ndarray,        # (Hkv, P, page, Dv)
+    k_pages: jnp.ndarray,        # (P, page, Hkv * D)  -- the shared block pool
+    v_pages: jnp.ndarray,        # (P, page, Hkv * Dv)
     block_table: jnp.ndarray,    # (B, max_pages) int32 page ids (-1 pad)
     lengths: jnp.ndarray,        # (B,) valid tokens per sequence
     *,
@@ -151,14 +151,13 @@ def paged_attention_ref(
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
     B, H, D = q.shape
-    Hkv, _, page, _ = k_pages.shape
+    _, page, KD = k_pages.shape
+    Hkv = KD // D
     max_pages = block_table.shape[1]
     safe_table = jnp.maximum(block_table, 0)
-    # (Hkv, B, max_pages, page, D) -> (B, max_pages * page, Hkv, D)
-    k = jnp.moveaxis(k_pages[:, safe_table], 0, -2)
-    v = jnp.moveaxis(v_pages[:, safe_table], 0, -2)
-    k = k.reshape(B, max_pages * page, Hkv, D)
-    v = v.reshape(B, max_pages * page, Hkv, v.shape[-1])
+    # (B, max_pages, page, Hkv * D) -> (B, max_pages * page, Hkv, D)
+    k = k_pages[safe_table].reshape(B, max_pages * page, Hkv, D)
+    v = v_pages[safe_table].reshape(B, max_pages * page, Hkv, -1)
     return decode_attention_ref(q[:, None], k, v, lengths,
                                 softcap=softcap, scale=scale)[:, 0]
 

@@ -51,7 +51,8 @@ def _layer_specs(cfg: ArchConfig, spec: LayerSpec) -> Dict[str, Any]:
         s["norm_cm"] = Spec((D,), ("embed",), "zeros")
         s["rwkv"] = rwkv6_specs(cfg)
     if spec.mlp == "dense":
-        s["norm2"] = Spec((D,), ("embed",), "zeros")
+        if not spec.parallel:           # a parallel MLP shares norm1
+            s["norm2"] = Spec((D,), ("embed",), "zeros")
         s["mlp"] = L.mlp_specs(D, cfg.d_ff, cfg.act)
         if cfg.post_norms:
             s["post_norm2"] = Spec((D,), ("embed",), "zeros")
@@ -205,7 +206,9 @@ def _remat(cfg: ArchConfig, fn):
 
 def _apply_layer(lp, spec: LayerSpec, x, *, cfg, mode, lcache, pos, kv_source,
                  shared_params, layer_idx=None):
-    """One pattern-position layer. Returns (x, new_lcache, aux)."""
+    """One pattern-position layer. Returns (x, new_lcache, aux).  With
+    ``spec.parallel`` the dense MLP reads the attention's own norm of the
+    layer's input: ``x + attn(norm1(x)) + mlp(norm1(x))``."""
     aux = {"moe_aux": jnp.zeros((), jnp.float32),
            "moe_z": jnp.zeros((), jnp.float32)}
     new_cache: Dict[str, Any] = {}
@@ -261,7 +264,8 @@ def _apply_layer(lp, spec: LayerSpec, x, *, cfg, mode, lcache, pos, kv_source,
             new_cache["shared_v"] = c["v"]
 
     if spec.mlp == "dense":
-        h = L.rms_norm(x, lp["norm2"], cfg.norm_eps)
+        if not spec.parallel:
+            h = L.rms_norm(x, lp["norm2"], cfg.norm_eps)
         o = L.mlp_apply(lp["mlp"], h, cfg.act)
         if cfg.post_norms:
             o = L.rms_norm(o, lp["post_norm2"], cfg.norm_eps)

@@ -6,10 +6,10 @@ allocation, ownership, reader sessions, and (through the pluggable
 retired block may be recycled.  :class:`PagedKVStore` owns the block
 *contents*: one physical K page and one V page per (layer, block id), laid
 out exactly as ``kernels/paged_attention.py`` consumes them --
-``(Hkv, num_blocks, page, hd)`` per layer, head-major so the kernel's
-``(page, hd)`` tile is a whole trailing block -- so a decode step gathers
-shared prefix pages physically through the block table instead of
-replaying a per-request dense cache.
+``(num_blocks, page, Hkv * hd)`` per layer, token-major so a page is one
+contiguous tile whose lanes stay dense at a head dim that is not a
+multiple of 128 -- so a decode step gathers shared prefix pages physically
+through the block table instead of replaying a per-request dense cache.
 
 Lifecycle of a physical page (mirrors the paper's retire/ping/free cycle;
 see docs/ARCHITECTURE.md):
@@ -109,7 +109,7 @@ class _HostPages:
     kind = "host"
 
     def __init__(self, L, num_blocks, page, Hkv, hd, dtype):
-        self.k = np.zeros((L, Hkv, num_blocks, page, hd), dtype)
+        self.k = np.zeros((L, num_blocks, page, Hkv * hd), dtype)
         self.v = np.zeros_like(self.k)
         self.bytes_h2d = 0
         self.bytes_d2h = 0
@@ -127,18 +127,18 @@ class _HostPages:
         k, v = np.asarray(k), np.asarray(v)
         blk = np.asarray(blk, np.int64)
         slot = np.asarray(slot, np.int64)
-        # token-major (.., T, Hkv, hd) values -> the head-major pages
+        # (.., T, Hkv, hd) values -> one (Hkv * hd) page row per token
         if layer is None:
-            self.k[:, :, blk, slot] = np.swapaxes(k, 1, 2)
-            self.v[:, :, blk, slot] = np.swapaxes(v, 1, 2)
+            self.k[:, blk, slot] = k.reshape(k.shape[:2] + (-1,))
+            self.v[:, blk, slot] = v.reshape(v.shape[:2] + (-1,))
         else:
-            self.k[layer][:, blk, slot] = np.swapaxes(k, 0, 1)
-            self.v[layer][:, blk, slot] = np.swapaxes(v, 0, 1)
+            self.k[layer][blk, slot] = k.reshape(len(k), -1)
+            self.v[layer][blk, slot] = v.reshape(len(v), -1)
 
     def fill(self, blocks, value: float) -> None:
         bl = list(blocks)
-        self.k[:, :, bl] = value
-        self.v[:, :, bl] = value
+        self.k[:, bl] = value
+        self.v[:, bl] = value
 
     def layer(self, li):
         import jax.numpy as jnp
@@ -157,6 +157,10 @@ class _HostPages:
     def nbytes(self) -> int:
         return self.k.nbytes + self.v.nbytes
 
+    @property
+    def device_bytes(self) -> int:
+        return self.nbytes
+
 
 @functools.cache
 def _device_fns():
@@ -169,12 +173,12 @@ def _device_fns():
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def scatter(pages, blk, slot, vals):
-        # vals (T, Hkv, hd) -> pages[:, blk[t], slot[t]] (Hkv, T, hd)
-        return pages.at[:, blk, slot].set(jnp.swapaxes(vals, 0, 1))
+        # vals (T, Hkv, hd) -> pages[blk[t], slot[t]] (T, Hkv * hd)
+        return pages.at[blk, slot].set(vals.reshape(vals.shape[0], -1))
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def fill(pages, blk, value):
-        return pages.at[:, blk].set(value)
+        return pages.at[blk].set(value)
 
     return scatter, fill
 
@@ -220,9 +224,9 @@ class _DevicePages:
         self.L = L
         self.dtype = dtype
         self.scatter_impl = scatter_impl
-        self.k = [jnp.zeros((Hkv, num_blocks, page, hd), dtype)
+        self.k = [jnp.zeros((num_blocks, page, Hkv * hd), dtype)
                   for _ in range(L)]
-        self.v = [jnp.zeros((Hkv, num_blocks, page, hd), dtype)
+        self.v = [jnp.zeros((num_blocks, page, Hkv * hd), dtype)
                   for _ in range(L)]
         self.bytes_h2d = 0
         self.bytes_d2h = 0
@@ -295,6 +299,13 @@ class _DevicePages:
         return sum(int(a.nbytes) for a in self.k) + \
             sum(int(a.nbytes) for a in self.v)
 
+    @property
+    def device_bytes(self) -> int:
+        # the arrays as the device lays them out, with any padding of a
+        # dimension to the device's tile
+        return sum(int(a.on_device_size_in_bytes()) for a in self.k) + \
+            sum(int(a.on_device_size_in_bytes()) for a in self.v)
+
 
 class PagedKVStore:
     """Physical page arrays for K and V, keyed by BlockPool block ids.
@@ -311,13 +322,19 @@ class PagedKVStore:
     ``"host"`` keeps the numpy reference implementation, ``"device"`` holds
     the pages as jax device arrays updated in place with buffer donation.
     ``scatter_impl`` ("jnp" | "pallas") picks the device write primitive.
+
+    Given a ``metrics`` registry, the store counts once, at construction,
+    the bytes its page arrays take on the device (``kv_pool_device_bytes``,
+    padding included) and the bytes of their elements
+    (``kv_pool_element_bytes``).
     """
 
     #: freed-page fill value (finite on purpose; see :meth:`on_free`)
     POISON = 1e9
 
     def __init__(self, cfg, num_blocks: int, page_size: int, dtype=None,
-                 storage: str = "host", scatter_impl: str = "jnp"):
+                 storage: str = "host", scatter_impl: str = "jnp",
+                 metrics=None):
         if storage not in ("host", "device"):
             raise ValueError(
                 f"storage must be 'host' or 'device', got {storage!r}")
@@ -345,6 +362,13 @@ class PagedKVStore:
         self.bytes_written = 0          # KV bytes physically written
         self.poisons = 0                # pages poisoned (freed under the store)
         self.token_bytes = int(2 * L * Hkv * hd * dtype.itemsize)
+        # what the pool takes on the device, padding included, and the K
+        # and V bytes one page of one layer moves when the kernel fetches it
+        self.device_bytes = self._st.device_bytes
+        self.page_fetch_bytes = self.device_bytes // (L * num_blocks)
+        if metrics is not None:
+            metrics.counter("kv_pool_device_bytes").inc(self.device_bytes)
+            metrics.counter("kv_pool_element_bytes").inc(self.nbytes)
 
     # ------------------------------------------------------------------
     # pool listener hooks (wired via BlockPool.add_block_listener)
@@ -479,18 +503,8 @@ class PagedKVStore:
         if bad:
             raise UseAfterFree(engine, min(bad), "kv-gather")
 
-    def gather_table(self, blocks: Sequence[Sequence[int]],
-                     lengths: Sequence[int], *, min_pages: int = 1):
-        """Padded block-table rows for a ragged batch of requests -- the
-        kernel-facing view of the pool's block lists.  Delegates to
-        :func:`repro.kernels.paged_attention.build_block_table` so the
-        layout contract lives in one place."""
-        from repro.kernels.paged_attention import build_block_table
-        return build_block_table(blocks, lengths, page=self.page,
-                                 min_pages=min_pages)
-
     def layer_pages(self, layer: int):
-        """The (Hkv, num_blocks, page, hd) K and V page arrays of one
+        """The (num_blocks, page, Hkv * hd) K and V page arrays of one
         layer, as jax arrays ready for the kernel.  Host storage uploads
         the layer (and meters it as ``bytes_h2d``); device storage returns
         the resident arrays -- zero bytes moved."""

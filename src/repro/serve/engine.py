@@ -165,7 +165,8 @@ class ServeEngine:
                 raise ValueError(f"kernel_impl must be 'pallas', 'interpret' "
                                  f"or 'xla', got {self.kernel_impl!r}")
             self.kv_store = PagedKVStore(cfg, pool.num_blocks, page_size,
-                                         storage=kv_storage)
+                                         storage=kv_storage,
+                                         metrics=self.metrics)
             pool.add_block_listener(self.kv_store)
         # one jitted decode shared by every worker (JAX execution is
         # thread-safe; the compile cache is shared)

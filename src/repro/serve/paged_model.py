@@ -51,7 +51,7 @@ from repro.models.model import apply_model
 from repro.runtime.kv_store import PagedKVStore, kv_layer_order
 
 __all__ = ["check_paged_support", "prefill_kv", "prefill_kv_chunked",
-           "prefill_chunk_step", "paged_decode_step"]
+           "prefill_chunk_step", "paged_decode_step", "kv_fetch_bytes"]
 
 
 def check_paged_support(cfg: ArchConfig) -> None:
@@ -153,10 +153,12 @@ def _qkv(gp, rep, x, positions, *, cfg: ArchConfig, rows: int):
             k[:, 0], v[:, 0])
 
 
-@functools.partial(jax.jit, static_argnames="cfg")
-def _out_mlp(gp, rep, x, attn, *, cfg: ArchConfig):
-    """Output projection + residual, then the MLP block + residual;
-    ``attn``'s rows past ``x``'s are padding, dropped."""
+@functools.partial(jax.jit, static_argnames=("cfg", "parallel"))
+def _out_mlp(gp, rep, x, attn, *, cfg: ArchConfig, parallel: bool):
+    """Output projection + residual, then the MLP block + residual: off a
+    second norm of the new residual, or with ``parallel`` off the
+    attention's own norm of the layer's input ``x``.  ``attn``'s rows past
+    ``x``'s are padding, dropped."""
     lp = _layer(gp, rep)
     B = x.shape[0]
     attn = attn[:B]
@@ -166,8 +168,11 @@ def _out_mlp(gp, rep, x, attn, *, cfg: ArchConfig):
                    lp["attn"]["wo"])
     if cfg.post_norms:
         o = rms_norm(o, lp["post_norm1"], cfg.norm_eps)
+    if parallel:
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
     x = x + o
-    h = rms_norm(x, lp["norm2"], cfg.norm_eps)
+    if not parallel:
+        h = rms_norm(x, lp["norm2"], cfg.norm_eps)
     o = L.mlp_apply(lp["mlp"], h, cfg.act)
     if cfg.post_norms:
         o = rms_norm(o, lp["post_norm2"], cfg.norm_eps)
@@ -183,6 +188,41 @@ def _logits(final_norm, head, x, *, cfg: ArchConfig):
         pad_mask = jnp.arange(cfg.vocab_padded) < cfg.vocab
         logits = jnp.where(pad_mask, logits, -1e30)
     return logits[:, 0]
+
+
+def _call_inputs(store: PagedKVStore, cfg: ArchConfig, blocks, lens):
+    """``(rows, table, att)``: the attention call's row count, numpy block
+    table and attended lengths for rows ``blocks``/``lens``, at the call's
+    bucket of shapes (padded rows empty, so calls share one program per
+    bucket).  Each row attends its fed token too."""
+    from repro.kernels.paged_attention import (build_block_table,
+                                               call_shape, live_pages)
+
+    att = np.asarray(lens, np.int64) + 1
+    B = len(att)
+    rows, width = call_shape(B, int(live_pages(att, store.page).max()),
+                             page=store.page, hkv=cfg.n_kv_heads,
+                             d=cfg.head_dim_,
+                             itemsize=jnp.dtype(cfg.dtype).itemsize)
+    att = np.pad(att, (0, rows - B))
+    table, att = build_block_table(list(blocks) + [[]] * (rows - B), att,
+                                   page=store.page, min_pages=width)
+    return rows, table, att
+
+
+def kv_fetch_bytes(store: PagedKVStore, cfg: ArchConfig, blocks,
+                   lens: Sequence[int]) -> int:
+    """Device bytes of the K and V pages that the attention calls of a
+    paged forward over rows ``blocks``/``lens`` fetch, summed over layers:
+    each page at its size on the device, padding included, as often as
+    the kernel's DMAs move it (:func:`~repro.kernels.paged_attention.
+    page_fetches`)."""
+    from repro.kernels.paged_attention import page_fetches
+
+    _, table, att = _call_inputs(store, cfg, blocks, lens)
+    n = page_fetches(table, att, page=store.page, hkv=cfg.n_kv_heads,
+                     d=cfg.head_dim_, itemsize=jnp.dtype(cfg.dtype).itemsize)
+    return n * store.page_fetch_bytes * len(store.layer_order)
 
 
 def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
@@ -201,21 +241,12 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
     i attends only to positions < lens[i] + 1.
     """
     from repro.kernels import ops as kops
-    from repro.kernels.paged_attention import call_shape, live_pages
 
-    lens_np = np.asarray(lens, np.int64)
-    B, att = len(lens_np), lens_np + 1
     # the table and lengths (and q, in _qkv) at the attention call's bucket
-    # of shapes, padded rows empty: calls share one program per bucket
-    rows, width = call_shape(B, int(live_pages(att, store.page).max()),
-                             page=store.page, hkv=cfg.n_kv_heads,
-                             d=cfg.head_dim_,
-                             itemsize=jnp.dtype(cfg.dtype).itemsize)
-    att = np.pad(att, (0, rows - B))
-    table, _ = store.gather_table(list(blocks) + [[]] * (rows - B), att,
-                                  min_pages=width)
-    att_lens = jnp.asarray(att, jnp.int32)
-    positions = jnp.asarray(lens_np, jnp.int32)[:, None]     # (B,1)
+    # of shapes
+    rows, table, att = _call_inputs(store, cfg, blocks, lens)
+    table, att_lens = jnp.asarray(table), jnp.asarray(att)
+    positions = jnp.asarray(np.asarray(lens), jnp.int32)[:, None]   # (B,1)
     x = _embed(params["embed"], jnp.asarray(list(tokens), jnp.int32),
                cfg=cfg)                                      # (B,1,D)
     scale = (1.0 / math.sqrt(cfg.attn_scale) if cfg.attn_scale
@@ -223,6 +254,7 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
 
     for li, (gi, pi, rep) in enumerate(store.layer_order):
         gp = params["groups"][f"g{gi}"][f"p{pi}"]
+        parallel = cfg.groups[gi].pattern[pi].parallel
         q, k, v = _qkv(gp, rep, x, positions, cfg=cfg, rows=rows)
         # physical write: every row's K/V lands in its page BEFORE the
         # gather, so each new position attends to itself (and, in a prefill
@@ -238,7 +270,7 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
             out = kops.paged_attention(
                 q, k_pages, v_pages, table, att_lens,        # q (B, H, hd)
                 softcap=cfg.attn_softcap, scale=scale, impl=impl)
-        x = _out_mlp(gp, rep, x, out, cfg=cfg)
+        x = _out_mlp(gp, rep, x, out, cfg=cfg, parallel=parallel)
 
     head = params.get("lm_head")
     if head is None:

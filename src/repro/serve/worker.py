@@ -424,7 +424,7 @@ class _PoolActor:
         """Chunked paged prefill: one batched forward per chunk through the
         paged kernel (prefix-shared and earlier-chunk pages gathered in
         place), pages written incrementally via write_prefill(start=)."""
-        from repro.serve.paged_model import prefill_kv_chunked
+        from repro.serve.paged_model import kv_fetch_bytes, prefill_kv_chunked
 
         store = self.kv_store
         hit = r.cache_hit
@@ -438,8 +438,12 @@ class _PoolActor:
             with tr.span("prefill_chunk", cat="serve") as sp:
                 end, _ = next(chunks)
                 if tr.enabled:
+                    # every chunk row is a row of the attention call
                     sp.set_args({"rid": r.rid, "start": r.prefilled,
-                                 "end": end})
+                                 "end": end, "kv_bytes": kv_fetch_bytes(
+                                     store, self.cfg,
+                                     [r.all_blocks] * (end - r.prefilled),
+                                     range(r.prefilled, end))})
             written = (end - r.prefilled) * store.token_bytes
             self.prefill_tokens += end - r.prefilled
             self.prefill_chunks += 1
@@ -698,6 +702,7 @@ class EngineWorker(_PoolActor):
         every running request becomes a block-table row over the shared
         physical pages -- ragged lengths, prefix pages included in place."""
         from repro.serve.paged_model import (decode_table_pages,
+                                             kv_fetch_bytes,
                                              paged_decode_step)
 
         tr = self.tracer
@@ -717,7 +722,9 @@ class EngineWorker(_PoolActor):
                                        impl=self.kernel_impl)
             if tr.enabled:
                 live, slots = decode_table_pages(self.kv_store, lens)
-                sp.set_args({"live_pages": live, "table_pages": slots})
+                sp.set_args({"live_pages": live, "table_pages": slots,
+                             "kv_bytes": kv_fetch_bytes(
+                                 self.kv_store, self.cfg, blocks, lens)})
         # the host waits for the device to finish the step
         with tr.span("token_sync", cat="serve"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))

@@ -122,9 +122,20 @@ PAGED_CASES = [
     (2, 36, 4, 128, 24, 16, 5, None, False),
     # stablelm-12b's KV heads and head dim
     (2, 16, 8, 160, 16, 16, 3, None, False),
+    # stablelm-12b's grouping (G = 4) at head dim 160: a ragged decode
+    # batch with an empty row, and a prefill chunk
+    (4, 32, 8, 160, 48, 16, 10, (150, 0, 17, 33), False),
+    (8, 32, 8, 160, 16, 16, 6, tuple(range(86, 94)), True),
     # a prefill chunk: 8 rows over one block list, lengths start+1..start+8
     (8, 8, 2, 128, 16, 16, 6, tuple(range(71, 79)), True),
+    # starcoder2's grouping in a prefill chunk
+    (8, 36, 4, 128, 16, 16, 4, tuple(range(40, 48)), True),
 ]
+
+
+def _pages(Hkv, P, page, D, dtype):
+    """A random page pool in the kernel's layout, ``(P, page, Hkv * D)``."""
+    return _rand((P, page, Hkv * D), dtype)
 
 
 @pytest.mark.parametrize("case", PAGED_CASES)
@@ -132,8 +143,8 @@ PAGED_CASES = [
 def test_paged_attention_kernel_vs_oracle(case, dtype):
     B, H, Hkv, D, P, page, max_pages, lengths, shared = case
     q = _rand((B, H, D), dtype)
-    k_pages = _rand((Hkv, P, page, D), dtype)
-    v_pages = _rand((Hkv, P, page, D), dtype)
+    k_pages = _pages(Hkv, P, page, D, dtype)
+    v_pages = _pages(Hkv, P, page, D, dtype)
     drawn = lengths is None
     if drawn:
         lengths = RNG.integers(1, page * max_pages, (B,))
@@ -156,7 +167,7 @@ def test_paged_attention_kernel_vs_oracle(case, dtype):
     read = np.zeros(P, bool)
     for b in range(B):
         read[np.asarray(table)[b, :live[b]]] = True
-    dead = jnp.asarray(~read)[None, :, None, None]
+    dead = jnp.asarray(~read)[:, None, None]
     k_pages = jnp.where(dead, jnp.asarray(1e9, dtype), k_pages)
     v_pages = jnp.where(dead, jnp.asarray(1e9, dtype), v_pages)
     got = paged_attention_pallas(q, k_pages, v_pages, table, lengths,
@@ -178,8 +189,8 @@ def test_paged_kernel_under_tpu_interpreter_is_race_free():
 
     B, H, Hkv, D, P, page, mp = 5, 8, 2, 128, 64, 16, 12
     q = _rand((B, H, D), jnp.bfloat16)
-    kp = _rand((Hkv, P, page, D), jnp.bfloat16)
-    vp = _rand((Hkv, P, page, D), jnp.bfloat16)
+    kp = _pages(Hkv, P, page, D, jnp.bfloat16)
+    vp = _pages(Hkv, P, page, D, jnp.bfloat16)
     # empty rows, and 9, 1 and 12 pages: blocks of 8, some partial
     lengths = np.asarray([0, 130, 7, 0, 185])
     table = np.full((B, mp), -1, np.int32)
@@ -213,14 +224,33 @@ def test_table_pages_counts_the_built_table(lengths):
     assert live == int((np.asarray(table) >= 0).sum())
 
 
+def test_fetch_plan_on_the_host_is_the_kernels():
+    """The numpy fetch plan that counts the kernel's page DMAs is the one
+    the kernel steers them by: ragged rows, empty rows, -1 entries inside
+    a row and rows that share one block list."""
+    import jax.numpy as jnp_
+    from repro.kernels.paged_attention import fetch_plan
+
+    for trial in range(20):
+        B, width, page, ppb = 1 + trial % 6, 1 + trial % 9, 16, 1 + trial % 4
+        table = RNG.integers(-1, 50, (B, width)).astype(np.int32)
+        if trial % 3 == 0:
+            table[:] = table[0]
+        lengths = RNG.integers(0, page * width + 1, B).astype(np.int32)
+        want = fetch_plan(jnp_.asarray(table), jnp_.asarray(lengths), page,
+                          ppb, jnp_)
+        np.testing.assert_array_equal(
+            fetch_plan(table, lengths, page, ppb), np.asarray(want))
+
+
 def test_paged_kernel_reads_page_0_for_a_dead_entry_inside_a_row():
     """A -1 entry inside a row's length reads page 0, unmasked, as the XLA
     reference does: the kernel masks by length alone.  (The block-table
     builder never writes a -1 there.)"""
     B, H, Hkv, D, P, page = 2, 4, 2, 128, 8, 16
     q = _rand((B, H, D), jnp.float32)
-    kp = _rand((Hkv, P, page, D), jnp.float32)
-    vp = _rand((Hkv, P, page, D), jnp.float32)
+    kp = _pages(Hkv, P, page, D, jnp.float32)
+    vp = _pages(Hkv, P, page, D, jnp.float32)
     table = jnp.asarray([[3, -1, 5, -1], [-1, 6, -1, -1]], jnp.int32)
     lengths = jnp.asarray([40, 20], jnp.int32)
     got = paged_attention_pallas(q, kp, vp, table, lengths, interpret=True)
@@ -237,13 +267,13 @@ def test_paged_kernel_ignores_dead_table_entries():
     """Pages past a sequence's length (or -1 slots) must not contribute."""
     B, H, D, P, page, mp = 1, 2, 32, 8, 8, 4
     q = _rand((B, H, D), jnp.float32)
-    kp = _rand((H, P, page, D), jnp.float32)
-    vp = _rand((H, P, page, D), jnp.float32)
+    kp = _pages(H, P, page, D, jnp.float32)
+    vp = _pages(H, P, page, D, jnp.float32)
     table = jnp.asarray([[3, 5, -1, -1]], jnp.int32)
     lengths = jnp.asarray([12], jnp.int32)
     got = paged_attention_pallas(q, kp, vp, table, lengths, interpret=True)
     # poison the dead pages: result must be identical
-    kp2 = kp.at[:, 6].set(1e9)
-    vp2 = vp.at[:, 6].set(1e9)
+    kp2 = kp.at[6].set(1e9)
+    vp2 = vp.at[6].set(1e9)
     got2 = paged_attention_pallas(q, kp2, vp2, table, lengths, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(got2))

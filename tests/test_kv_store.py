@@ -191,7 +191,7 @@ def test_poison_on_unsafe_free_trips_gather(storage):
     with pytest.raises(UseAfterFree):
         store.assert_alive(1, blocks)
     # the page contents themselves are poisoned too (belt and braces)
-    assert float(np.max(store.k[:, :, blocks[0]])) >= PagedKVStore.POISON
+    assert float(np.max(store.k[:, blocks[0]])) >= PagedKVStore.POISON
 
 
 def test_safe_policy_keeps_pages_alive_under_session():
@@ -288,11 +288,11 @@ def test_pallas_scatter_matches_jnp_scatter():
     identical pools (and both leave untouched pages untouched)."""
     from repro.kernels.paged_attention import paged_scatter_pallas
     P, page, Hkv, D, T = 6, 4, 2, 16, 7
-    pages = jnp.asarray(RNG.normal(size=(Hkv, P, page, D)), jnp.float32)
+    pages = jnp.asarray(RNG.normal(size=(P, page, Hkv * D)), jnp.float32)
     blk = jnp.asarray(RNG.integers(0, P, T), jnp.int32)
     slot = jnp.asarray(RNG.integers(0, page, T), jnp.int32)
     vals = jnp.asarray(RNG.normal(size=(T, Hkv, D)), jnp.float32)
-    want = pages.at[:, blk, slot].set(jnp.swapaxes(vals, 0, 1))
+    want = pages.at[blk, slot].set(vals.reshape(T, Hkv * D))
     got = paged_scatter_pallas(pages, blk, slot, vals, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -303,13 +303,67 @@ def test_pallas_scatter_takes_pages_in_any_order():
     across consecutive tokens, so the wrapper must regroup them."""
     from repro.kernels.paged_attention import paged_scatter_pallas
     P, page, Hkv, D = 5, 4, 2, 16
-    pages = jnp.asarray(RNG.normal(size=(Hkv, P, page, D)), jnp.float32)
+    pages = jnp.asarray(RNG.normal(size=(P, page, Hkv * D)), jnp.float32)
     blk = jnp.asarray([3, 1, 3, 0, 1, 3], jnp.int32)
     slot = jnp.asarray([0, 2, 1, 3, 0, 2], jnp.int32)
     vals = jnp.asarray(RNG.normal(size=(6, Hkv, D)), jnp.float32)
-    want = pages.at[:, blk, slot].set(jnp.swapaxes(vals, 0, 1))
+    want = pages.at[blk, slot].set(vals.reshape(6, Hkv * D))
     got = paged_scatter_pallas(pages, blk, slot, vals, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+@pytest.mark.parametrize("Hkv,hd", [(8, 160), (4, 128)])
+def test_store_writes_and_fills_token_major_pages(Hkv, hd, impl):
+    """A device store's prefill writes, decode appends, poison and zeroing
+    land where a plain numpy model of the ``(P, page, Hkv * hd)`` layout
+    puts them, with either scatter."""
+    cfg = ArchConfig(name="kv-wide", d_model=64, n_heads=2 * Hkv,
+                     n_kv_heads=Hkv, head_dim=hd, d_ff=64, vocab=64,
+                     groups=dense_stack(2), remat="none", dtype="float32")
+    L, P, W = 2, 6, Hkv * hd
+    store = PagedKVStore(cfg, num_blocks=P, page_size=PAGE,
+                         storage="device", scatter_impl=impl)
+    want = {"k": np.zeros((L, P, PAGE, W), np.float32),
+            "v": np.zeros((L, P, PAGE, W), np.float32)}
+    # a 6-token prefill into pages 4 then 1, all layers at once
+    kv = {n: RNG.normal(size=(L, 6, Hkv, hd)).astype(np.float32)
+          for n in "kv"}
+    store.write_prefill([4, 1], kv["k"], kv["v"])
+    for n in "kv":
+        for pos in range(6):
+            want[n][:, [4, 1][pos // PAGE], pos % PAGE] = \
+                kv[n][:, pos].reshape(L, W)
+    # one decode append of two rows into layer 1
+    kv = {n: RNG.normal(size=(2, Hkv, hd)).astype(np.float32) for n in "kv"}
+    store.append_tokens([1, 3], [2, 0], jnp.asarray(kv["k"]),
+                        jnp.asarray(kv["v"]), layer=1)
+    for n in "kv":
+        want[n][1, 1, 2] = kv[n][0].reshape(W)
+        want[n][1, 3, 0] = kv[n][1].reshape(W)
+    store.on_free([3])
+    store.on_alloc([4])
+    for n in "kv":
+        want[n][:, 3] = PagedKVStore.POISON
+        want[n][:, 4] = 0.0
+    np.testing.assert_array_equal(np.asarray(store.k), want["k"])
+    np.testing.assert_array_equal(np.asarray(store.v), want["v"])
+
+
+@pytest.mark.parametrize("storage", ["host", "device"])
+def test_store_counts_its_device_and_element_bytes(storage):
+    """The store puts the pool's bytes on the registry once, at
+    construction; off a TPU nothing is padded."""
+    from repro.obs import MetricsRegistry
+
+    reg = MetricsRegistry()
+    store = PagedKVStore(CFG_FANCY, num_blocks=8, page_size=PAGE,
+                         storage=storage, metrics=reg)
+    got = reg.counters()
+    assert got["kv_pool_element_bytes"] == store.nbytes > 0
+    assert got["kv_pool_device_bytes"] == store.device_bytes == store.nbytes
+    L = len(store.layer_order)
+    assert store.page_fetch_bytes * L * 8 == store.device_bytes
 
 
 def test_device_store_pallas_scatter_matches_jnp_scatter():
@@ -369,7 +423,7 @@ def test_block_table_empty_request_row_yields_zeros():
     (not NaN, not a mean over masked junk)."""
     P, page, H, D = 8, 4, 2, 32
     q = _rand((2, H, D))
-    kp, vp = _rand((H, P, page, D)), _rand((H, P, page, D))
+    kp, vp = _rand((P, page, H * D)), _rand((P, page, H * D))
     table, lens = build_block_table([[], [3, 5]], [0, 6], page=page)
     out = paged_attention_pallas(q, kp, vp, table, lens, interpret=True)
     assert np.all(np.isfinite(np.asarray(out)))
@@ -382,7 +436,7 @@ def test_block_table_all_empty_batch():
     assert table.shape == (2, 1)               # min_pages floor
     assert np.all(np.asarray(table) == -1)
     q = _rand((2, 2, 32))
-    kp = _rand((2, 4, 4, 32))                  # (Hkv, P, page, D)
+    kp = _rand((4, 4, 2 * 32))                 # (P, page, Hkv * D)
     out = paged_attention_pallas(q, kp, kp, table, lens, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), 0.0)
 
@@ -402,7 +456,7 @@ def test_single_token_tail_page_and_max_pages_parity():
     from repro.kernels import ref
     P, page, H, D = 16, 4, 2, 32
     q = _rand((2, H, D))
-    kp, vp = _rand((H, P, page, D)), _rand((H, P, page, D))
+    kp, vp = _rand((P, page, H * D)), _rand((P, page, H * D))
     blocks = [[3], [8, 9, 10, 11]]
     lens = [1, 16]                             # tail page of 1; max pages
     table, lengths = build_block_table(blocks, lens, page=page)
@@ -468,6 +522,50 @@ def test_dispatch_span_counts_live_and_table_pages():
     # len+1 positions, so pages of 4 give 1+3+4 live of 3x4 slots twice,
     # then 2+3+5 of 3x5
     assert got == [(8, 12), (8, 12), (10, 15)]
+
+
+def test_kv_fetch_bytes_counts_the_kernels_page_dmas():
+    """Bytes of the pages the attention calls fetch, by hand: the first
+    grid step loads every operand of the block (page 0 where a row holds
+    fewer), later steps only the operands whose page changes, and rows
+    of a prefill chunk that hold the same pages fetch nothing anew."""
+    from repro.serve.paged_model import kv_fetch_bytes
+
+    store = PagedKVStore(CFG_PLAIN, num_blocks=40, page_size=PAGE)
+    # one K + V page of one layer: 2 x 4 tokens x 2 heads x 8 x 4 B
+    assert store.page_fetch_bytes == 512
+    per = store.page_fetch_bytes * 2                        # two layers
+    # decode: 1, 3 and 4 live pages, blocks of 4 pages, rows padded to 8:
+    # [5, 0, 0, 0] (4 DMAs), then 3 and 4 changed operands
+    got = kv_fetch_bytes(store, CFG_PLAIN,
+                         [[5], [7, 8, 9], [10, 11, 12, 13]], [2, 9, 14])
+    assert got == (4 + 3 + 4) * per
+    # a 4-row chunk over one list: 8, 8, 9, 9 live pages in a block of 9:
+    # 9 DMAs, then page 28 once
+    got = kv_fetch_bytes(store, CFG_PLAIN, [list(range(20, 29))] * 4,
+                         range(30, 34))
+    assert got == (9 + 1) * per
+
+
+def test_spans_carry_the_kv_bytes_they_fetch():
+    """Each traced ``dispatch`` and ``prefill_chunk`` span has a
+    ``kv_bytes`` arg: whole pages of every layer."""
+    from repro.obs.trace import Tracer
+
+    tr = Tracer()
+    params = init_params(CFG_PLAIN, jax.random.PRNGKey(6))
+    eng = _engine(CFG_PLAIN, params, "paged", trace=tr, prefill_chunk=4)
+    _run(eng, [[1, 2, 3, 4, 5, 6], [3] * 9], max_new=3)
+    eng.stop()
+    assert eng.error is None, f"engine failed: {eng.error!r}"
+    unit = eng.kv_store.page_fetch_bytes * 2
+    got = {}
+    for e in tr.to_dict()["traceEvents"]:
+        if e["name"] in ("dispatch", "prefill_chunk"):
+            got.setdefault(e["name"], []).append(e["args"]["kv_bytes"])
+    assert len(got["prefill_chunk"]) == 2 + 3 and len(got["dispatch"]) >= 3
+    for b in got["dispatch"] + got["prefill_chunk"]:
+        assert b > 0 and b % unit == 0
 
 
 def test_decode_steps_in_one_bucket_share_a_kernel_program():

@@ -211,7 +211,7 @@ def test_partial_prefill_resumable_across_workers():
     k, v = prefill_kv(params, cfg, prompt)
     ref.write_prefill(r.all_blocks, k, v)
     for b_idx in r.all_blocks:
-        np.testing.assert_allclose(ref.k[:, :, b_idx], store.k[:, :, b_idx],
+        np.testing.assert_allclose(ref.k[:, b_idx], store.k[:, b_idx],
                                    atol=2e-5, rtol=2e-5)
     pool.retire(1, r.blocks)
     pool.reclaim(1)

@@ -14,7 +14,7 @@ Covered, at the published widths of two configs the paged path serves
   decode table widths 21 and 44;
 * one whole paged decode step of starcoder2-7b cut to 16 layers, against
   a full 4096-page pool, whose ``memory_analysis()`` must fit one v5e's
-  16 GiB.
+  16 GiB, and one of stablelm-12b cut to 12, which must leave room.
 
 The topology is described inside module fixtures, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -31,8 +31,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs.base import dense_stack  # noqa: E402
 from repro.configs.registry import get_config  # noqa: E402
-from repro.kernels.paged_attention import (build_block_table,  # noqa: E402
-                                           paged_attention_pallas,
+from repro.kernels.paged_attention import (paged_attention_pallas,  # noqa: E402
                                            paged_scatter_pallas)
 from repro.models.model import abstract_params  # noqa: E402
 from repro.runtime.kv_store import _device_fns, kv_layer_order  # noqa: E402
@@ -83,8 +82,8 @@ def _sds(shape, dtype, sharding):
 def test_paged_attention_compiles_for_v5e(one_chip, name, H, Hkv, D):
     B, max_pages = 8, 32
     args = (_sds((B, H, D), "float32", one_chip),            # q (served f32)
-            _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
-            _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
+            _sds((POOL_PAGES, PAGE, Hkv * D), "bfloat16", one_chip),
+            _sds((POOL_PAGES, PAGE, Hkv * D), "bfloat16", one_chip),
             _sds((B, max_pages), "int32", one_chip),
             _sds((B,), "int32", one_chip))
     compiled = jax.jit(paged_attention_pallas).lower(*args).compile()
@@ -99,8 +98,8 @@ def test_paged_attention_compiles_at_decode_table_widths(one_chip, name, H,
     (20-44 pages), neither a multiple of the kernel's block of pages."""
     B = 32
     args = (_sds((B, H, D), "float32", one_chip),
-            _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
-            _sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
+            _sds((POOL_PAGES, PAGE, Hkv * D), "bfloat16", one_chip),
+            _sds((POOL_PAGES, PAGE, Hkv * D), "bfloat16", one_chip),
             _sds((B, width), "int32", one_chip),
             _sds((B,), "int32", one_chip))
     compiled = jax.jit(paged_attention_pallas).lower(*args).compile()
@@ -111,7 +110,8 @@ def test_paged_attention_compiles_at_decode_table_widths(one_chip, name, H,
 def test_paged_scatter_compiles_for_v5e(one_chip, name, H, Hkv, D):
     T = 64                                   # one prefill chunk of tokens
     fn = jax.jit(paged_scatter_pallas, donate_argnums=(0,))
-    compiled = fn.lower(_sds((Hkv, POOL_PAGES, PAGE, D), "bfloat16", one_chip),
+    compiled = fn.lower(_sds((POOL_PAGES, PAGE, Hkv * D), "bfloat16",
+                             one_chip),
                         _sds((T,), "int32", one_chip),
                         _sds((T,), "int32", one_chip),
                         _sds((T, Hkv, D), "bfloat16", one_chip)).compile()
@@ -131,10 +131,6 @@ class _TracedPages:
     def write_guard(self):
         return contextlib.nullcontext()
 
-    def gather_table(self, blocks, lengths, *, min_pages=1):
-        return build_block_table(blocks, lengths, page=self.page,
-                                 min_pages=min_pages)
-
     def append_tokens(self, blocks, slots, k, v, layer):
         scatter = _device_fns()[0]
         blk, slot = jnp.asarray(blocks, jnp.int32), jnp.asarray(slots, jnp.int32)
@@ -145,15 +141,12 @@ class _TracedPages:
         return self.k[li], self.v[li]
 
 
-def test_starcoder2_decode_step_fits_one_v5e(one_chip):
-    """One batched paged decode step of starcoder2-7b at full width, 16
-    layers, 8 requests of 512 tokens each over a 4096-page pool: the
-    program compiles for the chip and its weights + pool + temporaries fit
-    16 GiB (the pool is donated, so it is not counted twice)."""
-    cfg = get_config("starcoder2_7b").scaled(
-        groups=dense_stack(DECODE_LAYERS))
+def _decode_step_bytes(one_chip, cfg, B, ctx):
+    """(device bytes, weight bytes) of one batched paged decode step of
+    ``cfg`` compiled for the chip: ``B`` requests of ``ctx`` tokens over a
+    full ``POOL_PAGES`` pool.  Weights, pool and temporaries are counted
+    once (the pool is donated)."""
     L = len(kv_layer_order(cfg))
-    B, ctx = 8, 512
     per = ctx // PAGE + 1
     blocks = [list(range(b * per, (b + 1) * per)) for b in range(B)]
     lens, last = [ctx] * B, [1] * B
@@ -166,7 +159,7 @@ def test_starcoder2_decode_step_fits_one_v5e(one_chip):
 
     params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
                           abstract_params(cfg))
-    pages = [_sds((cfg.n_kv_heads, POOL_PAGES, PAGE, cfg.head_dim_),
+    pages = [_sds((POOL_PAGES, PAGE, cfg.n_kv_heads * cfg.head_dim_),
                   cfg.dtype, one_chip)] * L
     compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
         params, pages, pages).compile()
@@ -176,8 +169,28 @@ def test_starcoder2_decode_step_fits_one_v5e(one_chip):
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
     weights = sum(s.size * s.dtype.itemsize
                   for s in jax.tree.leaves(params))
+    return total, weights
+
+
+def test_starcoder2_decode_step_fits_one_v5e(one_chip):
+    """One batched paged decode step of starcoder2-7b at full width, 16
+    layers, 8 requests of 512 tokens each over a 4096-page pool: the
+    program compiles for the chip and its weights + pool + temporaries fit
+    16 GiB (the pool is donated, so it is not counted twice)."""
+    cfg = get_config("starcoder2_7b").scaled(
+        groups=dense_stack(DECODE_LAYERS))
+    total, weights = _decode_step_bytes(one_chip, cfg, 8, 512)
     assert weights > 7 * GiB                      # really full width
-    assert total < 16 * GiB, (
-        f"decode step needs {total / GiB:.2f} GiB: args "
-        f"{m.argument_size_in_bytes / GiB:.2f}, temp "
-        f"{m.temp_size_in_bytes / GiB:.2f}")
+    assert total < 16 * GiB, f"decode step needs {total / GiB:.2f} GiB"
+
+
+def test_stablelm12_decode_step_leaves_room_on_one_v5e(one_chip):
+    """stablelm-12b at full width, 12 layers (the benchmark's cut), a
+    32-row decode step at 704 tokens over a 4096-page pool of head dim
+    160: under 14.5 GB of the chip's 16.9, which a pool whose 160 lanes
+    padded to 256 (6.4 GB of K/V instead of 4.0) could not be."""
+    cfg = get_config("stablelm_12b").scaled(
+        groups=dense_stack(12, parallel=True))
+    total, weights = _decode_step_bytes(one_chip, cfg, 32, 704)
+    assert weights > 8 * GiB                      # really full width
+    assert total < 14.5e9, f"decode step needs {total / 1e9:.2f} GB"
