@@ -51,9 +51,11 @@ the ``storage`` seam:
   copy -- verified in the tests via ``unsafe_buffer_pointer`` stability),
   or optionally a Pallas scatter kernel
   (:func:`repro.kernels.paged_attention.paged_scatter_pallas`) sharing the
-  paged-attention kernel's block layout.  ``layer_pages`` hands the
-  RESIDENT arrays straight to the kernel -- zero host->device bytes per
-  step -- and poison-on-free / zero-on-alloc become device fills at the
+  paged-attention kernel's block layout.  The paged forward writes a
+  step's tokens inside its own program instead (``write_in``: the layer's
+  arrays donated into that program and taken back).  ``layer_pages``
+  hands the RESIDENT arrays straight to the kernel -- zero host->device
+  bytes per step -- and poison-on-free / zero-on-alloc become device fills at the
   same pool-listener choke points, so the UseAfterFree tripwire semantics
   are identical on both storages.
 
@@ -162,6 +164,12 @@ class _HostPages:
         return self.nbytes
 
 
+def _set_rows(pages, blk, slot, vals):
+    """The jnp page write: vals (T, Hkv, hd) -> pages[blk[t], slot[t]]
+    (T, Hkv * hd)."""
+    return pages.at[blk, slot].set(vals.reshape(vals.shape[0], -1))
+
+
 @functools.cache
 def _device_fns():
     """Jitted in-place page updaters, built lazily so importing this module
@@ -169,12 +177,8 @@ def _device_fns():
     aliases the incoming pool buffer into the output, so a scatter/fill is
     a true in-place update of the resident pages, not an O(pool) copy."""
     import jax
-    import jax.numpy as jnp
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def scatter(pages, blk, slot, vals):
-        # vals (T, Hkv, hd) -> pages[blk[t], slot[t]] (T, Hkv * hd)
-        return pages.at[blk, slot].set(vals.reshape(vals.shape[0], -1))
+    scatter = jax.jit(_set_rows, donate_argnums=(0,))
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def fill(pages, blk, value):
@@ -184,19 +188,20 @@ def _device_fns():
 
 
 @functools.cache
+def _pallas_write():
+    """The Pallas page write, in this process's kernel mode: one object, so
+    a program that takes it as a static argument compiles once."""
+    from repro.kernels.paged_attention import paged_impl, paged_scatter_pallas
+
+    return functools.partial(paged_scatter_pallas,
+                             interpret=paged_impl() == "interpret")
+
+
+@functools.cache
 def _pallas_scatter_fn():
     import jax
 
-    from repro.kernels.paged_attention import paged_impl, paged_scatter_pallas
-
-    interpret = paged_impl() == "interpret"
-
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def scatter(pages, blk, slot, vals):
-        return paged_scatter_pallas(pages, blk, slot, vals,
-                                    interpret=interpret)
-
-    return scatter
+    return jax.jit(_pallas_write(), donate_argnums=(0,))
 
 
 class _DevicePages:
@@ -207,10 +212,10 @@ class _DevicePages:
     Concurrency: donation invalidates the OLD buffer object, so a
     read-modify-write race between two writers -- or a reader that fetched
     a layer just before a writer donated it -- would raise "array deleted".
-    ``guard()`` (an RLock, also taken by every scatter/fill) is the store's
-    contract: the paged forward holds it across its
-    write -> fetch -> kernel-dispatch window per layer, which is exactly
-    the span in which a stale reference could exist."""
+    ``guard()`` (an RLock, also taken by every scatter/fill/write_in) is
+    the store's contract: the paged forward holds it across its whole
+    write -> fetch -> kernel-dispatch sequence, which is exactly the span
+    in which a stale reference could exist."""
 
     kind = "device"
 
@@ -224,6 +229,8 @@ class _DevicePages:
         self.L = L
         self.dtype = dtype
         self.scatter_impl = scatter_impl
+        # the page write a forward's own program traces in (``write_in``)
+        self.write = _set_rows if scatter_impl == "jnp" else _pallas_write()
         self.k = [jnp.zeros((num_blocks, page, Hkv * hd), dtype)
                   for _ in range(L)]
         self.v = [jnp.zeros((num_blocks, page, Hkv * hd), dtype)
@@ -263,6 +270,12 @@ class _DevicePages:
                                    jnp.asarray(k, self.dtype))
                 self.v[layer] = sc(self.v[layer], bidx, sidx,
                                    jnp.asarray(v, self.dtype))
+
+    def write_in(self, layer, program):
+        with self._lock:
+            self.k[layer], self.v[layer], out = program(
+                self.write, self.k[layer], self.v[layer])
+        return out
 
     def fill(self, blocks, value: float) -> None:
         import jax.numpy as jnp
@@ -415,7 +428,7 @@ class PagedKVStore:
         return blk, pos % self.page
 
     def write_prefill(self, blocks: Sequence[int], k, v,
-                      start: int = 0, layer: Optional[int] = None) -> int:
+                      start: int = 0) -> int:
         """Write a prefilled token range into ``blocks``.
 
         ``k``/``v``: ``(L, T, Hkv, hd)`` -- the per-layer post-rope K/V of T
@@ -423,22 +436,14 @@ class PagedKVStore:
         prefill cache leaves, see serve/paged_model.py).  ``blocks`` is the
         request's page list from position 0, so token ``start + i`` lands in
         ``blocks[(start + i) // page]`` slot ``(start + i) % page``.
-        Returns the number of bytes written.
-
-        With a ``layer`` index, ``k``/``v`` are ``(T, Hkv, hd)`` slices of
-        that single layer: the chunked-prefill forward
-        (serve/paged_model.py) writes each layer's chunk right before that
-        layer's page gather, so ``start=`` is how prefill lands in the pages
-        incrementally, chunk by chunk, instead of one whole-prompt write.
-        Accepts numpy or jax arrays; on device storage, device-resident
-        inputs scatter with zero host traffic.
+        Returns the number of bytes written.  Accepts numpy or jax arrays;
+        on device storage, device-resident inputs scatter with zero host
+        traffic.
         """
-        T = k.shape[1] if layer is None else k.shape[0]
+        T = k.shape[1]
         blk, slot = self._token_coords(blocks, start, T)
-        self._st.scatter(layer, blk, slot, k, v)
-        nl = len(self.layer_order) if layer is None else 1
-        written = int(2 * T * nl * (self.token_bytes //
-                                    (2 * len(self.layer_order))))
+        self._st.scatter(None, blk, slot, k, v)
+        written = int(T * self.token_bytes)
         self.bytes_written += written
         return written
 
@@ -469,6 +474,22 @@ class PagedKVStore:
         self.bytes_written += written
         return written
 
+    def write_in(self, layer: int, tokens: int, program):
+        """Hand ``layer``'s K and V page arrays to ``program``, one jitted
+        program that donates them, writes ``tokens`` tokens into them and
+        returns them: ``program(write, k_pages, v_pages) -> (k_pages,
+        v_pages, out)``, where ``write(pages, blk, slot, vals)`` is the
+        storage's page write (the jnp or Pallas scatter) for the program to
+        trace in.  The store keeps the returned arrays and this returns
+        ``out``.  Device storage only: host pages are written on the host
+        (:meth:`append_tokens`)."""
+        if self.storage != "device":
+            raise ValueError("write_in needs device storage")
+        out = self._st.write_in(layer, program)
+        self.bytes_written += tokens * self.token_bytes // len(
+            self.layer_order)
+        return out
+
     @property
     def _itemsize(self) -> int:
         L, Hkv, hd = (len(self.layer_order), self.cfg.n_kv_heads,
@@ -476,8 +497,8 @@ class PagedKVStore:
         return self.token_bytes // (2 * L * Hkv * hd)
 
     def write_guard(self):
-        """Context manager the paged forward holds across its per-layer
-        write -> fetch -> kernel-dispatch window.  A no-op for host storage;
+        """Context manager the paged forward holds across its whole
+        write -> fetch -> kernel-dispatch sequence.  A no-op for host storage;
         for device storage it is the RLock that makes in-place buffer
         donation safe against a concurrent writer invalidating the fetched
         page arrays (see :class:`_DevicePages`)."""

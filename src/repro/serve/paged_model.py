@@ -17,27 +17,29 @@ weight-tied shared attention keep using the dense path;
 :func:`check_paged_support` rejects them up front so the failure mode is a
 clear error at engine construction, not silent wrong math.
 
-The per-layer loop runs at host level (a page-store write sits between the
-projection math and the kernel call), so this is NOT one jitted function;
-the projection and MLP halves of a layer are jitted programs shared by all
-layers, and the kernel runs in the mode each entry point's required
-``impl`` names (``kernels.paged_attention.paged_impl()`` gives compiled
-Pallas on a TPU and interpret mode elsewhere; there is no default).  The
-data plane, however, is storage-aware end to end: the new K/V stay jax
-arrays from projection to
-:meth:`PagedKVStore.append_tokens`/``write_prefill`` (under device storage
-that is a donated in-place scatter with ZERO host traffic), and
-``layer_pages`` hands the kernel the store's resident arrays -- no
-per-layer, per-step pool re-upload.  Each layer's
-write -> gather -> kernel-dispatch span runs under
-:meth:`PagedKVStore.write_guard` so a concurrent writer's buffer donation
-can never invalidate the pages mid-dispatch.
+The layer loop runs at host level, two launches a layer: the kernel call
+(``kops.paged_attention``, compiled Pallas on a TPU and interpret mode
+elsewhere, as each entry point's required ``impl`` names; there is no
+default), and one jitted program for everything between two kernel calls
+-- layer i's output projection and MLP, then layer i+1's projections and
+its K/V page write (``_mlp_qkv``; ``_embed_qkv`` before the first layer,
+``_mlp_logits`` after the last).  The step's table, lengths, positions,
+tokens and write indices go to the device in one ``jax.device_put``.  The
+data plane is storage-aware end to end: under device storage a layer's
+page arrays are donated into the program that writes them
+(:meth:`PagedKVStore.write_in`) -- an in-place write with ZERO host
+traffic -- and ``layer_pages`` hands the kernel the store's resident
+arrays; host storage (the reference) writes on the host between the two
+programs.  The whole forward runs under :meth:`PagedKVStore.write_guard`,
+so a concurrent writer's buffer donation can never invalidate the pages
+mid-step.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+import threading
 from typing import List, Sequence, Tuple
 
 import jax
@@ -51,7 +53,8 @@ from repro.models.model import apply_model
 from repro.runtime.kv_store import PagedKVStore, kv_layer_order
 
 __all__ = ["check_paged_support", "prefill_kv", "prefill_kv_chunked",
-           "prefill_chunk_step", "paged_decode_step", "kv_fetch_bytes"]
+           "prefill_chunk_step", "paged_decode_step", "kv_fetch_bytes",
+           "forward_counts"]
 
 
 def check_paged_support(cfg: ArchConfig) -> None:
@@ -112,12 +115,12 @@ def prefill_kv(params, cfg: ArchConfig,
 # ----------------------------------------------------------------------------
 
 
-# The layer math around the page write and the kernel call, jitted per
-# half-layer.  ``gp`` is a group's stacked weights and ``rep`` a traced
-# layer index, so one program serves every layer and the layer's weights
-# are sliced inside it: an eager ``a[rep]`` would copy each layer's weights
-# per call, and a host loop running ahead of the device would hold many
-# such copies at once.
+# The layer math, jitted per part.  ``gp`` is a group's stacked weights
+# and ``rep`` a traced layer index, so one program serves every layer and
+# the layer's weights are sliced inside it: an eager ``a[rep]`` would copy
+# each layer's weights per call, and a host loop running ahead of the
+# device would hold many such copies at once.  The forward launches them
+# fused (``_embed_qkv``, ``_mlp_qkv``, ``_mlp_logits``, below).
 
 
 def _layer(gp, rep):
@@ -225,57 +228,161 @@ def kv_fetch_bytes(store: PagedKVStore, cfg: ArchConfig, blocks,
     return n * store.page_fetch_bytes * len(store.layer_order)
 
 
+def _project(qkv, gp, rep, x, positions, pages, blk, slot, *, cfg, rows,
+             write):
+    """Layer ``rep``'s q, and its K/V: written into ``pages`` by ``write``
+    (``(q, k_pages, v_pages)``), or returned for the host to write when
+    ``write`` is None (``(q, k, v)``)."""
+    q, k, v = qkv(gp, rep, x, positions, cfg=cfg, rows=rows)
+    if write is None:
+        return q, k, v
+    kp, vp = pages
+    return q, write(kp, blk, slot, k), write(vp, blk, slot, v)
+
+
+# The programs of a forward.  The layer math comes in as static callables,
+# read from this module when the forward runs: a replaced ``_qkv``,
+# ``_out_mlp`` or ``_logits`` is a new static value, so the programs trace
+# it anew.  ``pages`` (a layer's K and V arrays) are donated: XLA aliases
+# them into the outputs, so the write is in place.
+
+
+@functools.partial(jax.jit, donate_argnames="pages",
+                   static_argnames=("cfg", "rows", "qkv", "write"))
+def _embed_qkv(embed, toks, gp, rep, positions, pages, blk, slot, *,
+               cfg: ArchConfig, rows: int, qkv, write):
+    """Before the first kernel call: embed the fed tokens, then layer
+    ``rep``'s projections and page write."""
+    x = _embed(embed, toks, cfg=cfg)
+    return (x,) + _project(qkv, gp, rep, x, positions, pages, blk, slot,
+                           cfg=cfg, rows=rows, write=write)
+
+
+@functools.partial(jax.jit, donate_argnames="pages",
+                   static_argnames=("cfg", "rows", "parallel", "out_mlp",
+                                    "qkv", "write"))
+def _mlp_qkv(gp, rep, x, attn, next_gp, next_rep, positions, pages, blk,
+             slot, *, cfg: ArchConfig, rows: int, parallel: bool, out_mlp,
+             qkv, write):
+    """Between two kernel calls: layer ``rep``'s output projection and MLP,
+    then layer ``next_rep``'s projections and page write."""
+    x = out_mlp(gp, rep, x, attn, cfg=cfg, parallel=parallel)
+    return (x,) + _project(qkv, next_gp, next_rep, x, positions, pages, blk,
+                           slot, cfg=cfg, rows=rows, write=write)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "parallel", "out_mlp", "logits"))
+def _mlp_logits(gp, rep, x, attn, final_norm, head, embed, *,
+                cfg: ArchConfig, parallel: bool, out_mlp, logits):
+    """After the last kernel call: its layer's output projection and MLP,
+    then the logits, off ``head`` or, tied (``head`` None), ``embed``."""
+    x = out_mlp(gp, rep, x, attn, cfg=cfg, parallel=parallel)
+    return logits(final_norm, embed.T if head is None else head, x, cfg=cfg)
+
+
+@functools.cache
+def _device_ints(n: int):
+    """Device scalars 0..n-1, the layer indices the programs take: put on
+    the device once, where a Python int would be uploaded on every call.
+    Concrete even when first asked for inside a trace, so the cache never
+    keeps a tracer."""
+    with jax.ensure_compile_time_eval():
+        return tuple(jax.device_put(np.int32(i)) for i in range(n))
+
+
+_counts = threading.local()
+
+
+def forward_counts() -> dict:
+    """``{"launches", "uploads"}`` of the calling thread's last paged
+    forward: the programs it launched (kernel calls included) and the
+    ``jax.device_put`` calls it made."""
+    return {"launches": _counts.launches, "uploads": _counts.uploads}
+
+
 def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
-                   blocks, lens, tokens, *, impl: str,
-                   write_layer) -> jnp.ndarray:
+                   blocks, lens, tokens, *, impl: str) -> jnp.ndarray:
     """The transformer loop both paged entry points share: embed the fed
-    tokens (one per row), and per layer project -> rope -> hand the new K/V
-    to ``write_layer`` (which scatters them into the physical pages) ->
-    gather through the padded block table with the paged-attention kernel.
+    tokens (one per row), and per layer project -> rope -> write the new
+    K/V into the physical pages -> gather through the padded block table
+    with the paged-attention kernel.
 
     ``blocks``/``lens``/``tokens`` are per-ROW: a decode step has one row
     per request (each its own block list); a prefill chunk has one row per
     chunk position, all rows sharing ONE block list with consecutive
-    positions.  Causality is the kernel's length masking: row i's K/V is in
-    the pages before any row gathers (``write_layer`` runs first), and row
-    i attends only to positions < lens[i] + 1.
+    positions.  Row r's token lands at position ``lens[r]``: page
+    ``blocks[r][lens[r] // page]``, slot ``lens[r] % page``.  Causality is
+    the kernel's length masking: every row's K/V is in the pages before any
+    row gathers, and row r attends only to positions < lens[r] + 1.
     """
     from repro.kernels import ops as kops
 
+    page = store.page
     # the table and lengths (and q, in _qkv) at the attention call's bucket
     # of shapes
     rows, table, att = _call_inputs(store, cfg, blocks, lens)
-    table, att_lens = jnp.asarray(table), jnp.asarray(att)
-    positions = jnp.asarray(np.asarray(lens), jnp.int32)[:, None]   # (B,1)
-    x = _embed(params["embed"], jnp.asarray(list(tokens), jnp.int32),
-               cfg=cfg)                                      # (B,1,D)
+    pos = np.asarray(lens, np.int32)
+    blk = np.asarray([bl[p // page] for bl, p in zip(blocks, pos)],
+                     np.int32)
+    slot = pos % page
+    table, att, positions, toks, blk_d, slot_d = jax.device_put(
+        (table, att, pos[:, None], np.asarray(tokens, np.int32), blk, slot))
+    order = store.layer_order
+    reps = _device_ints(1 + max(rep for _, _, rep in order))
     scale = (1.0 / math.sqrt(cfg.attn_scale) if cfg.attn_scale
              else 1.0 / math.sqrt(cfg.head_dim_))
+    # the layer math as it stands in this module now
+    qkv, out_mlp, logits = _qkv, _out_mlp, _logits
+    on_device = store.storage == "device"
 
-    for li, (gi, pi, rep) in enumerate(store.layer_order):
-        gp = params["groups"][f"g{gi}"][f"p{pi}"]
-        parallel = cfg.groups[gi].pattern[pi].parallel
-        q, k, v = _qkv(gp, rep, x, positions, cfg=cfg, rows=rows)
-        # physical write: every row's K/V lands in its page BEFORE the
-        # gather, so each new position attends to itself (and, in a prefill
-        # chunk, to its chunk-mates) exactly like the dense path -- model
-        # dtype preserved end to end.  The K/V stay jax arrays: under
-        # device storage the scatter and the gather both run against the
-        # RESIDENT pages (no host round trip), and the guard keeps a
-        # concurrent writer's buffer donation from invalidating the pages
-        # between fetch and kernel dispatch.
-        with store.write_guard():
-            write_layer(li, k, v)                            # (B, Hkv, hd)
+    def layer_in(li, program, *args, **static):
+        """Launch ``program`` with layer ``li``'s pages: ``(x, q)``.  Under
+        device storage the program writes the layer's K/V into its donated
+        pages; under host storage the host writes them after it."""
+        if not on_device:
+            x, q, k, v = program(*args, None, blk_d, slot_d, write=None,
+                                 **static)
+            store.append_tokens(blk, slot, k, v, layer=li)  # (B, Hkv, hd)
+            return x, q
+
+        def run(write, kp, vp):
+            x, q, kp, vp = program(*args, (kp, vp), blk_d, slot_d,
+                                   write=write, **static)
+            return kp, vp, (x, q)
+        return store.write_in(li, len(pos), run)
+
+    def weights(li):
+        gi, pi, rep = order[li]
+        return (params["groups"][f"g{gi}"][f"p{pi}"], reps[rep],
+                cfg.groups[gi].pattern[pi].parallel)
+
+    with store.write_guard():
+        gp, rep, parallel = weights(0)
+        x, q = layer_in(0, _embed_qkv, params["embed"], toks, gp, rep,
+                        positions, cfg=cfg, rows=rows, qkv=qkv)
+        launches = 1
+        for li in range(len(order)):
             k_pages, v_pages = store.layer_pages(li)
             out = kops.paged_attention(
-                q, k_pages, v_pages, table, att_lens,        # q (B, H, hd)
+                q, k_pages, v_pages, table, att,             # q (rows, H, hd)
                 softcap=cfg.attn_softcap, scale=scale, impl=impl)
-        x = _out_mlp(gp, rep, x, out, cfg=cfg, parallel=parallel)
-
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    return _logits(params["final_norm"], head, x, cfg=cfg)
+            if li + 1 < len(order):
+                ngp, nrep, nparallel = weights(li + 1)
+                x, q = layer_in(li + 1, _mlp_qkv, gp, rep, x, out, ngp, nrep,
+                                positions, cfg=cfg, rows=rows,
+                                parallel=parallel, out_mlp=out_mlp, qkv=qkv)
+                gp, rep, parallel = ngp, nrep, nparallel
+            else:
+                result = _mlp_logits(gp, rep, x, out, params["final_norm"],
+                                     params.get("lm_head"), params["embed"],
+                                     cfg=cfg, parallel=parallel,
+                                     out_mlp=out_mlp, logits=logits)
+            launches += 2
+    _counts.launches = launches
+    # host storage uploads each layer's K and V pages for the kernel
+    _counts.uploads = 1 if on_device else 1 + 2 * len(order)
+    return result
 
 
 # ----------------------------------------------------------------------------
@@ -306,22 +413,14 @@ def paged_decode_step(
     """One batched decode step for a ragged batch of requests.
 
     For each request the fed token's K/V is appended at page slot
-    ``lens[b]`` (ONE batched scatter per layer into the shared physical
+    ``lens[b]`` (ONE batched write per layer into the shared physical
     pool, not a per-request loop), then every layer's attention gathers
     through the padded block table -- prefix-shared pages are read in
     place, whichever engine wrote them.  Returns the ``(B, vocab_padded)``
     logits of the new position.
     """
-    page = store.page
-    lens_np = np.asarray(lens, np.int64)
-    blk = [blocks[b][int(p) // page] for b, p in enumerate(lens_np)]
-    slot = [int(p) % page for p in lens_np]
-
-    def write_layer(li, k_b, v_b):                           # (B, Hkv, hd)
-        store.append_tokens(blk, slot, k_b, v_b, layer=li)
-
     return _paged_forward(params, cfg, store, blocks, lens, last_tokens,
-                          impl=impl, write_layer=write_layer)
+                          impl=impl)
 
 
 # ----------------------------------------------------------------------------
@@ -343,8 +442,8 @@ def prefill_chunk_step(
     over one shared block table (the ROADMAP's "q block x page gather").
 
     Row i carries prompt position ``start + i``; its K/V is written into
-    the physical pages (one :meth:`PagedKVStore.write_prefill` slice per
-    layer, ``start=`` addressed) before any row gathers, and the kernel's
+    the physical pages (one batched write per layer, as a decode step's)
+    before any row gathers, and the kernel's
     per-row length mask (``att_len = start + i + 1``) keeps attention
     causal within the chunk while earlier chunks -- and prefix-shared pages
     -- are gathered in place.  Returns the ``(chunk, vocab_padded)`` logits
@@ -353,12 +452,7 @@ def prefill_chunk_step(
     c = len(tokens)
     rows = [list(blocks)] * c
     lens = list(range(start, start + c))
-
-    def write_layer(li, k_c, v_c):                            # (c, Hkv, hd)
-        store.write_prefill(blocks, k_c, v_c, start=start, layer=li)
-
-    return _paged_forward(params, cfg, store, rows, lens, tokens,
-                          impl=impl, write_layer=write_layer)
+    return _paged_forward(params, cfg, store, rows, lens, tokens, impl=impl)
 
 
 def prefill_kv_chunked(

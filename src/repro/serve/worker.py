@@ -423,8 +423,9 @@ class _PoolActor:
     def _prefill_paged(self, r: Request) -> bool:
         """Chunked paged prefill: one batched forward per chunk through the
         paged kernel (prefix-shared and earlier-chunk pages gathered in
-        place), pages written incrementally via write_prefill(start=)."""
-        from repro.serve.paged_model import kv_fetch_bytes, prefill_kv_chunked
+        place), each chunk's K/V written into the pages by its own forward."""
+        from repro.serve.paged_model import (forward_counts, kv_fetch_bytes,
+                                             prefill_kv_chunked)
 
         store = self.kv_store
         hit = r.cache_hit
@@ -443,7 +444,8 @@ class _PoolActor:
                                  "end": end, "kv_bytes": kv_fetch_bytes(
                                      store, self.cfg,
                                      [r.all_blocks] * (end - r.prefilled),
-                                     range(r.prefilled, end))})
+                                     range(r.prefilled, end)),
+                                 **forward_counts()})
             written = (end - r.prefilled) * store.token_bytes
             self.prefill_tokens += end - r.prefilled
             self.prefill_chunks += 1
@@ -702,7 +704,7 @@ class EngineWorker(_PoolActor):
         every running request becomes a block-table row over the shared
         physical pages -- ragged lengths, prefix pages included in place."""
         from repro.serve.paged_model import (decode_table_pages,
-                                             kv_fetch_bytes,
+                                             forward_counts, kv_fetch_bytes,
                                              paged_decode_step)
 
         tr = self.tracer
@@ -724,7 +726,8 @@ class EngineWorker(_PoolActor):
                 live, slots = decode_table_pages(self.kv_store, lens)
                 sp.set_args({"live_pages": live, "table_pages": slots,
                              "kv_bytes": kv_fetch_bytes(
-                                 self.kv_store, self.cfg, blocks, lens)})
+                                 self.kv_store, self.cfg, blocks, lens),
+                             **forward_counts()})
         # the host waits for the device to finish the step
         with tr.span("token_sync", cat="serve"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))

@@ -26,7 +26,8 @@ from repro.runtime.kv_store import PagedKVStore, kv_layer_order  # noqa: E402
 from repro.runtime.reclaim import UnsafeEagerPolicy  # noqa: E402
 from repro.serve.engine import ServeEngine  # noqa: E402
 from repro.serve.paged_model import (check_paged_support,  # noqa: E402
-                                     paged_decode_step, prefill_kv)
+                                     paged_decode_step, prefill_kv,
+                                     prefill_kv_chunked)
 
 RNG = np.random.default_rng(3)
 
@@ -85,15 +86,19 @@ def test_paged_dense_token_parity(cfg):
     assert outs["device"] == outs["dense"]
 
 
+@pytest.mark.parametrize("parallel", [False, True],
+                         ids=["serial", "parallel"])
 @pytest.mark.parametrize("storage", ["host", "device"])
 @pytest.mark.parametrize("dtype,atol", [("float32", 2e-4),
                                         ("bfloat16", 5e-2)])
-def test_paged_decode_logits_match_dense(dtype, atol, storage):
+def test_paged_decode_logits_match_dense(dtype, atol, storage, parallel):
     """One decode step, same prompt: paged logits vs dense logits, on both
-    storages.  The bf16 case pins the store to the MODEL dtype (pages must
-    hold exactly the values the dense cache would, not silently-upcast
-    f32) -- for device storage that means resident bf16 device arrays."""
-    cfg = CFG_PLAIN.scaled(dtype=dtype)
+    storages, with a serial and a parallel residual.  The bf16 case pins
+    the store to the MODEL dtype (pages must hold exactly the values the
+    dense cache would, not silently-upcast f32) -- for device storage that
+    means resident bf16 device arrays."""
+    cfg = CFG_PLAIN.scaled(dtype=dtype,
+                           groups=dense_stack(2, parallel=parallel))
     prompt = [3, 1, 4, 1, 5, 9, 2]
     params = init_params(cfg, jax.random.PRNGKey(2))
     n = len(prompt)
@@ -119,6 +124,161 @@ def test_paged_decode_logits_match_dense(dtype, atol, storage):
     np.testing.assert_allclose(np.asarray(paged_logits[0], np.float32),
                                np.asarray(dense_logits[0, -1], np.float32),
                                atol=atol, rtol=atol)
+
+
+@pytest.mark.parametrize("parallel", [False, True],
+                         ids=["serial", "parallel"])
+@pytest.mark.parametrize("storage", ["host", "device"])
+@pytest.mark.parametrize("dtype,atol", [("float32", 2e-4),
+                                        ("bfloat16", 5e-2)])
+def test_paged_prefill_chunk_logits_match_dense(dtype, atol, storage,
+                                                parallel):
+    """A prompt prefilled in chunks of 3 through the paged forward: every
+    chunk row's logits against the dense full-sequence forward's at that
+    position, on both storages, serial and parallel residual."""
+    cfg = CFG_FANCY.scaled(dtype=dtype,
+                           groups=dense_stack(3, parallel=parallel))
+    prompt = [2, 7, 1, 8, 2, 8, 1, 4, 5, 9, 3]
+    params = init_params(cfg, jax.random.PRNGKey(12))
+    want = apply_model(params, jnp.asarray([prompt], jnp.int32), cfg=cfg,
+                       mode="train")[0]
+    want = np.asarray(want[0, :, :cfg.vocab], np.float32)
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage=storage)
+    start = 0
+    for end, logits in prefill_kv_chunked(params, cfg, store, [5, 0, 3],
+                                          prompt, 3, impl="interpret"):
+        np.testing.assert_allclose(
+            np.asarray(logits[:, :cfg.vocab], np.float32), want[start:end],
+            atol=atol, rtol=atol)
+        start = end
+    assert start == len(prompt)
+
+
+@pytest.mark.parametrize("storage,uploads", [("device", 1), ("host", None)])
+def test_forward_launches_two_programs_a_layer(storage, uploads):
+    """A decode step and a prefill chunk of an L-layer model each launch
+    2L + 1 programs -- the kernel, and one program between two kernel
+    calls -- and, on device pages, put the step's inputs on the device
+    in one upload; host pages upload each layer's K and V besides."""
+    from repro.serve.paged_model import forward_counts, prefill_chunk_step
+
+    cfg = CFG_FANCY
+    L = len(kv_layer_order(cfg))
+    params = init_params(cfg, jax.random.PRNGKey(13))
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage=storage)
+    want = {"launches": 2 * L + 1, "uploads": uploads or 1 + 2 * L}
+    prefill_chunk_step(params, cfg, store, [1, 2], [3, 1, 4, 1, 5], 0,
+                       impl="interpret")
+    assert forward_counts() == want
+    paged_decode_step(params, cfg, store, [[1, 2], [4]], [5, 1], [9, 2],
+                      impl="interpret")
+    assert forward_counts() == want
+
+
+def test_fused_layer_program_writes_its_pages_in_place():
+    """The program between two kernel calls donates the next layer's K and
+    V pages: the compiled program aliases both into its outputs, and a
+    decode step leaves every layer's page buffers where they were."""
+    from repro.runtime.kv_store import _set_rows
+    from repro.serve.paged_model import _mlp_qkv, _out_mlp, _qkv
+
+    cfg = CFG_PLAIN
+    params = init_params(cfg, jax.random.PRNGKey(14))
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage="device")
+    gp = params["groups"]["g0"]["p0"]
+    B, H, hd = 3, cfg.n_heads, cfg.head_dim_
+    x = jnp.zeros((B, 1, cfg.d_model), cfg.dtype)
+    attn = jnp.zeros((8, H, hd), jnp.float32)
+    idx = jnp.zeros((B,), jnp.int32)
+    pages = store.layer_pages(1)
+    compiled = _mlp_qkv.lower(
+        gp, 0, x, attn, gp, 1, idx[:, None], pages, idx, idx, cfg=cfg,
+        rows=8, parallel=False, out_mlp=_out_mlp, qkv=_qkv,
+        write=_set_rows).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        pages[0].nbytes + pages[1].nbytes
+
+    store.sync()
+    before = [store.layer_pages(li)[0].unsafe_buffer_pointer()
+              for li in range(len(store.layer_order))]
+    paged_decode_step(params, cfg, store, [[0, 1], [2]], [5, 2], [9, 2],
+                      impl="interpret")
+    store.sync()
+    assert [store.layer_pages(li)[0].unsafe_buffer_pointer()
+            for li in range(len(store.layer_order))] == before
+
+
+def test_concurrent_prefill_write_during_decode_steps():
+    """A prefill worker's ``write_prefill`` from another thread, racing a
+    run of decode steps on one device store: no "array deleted" error, and
+    every token either side wrote is in the pages -- as a serial run of
+    the same writes leaves them."""
+    import sys
+    import threading
+    import time
+
+    cfg = CFG_PLAIN
+    params = init_params(cfg, jax.random.PRNGKey(15))
+    prompt = [3, 1, 4, 1, 5]
+    k, v = prefill_kv(params, cfg, prompt)
+    other = [np.asarray(a) for a in prefill_kv(params, cfg, [7, 2, 8, 6])]
+
+    def decode(store, n_steps):
+        tok, n = prompt[-1], len(prompt)
+        for _ in range(n_steps):
+            logits = paged_decode_step(params, cfg, store, [[0, 1, 2]], [n],
+                                       [tok], impl="interpret")
+            tok, n = int(np.argmax(np.asarray(logits[0]))), n + 1
+        return tok
+
+    def writes(store, n, done=None):
+        # the same tokens into pages 3-6, again and again until ``done``
+        i = 0
+        while i < n or (done is not None and not done.is_set()):
+            store.write_prefill([3 + i % 4], *other)
+            i += 1
+
+    serial = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage="device")
+    serial.write_prefill([0, 1, 2], k, v)
+    want_tok = decode(serial, 6)
+    writes(serial, 4)
+
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage="device")
+    store.write_prefill([0, 1, 2], k, v)
+    fetch = store.layer_pages
+
+    def slow_fetch(li):
+        # a slow host between fetching a layer's pages and the kernel call:
+        # the window in which a writer's donation would delete them
+        pages = fetch(li)
+        time.sleep(0.002)
+        return pages
+
+    store.layer_pages = slow_fetch
+    errors, done = [], threading.Event()
+
+    def writer():
+        try:
+            writes(store, 4, done)
+        except Exception as e:  # noqa: BLE001 -- reported below
+            errors.append(e)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        t = threading.Thread(target=writer)
+        t.start()
+        got_tok = decode(store, 6)
+        done.set()
+        t.join(timeout=120)
+    finally:
+        done.set()
+        sys.setswitchinterval(was)
+    assert not t.is_alive()
+    assert not errors, errors
+    assert got_tok == want_tok
+    np.testing.assert_array_equal(np.asarray(store.k), np.asarray(serial.k))
+    np.testing.assert_array_equal(np.asarray(store.v), np.asarray(serial.v))
 
 
 def test_prefill_kv_matches_decode_appends():
@@ -566,6 +726,69 @@ def test_spans_carry_the_kv_bytes_they_fetch():
     assert len(got["prefill_chunk"]) == 2 + 3 and len(got["dispatch"]) >= 3
     for b in got["dispatch"] + got["prefill_chunk"]:
         assert b > 0 and b % unit == 0
+
+
+def test_spans_carry_the_forward_counts():
+    """Each traced ``dispatch`` and ``prefill_chunk`` span has the
+    forward's ``launches`` (2L + 1) and ``uploads`` (1, device pages)."""
+    from repro.obs.trace import Tracer
+
+    tr = Tracer()
+    params = init_params(CFG_FANCY, jax.random.PRNGKey(6))
+    eng = _engine(CFG_FANCY, params, "paged", trace=tr, prefill_chunk=4)
+    _run(eng, [[1, 2, 3, 4, 5, 6], [3] * 9], max_new=3)
+    assert eng.kv_store.storage == "device"
+    L = len(eng.kv_store.layer_order)
+    got = {}
+    for e in tr.to_dict()["traceEvents"]:
+        if e["name"] in ("dispatch", "prefill_chunk"):
+            got.setdefault(e["name"], []).append(
+                (e["args"]["launches"], e["args"]["uploads"]))
+    assert len(got["prefill_chunk"]) == 2 + 3 and len(got["dispatch"]) >= 3
+    assert set(got["dispatch"] + got["prefill_chunk"]) == {(2 * L + 1, 1)}
+
+
+def test_forward_traced_whole_leaves_nothing_traced_behind():
+    """A paged decode step traced whole into one program, as a compile
+    check does, and then run eagerly: the layer indices the forward keeps
+    on the device stay concrete, so no tracer escapes the trace."""
+    from repro.runtime.kv_store import _set_rows
+    from repro.serve import paged_model
+
+    cfg = CFG_PLAIN
+    params = init_params(cfg, jax.random.PRNGKey(16))
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage="device")
+
+    class Traced:
+        layer_order, page, storage = store.layer_order, PAGE, "device"
+
+        def __init__(self, k, v):
+            self.k, self.v = list(k), list(v)
+
+        def write_guard(self):
+            return store.write_guard()
+
+        def write_in(self, layer, tokens, program):
+            self.k[layer], self.v[layer], out = program(
+                _set_rows, self.k[layer], self.v[layer])
+            return out
+
+        def layer_pages(self, li):
+            return self.k[li], self.v[li]
+
+    def step(k, v):
+        return paged_decode_step(params, cfg, Traced(k, v), [[0, 1]], [5],
+                                 [9], impl="interpret")
+
+    paged_model._device_ints.cache_clear()
+    pages = [store.layer_pages(li) for li in range(len(store.layer_order))]
+    traced = jax.jit(step)([k for k, _ in pages], [v for _, v in pages])
+    reps = paged_model._device_ints(len(store.layer_order))
+    assert not any(isinstance(r, jax.core.Tracer) for r in reps)
+    eager = paged_decode_step(params, cfg, store, [[0, 1]], [5], [9],
+                              impl="interpret")
+    np.testing.assert_allclose(np.asarray(eager), np.asarray(traced),
+                               atol=1e-5, rtol=1e-5)
 
 
 def test_decode_steps_in_one_bucket_share_a_kernel_program():

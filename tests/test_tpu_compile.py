@@ -12,6 +12,8 @@ Covered, at the published widths of two configs the paged path serves
 * the paged-attention kernel and the paged token scatter at starcoder2-7b
   (Hkv=4, D=128) and stablelm-12b (Hkv=8, D=160), the kernel also at
   decode table widths 21 and 44;
+* the program between two kernel calls at both widths, whose donated
+  page arrays must alias its outputs;
 * one whole paged decode step of starcoder2-7b cut to 16 layers, against
   a full 4096-page pool, whose ``memory_analysis()`` must fit one v5e's
   16 GiB, and one of stablelm-12b cut to 12, which must leave room.
@@ -22,6 +24,7 @@ imports this file.
 """
 
 import contextlib
+import functools
 import os
 
 import pytest
@@ -34,8 +37,9 @@ from repro.configs.registry import get_config  # noqa: E402
 from repro.kernels.paged_attention import (paged_attention_pallas,  # noqa: E402
                                            paged_scatter_pallas)
 from repro.models.model import abstract_params  # noqa: E402
-from repro.runtime.kv_store import _device_fns, kv_layer_order  # noqa: E402
-from repro.serve.paged_model import paged_decode_step  # noqa: E402
+from repro.runtime.kv_store import _set_rows, kv_layer_order  # noqa: E402
+from repro.serve.paged_model import (_mlp_qkv, _out_mlp, _qkv,  # noqa: E402
+                                     paged_decode_step)
 
 GiB = 1 << 30
 PAGE = 16
@@ -118,10 +122,40 @@ def test_paged_scatter_compiles_for_v5e(one_chip, name, H, Hkv, D):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("name,layers,parallel",
+                         [("starcoder2_7b", DECODE_LAYERS, False),
+                          ("stablelm_12b", 12, True)],
+                         ids=["starcoder2-7b", "stablelm-12b"])
+def test_fused_layer_program_aliases_its_pages_on_v5e(one_chip, name, layers,
+                                                      parallel):
+    """The program between two kernel calls (a layer's MLP, the next
+    layer's projections and page write), compiled for the chip at full
+    width over a 4096-page pool: both donated page arrays alias its
+    outputs, so the write moves a token's rows and not the pool."""
+    cfg = get_config(name).scaled(groups=dense_stack(layers,
+                                                     parallel=parallel))
+    B, rows = 32, 32
+    H, hd = cfg.n_heads, cfg.head_dim_
+    gp = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                      abstract_params(cfg)["groups"]["g0"]["p0"])
+    i32 = functools.partial(_sds, dtype="int32", sharding=one_chip)
+    page = _sds((POOL_PAGES, PAGE, cfg.n_kv_heads * hd), cfg.dtype, one_chip)
+    compiled = _mlp_qkv.lower(
+        gp, i32(()), _sds((B, 1, cfg.d_model), cfg.dtype, one_chip),
+        _sds((rows, H, hd), "float32", one_chip), gp, i32(()), i32((B, 1)),
+        (page, page), i32((B,)), i32((B,)), cfg=cfg, rows=rows,
+        parallel=parallel, out_mlp=_out_mlp, qkv=_qkv,
+        write=_set_rows).compile()
+    page_bytes = POOL_PAGES * PAGE * cfg.n_kv_heads * hd * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == 2 * page_bytes
+
+
 class _TracedPages:
     """The page-store surface ``paged_decode_step`` uses, over functional
     page arrays, so the whole step traces into one program.  Writes go
-    through the device storage's own donated scatter."""
+    through the device storage's own page write."""
+
+    storage = "device"
 
     def __init__(self, cfg, k, v):
         self.layer_order = kv_layer_order(cfg)
@@ -131,11 +165,10 @@ class _TracedPages:
     def write_guard(self):
         return contextlib.nullcontext()
 
-    def append_tokens(self, blocks, slots, k, v, layer):
-        scatter = _device_fns()[0]
-        blk, slot = jnp.asarray(blocks, jnp.int32), jnp.asarray(slots, jnp.int32)
-        self.k[layer] = scatter(self.k[layer], blk, slot, k)
-        self.v[layer] = scatter(self.v[layer], blk, slot, v)
+    def write_in(self, layer, tokens, program):
+        self.k[layer], self.v[layer], out = program(
+            _set_rows, self.k[layer], self.v[layer])
+        return out
 
     def layer_pages(self, li):
         return self.k[li], self.v[li]
