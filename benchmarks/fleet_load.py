@@ -153,6 +153,42 @@ def _tiny_cfg_params():
     return cfg, init_params(cfg, jax.random.PRNGKey(0))
 
 
+def warm_programs(eng, trace: Trace) -> int:
+    """Before ``eng`` starts: load every program a replay of ``trace`` can
+    run, so that none compiles inside the measured window.  The paged
+    forward is a program per row count and table width: a decode step has
+    one row per running request (up to ``max_batch``), a prefill chunk one
+    per chunk position (up to ``prefill_chunk``), and the widest table
+    covers the longest request.  Each (rows, width) runs once, into pages
+    no request holds yet (a page is zeroed when it is allocated), and so
+    does the page fill for every page count an allocation or a reclaim
+    pass can hand the store.  Returns the forward calls made."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.serve.paged_model import paged_decode_step
+
+    store, w = eng.kv_store, eng.workers[0]
+    longest_prompt = max(len(r.prompt) for r in trace.requests)
+    longest = max(len(r.prompt) + r.max_new for r in trace.requests)
+    max_rows = max(w.max_batch, min(w.prefill_chunk, longest_prompt))
+    max_width = -(-(longest + 1) // store.page)
+    for n in range(1, store.num_blocks + 1):
+        store.on_alloc(range(n))
+    calls = 0
+    for b in range(1, max_rows + 1):
+        for width in range(1, max_width + 1):
+            # row 0 spans ``width`` pages, the others one: the table is
+            # (b, width)
+            logits = paged_decode_step(
+                eng.params, eng.cfg, store, [list(range(width))] * b,
+                [store.page * width - 1] + [0] * (b - 1), [1] * b,
+                impl=eng.kernel_impl)
+            np.asarray(jnp.argmax(logits, axis=-1))
+            calls += 1
+    return calls
+
+
 def run_cell(scheme: str, profile: str, trace: Trace, *, engines: int = 8,
              sim_backend: str = "vec", slo: SLOSpec = SLO,
              sample_interval_s: float = 0.1, migrate: bool = False,
@@ -171,9 +207,8 @@ def run_cell(scheme: str, profile: str, trace: Trace, *, engines: int = 8,
     stall_every, stall_s = (
         (HOT_STALL_EVERY, HOT_STALL_S) if profile == "hot-engine"
         else (STALL_EVERY, STALL_S) if stalled else (0, 0.0))
-    kw = dict(n_engines=engines, max_batch=4, page_size=16, max_seq=64,
-              prefix_cache=True, kv_store="dense",
-              stall_every=stall_every, stall_s=stall_s,
+    kw = dict(n_engines=engines, max_batch=4, page_size=16,
+              prefix_cache=True, stall_every=stall_every, stall_s=stall_s,
               place_policy="static" if profile == "hot-engine"
               else "least-loaded",
               migrate=migrate, migrate_threshold=2,
@@ -193,10 +228,11 @@ def run_cell(scheme: str, profile: str, trace: Trace, *, engines: int = 8,
                          policy=make_policy(None, pop_every=2))
         sim_backend = None
         eng = ServeEngine(cfg, params, pool=pool, **kw)
+    warm_programs(eng, trace)
     eng.start()
     try:
-        # warmup: one request end-to-end covers jit compile of prefill +
-        # decode, then the measurement window starts clean
+        # one request end-to-end through the started engine, then the
+        # measurement window starts clean
         w = eng.submit([1, 2, 3, 4], max_new=2)
         w.done.wait(120)
         eng.metrics.reset()
@@ -234,7 +270,7 @@ def run_cell(scheme: str, profile: str, trace: Trace, *, engines: int = 8,
         samples = sampler.stop()
         row = {
             "scheme": scheme, "profile": profile, "engines": engines,
-            "sim_backend": sim_backend, "kv_store": "dense",
+            "sim_backend": sim_backend, "kv_store": "paged",
             "place_policy": kw["place_policy"], "migrate": int(migrate),
             "trace_seed": int(trace.meta["seed"]),
             "trace_duration_s": trace.duration_s,

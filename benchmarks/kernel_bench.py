@@ -70,14 +70,12 @@ def bench_paged(B=8, P=512, page=16, Hkv=8, D=128, max_pages=64):
 
 def bench_page_scatter(P=256, page=16, Hkv=8, D=128, B=8, layers=4,
                        chunk=64):
-    """The write half of the paged KV path, host vs device storage: one
-    decode step's batched ``append_tokens`` (B tokens scattered into B
-    pages per layer) and one ``chunk``-token chunked ``write_prefill``,
-    through the real :class:`PagedKVStore` lifecycle.  The device rows are
-    in-place donated scatters (O(tokens) moved); the host rows additionally
-    pay the O(pool) re-upload that reading the pages back costs the decode
-    step -- reported separately as the ``layer_pages`` row, which is the
-    traffic the device storage deletes."""
+    """The write half of the paged KV path: one decode step's batched
+    ``append_tokens`` (B tokens written into B pages per layer), one
+    ``chunk``-token ``write_prefill``, and the ``layer_pages`` reads a
+    forward makes, through the real :class:`PagedKVStore` lifecycle.  The
+    writes are in-place donated scatters (O(tokens) moved) and the reads
+    hand back the resident arrays (nothing moved)."""
     import numpy as np
 
     from repro.configs.base import ArchConfig, dense_stack
@@ -95,45 +93,40 @@ def bench_page_scatter(P=256, page=16, Hkv=8, D=128, B=8, layers=4,
         rng.normal(size=(layers, chunk, Hkv, D)), jnp.bfloat16)
     v_chunk = jnp.asarray(
         rng.normal(size=(layers, chunk, Hkv, D)), jnp.bfloat16)
+    store = PagedKVStore(cfg, num_blocks=P, page_size=page)
+    blk = [int(x) for x in rng.choice(P, B, replace=False)]
+    slot = [int(x) for x in rng.integers(0, page, B)]
+
+    def append_step():
+        for li in range(layers):
+            store.append_tokens(blk, slot, k_tok, v_tok, layer=li)
+        store.sync()
+
+    def prefill_chunk():
+        store.write_prefill(list(range(n_blk)), k_chunk, v_chunk)
+        store.sync()
+
+    def read_layers():
+        for li in range(layers):
+            kp, vp = store.layer_pages(li)
+        kp.block_until_ready()
+
     rows = []
-    for storage in ("host", "device"):
-        store = PagedKVStore(cfg, num_blocks=P, page_size=page,
-                             storage=storage)
-        blk = [int(x) for x in rng.choice(P, B, replace=False)]
-        slot = [int(x) for x in rng.integers(0, page, B)]
-
-        def append_step(store=store, blk=blk, slot=slot):
-            for li in range(layers):
-                store.append_tokens(blk, slot, k_tok, v_tok, layer=li)
-            store.sync()
-
-        def prefill_chunk(store=store, n_blk=n_blk):
-            store.write_prefill(list(range(n_blk)), k_chunk, v_chunk)
-            store.sync()
-
-        def read_layers(store=store):
-            for li in range(layers):
-                kp, vp = store.layer_pages(li)
-            kp.block_until_ready()
-
-        for op, fn, moved in (
-                ("append_tokens", append_step,
-                 2 * layers * B * Hkv * D * 2),
-                ("write_prefill", prefill_chunk,
-                 2 * layers * chunk * Hkv * D * 2),
-                ("layer_pages", read_layers,
-                 store.nbytes if storage == "host" else 0)):
-            fn()                               # warmup (jit trace)
-            t0 = time.perf_counter()
-            iters = 5
-            for _ in range(iters):
-                fn()
-            us = (time.perf_counter() - t0) / iters * 1e6
-            rows.append({
-                "name": f"page_scatter:{op}:{storage} "
-                        f"P{P} page{page} L{layers}",
-                "us_per_call": us, "bytes": moved,
-                "v5e_roofline_us": moved / HBM * 1e6})
+    for op, fn, moved in (
+            ("append_tokens", append_step, 2 * layers * B * Hkv * D * 2),
+            ("write_prefill", prefill_chunk,
+             2 * layers * chunk * Hkv * D * 2),
+            ("layer_pages", read_layers, 0)):
+        fn()                                   # warmup (jit trace)
+        t0 = time.perf_counter()
+        iters = 5
+        for _ in range(iters):
+            fn()
+        us = (time.perf_counter() - t0) / iters * 1e6
+        rows.append({
+            "name": f"page_scatter:{op} P{P} page{page} L{layers}",
+            "us_per_call": us, "bytes": moved,
+            "v5e_roofline_us": moved / HBM * 1e6})
     return rows
 
 

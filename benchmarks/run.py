@@ -95,13 +95,11 @@ def main() -> None:
                       f"pop {pool_rows[1]['steps_per_s']:.0f} steps/s"))
 
     # -- framework: serving-side reclamation grid (scheme x engines x pressure
-    #    + the shared-prefix allocation comparison + paged-vs-dense KV rows) --
-    from benchmarks.serve_reclaim import (QUICK_SCHEMES, run_grid,
-                                          run_kv_compare, to_csv)
+    #    + the shared-prefix allocation comparison) --
+    from benchmarks.serve_reclaim import QUICK_SCHEMES, run_grid, to_csv
     sr = _quiet(run_grid, schemes=QUICK_SCHEMES, engines=(1, 2),
                 pressures=("high",), duration=0.2, sim_backend="vec",
                 asym=False)
-    sr += _quiet(run_kv_compare, n_engines=2, requests=4, max_new=4)
     csv.extend(to_csv(sr))
     Path("results/serve_reclaim.json").write_text(json.dumps(sr, indent=1))
     summaries.append(("serve_reclaim", len(sr),

@@ -24,19 +24,12 @@ garbage-bound axis) and **per-engine throughput** (steps/s min/mean across
 engines -- fairness under ping fan-out), plus blocks allocated per request
 for the sharing comparison.
 
-Two extra axes ride on the grid:
+Extra axes ride on the grid:
 
-* **kv_store** -- every row records its KV storage layer.  The protocol
-  grid moves no KV payload (``kv_store="none"``); the ``kv-compare`` rows
-  run REAL model traffic through the serving engine three times --
-  ``dense`` (private per-request caches), ``paged/host`` (physical pages
-  in numpy, re-uploaded per step), ``paged/device`` (device-resident
-  pages, in-place donated scatters; runtime/kv_store.py) -- and report
-  decode throughput, resident KV bytes, **bytes-copied-per-request**
-  split by prefix-cache hit/miss (the paged path's hits must be ~0:
-  shared pages enter the block table, nothing is copied), and
-  **bytes_h2d** (device storage must move ZERO host->device KV bytes in
-  steady-state decode; host storage pays O(pool x layers) per step).
+* **kv_store** -- every row records its KV storage layer: the protocol
+  grid moves no KV payload (``kv_store="none"``); the prefill-interference
+  rows run real model traffic through the paged serving path
+  (``"paged"``).
 * **evict_policy** -- the shared-prefix comparison runs the prefix cache
   under plain LRU and under refcount-aware eviction (skip entries with
   live readers) so the two policies are directly comparable.
@@ -61,8 +54,8 @@ engine readers live on a "remote socket" (4x ping/signal delivery
 latency, 2x memory latency via ``Costs.asymmetric``), the regime where
 publish-on-ping's contrast with fence-per-read is widest.
 
-Every row that runs the real serving engine (kv-compare, prefill-
-interference) additionally carries per-request latency distributions from
+Every row that runs the real serving engine (prefill-interference)
+additionally carries per-request latency distributions from
 the obs registry -- ``ttft_{p50,p99,p999,max}_s`` and
 ``tok_latency_*_s`` -- and every row with an SMR pool carries
 ``ping_stall_*_s`` / ``reclaim_pass_*_s`` percentiles sourced from the
@@ -243,8 +236,8 @@ def run_one(scheme: str, n_engines: int, pressure: str = "high",
         "scheme": scheme, "engines": n_engines, "pressure": pressure,
         "workload": workload, "prefix_cache": prefix_cache,
         "sim_backend": sim_backend, "asym": asym,
-        # the protocol grid moves no KV payload; the kv-compare rows
-        # (run_kv_compare) record "dense"/"paged" here
+        # the protocol grid moves no KV payload; the prefill-interference
+        # rows record "paged" here
         "kv_store": "none", "evict_policy": evict_policy,
         "steps": total, "requests": n_reqs,
         "us_per_step": 1e6 * elapsed / max(total, 1),
@@ -268,131 +261,6 @@ def run_one(scheme: str, n_engines: int, pressure: str = "high",
                             fields=LAT_FIELDS),
         "uaf": uaf[0], "errors": errors[:3],
     }
-
-
-def run_kv_compare(n_engines: int = 2, requests: int = 8,
-                   max_new: int = 6,
-                   tracer: "Tracer | None" = None) -> list:
-    """Paged-vs-dense KV storage under REAL model traffic: same tiny model,
-    same hot page-aligned prompts, the serving engine run three times --
-    dense, paged with host-resident pages, paged with device-resident
-    pages.  Reports decode throughput, resident KV bytes, bytes-copied-
-    per-request by prefix-cache outcome, and host->device KV traffic
-    (``bytes_h2d``); asserts the acceptance criteria (hits install ~0
-    bytes, device storage moves ZERO h2d KV bytes while host storage pays
-    an upload per step, zero use-after-free, identical tokens)."""
-    import jax
-
-    from repro.configs.base import ArchConfig, dense_stack
-    from repro.models.model import init_params
-    from repro.serve.engine import ServeEngine
-
-    page, max_seq, max_batch = 4, 32, 4
-    cfg = ArchConfig(name="kv-bench", d_model=32, n_heads=4, n_kv_heads=2,
-                     d_ff=64, vocab=64, groups=dense_stack(2), remat="none",
-                     dtype="float32")
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    # two hot prompts, both page-aligned so a cache hit covers the WHOLE
-    # prompt (the bytes-per-hit ~ 0 criterion is exact, not approximate)
-    hot = [[1, 9, 3, 5, 2, 8, 6, 4], [7, 2, 8, 6, 4, 1, 3, 5]]
-    rows, outs = [], {}
-    cells = [("dense", None), ("paged", "host"), ("paged", "device")]
-    for mode, kv_storage in cells:
-        label = mode if kv_storage is None else f"{mode}/{kv_storage}"
-        eng = ServeEngine(cfg, params, max_batch=max_batch, page_size=page,
-                          num_pages=64, max_seq=max_seq,
-                          n_engines=n_engines, prefix_cache=True,
-                          kv_store=mode,
-                          kv_storage=kv_storage or "device",
-                          trace=tracer)
-        eng.start()
-        # warmup outside the clock: the first request pays jit compile /
-        # kernel tracing, which would otherwise dominate a short run and
-        # make tok_per_s a startup benchmark (a prompt OUTSIDE the hot set,
-        # so the timed hit/miss mix is unchanged)
-        eng.submit([9, 9, 9, 9], max_new=1).done.wait(timeout=600)
-        # the warmup TTFT is all jit compile: drop it so the reported
-        # latency tail is the steady-state distribution
-        eng.metrics.reset()
-        t0 = time.perf_counter()
-        reqs = [eng.submit(hot[i % len(hot)], max_new=max_new)
-                for i in range(requests)]
-        for r in reqs:
-            r.done.wait(timeout=600)
-        elapsed = time.perf_counter() - t0
-        eng.stop()
-        # the row is printed (uaf included) before the asserts below, so a
-        # failing run still leaves its numbers on stdout (the results file
-        # is only written by a run that completes)
-        uaf = int(isinstance(eng.error, UseAfterFree))
-        outs[label] = sorted(tuple(r.out) for r in reqs)
-        kv = eng.kv_copy_stats()
-        toks = sum(len(r.out) for r in reqs)
-        if mode == "paged":
-            kv_resident = eng.kv_store.nbytes          # constant pool
-        else:
-            # dense reserves one full cache per concurrently running
-            # request: the static-batch capacity the paged pool replaces
-            per_req = next((w._dense_cache_bytes for w in eng.workers
-                            if w._dense_cache_bytes), 0)
-            kv_resident = per_req * max_batch * n_engines
-        s = eng.pool.stats
-        # per-request latency distributions from the engine registry (TTFT,
-        # inter-token gap) and the pool registry (ping stall)
-        lat = eng.metrics.flat(["ttft_s", "tok_latency_s"],
-                               fields=LAT_FIELDS)
-        lat.update(eng.pool.metrics.flat(["ping_stall_s"],
-                                         fields=LAT_FIELDS))
-        rows.append({
-            "scheme": "EpochPOP-pool", "engines": n_engines,
-            "pressure": "low", "workload": "kv-compare",
-            "prefix_cache": True, "sim_backend": None, "asym": False,
-            "kv_store": mode, "kv_storage": kv_storage,
-            "evict_policy": "lru",
-            "requests": requests, "tokens": toks,
-            "tok_per_s": toks / elapsed,
-            "us_per_step": 1e6 * elapsed / max(eng.steps, 1),
-            "kv_resident_bytes": kv_resident,
-            "bytes_per_hit": kv["bytes_per_hit"],
-            "bytes_per_miss": kv["bytes_per_miss"],
-            "admitted_hit": kv["admitted_hit"],
-            "admitted_miss": kv["admitted_miss"],
-            # host<->device KV traffic through the page store (None on the
-            # dense rows: private caches live wherever jit puts them)
-            "bytes_h2d": kv["bytes_h2d"],
-            "bytes_d2h": kv["bytes_d2h"],
-            "bytes_h2d_per_step": kv["bytes_h2d_per_step"],
-            "prefix_hits": s.prefix_hits, "blocks_saved": s.blocks_saved,
-            "peak_unreclaimed": s.retired_peak, "freed": s.freed,
-            "allocated": s.allocated, **lat, "uaf": uaf, "errors": [],
-        })
-        h2d = "-" if kv["bytes_h2d"] is None else str(kv["bytes_h2d"])
-        print(f"# kv-compare {label:12s} e={n_engines} "
-              f"{rows[-1]['tok_per_s']:8.1f} tok/s "
-              f"ttft p50/p99 {lat['ttft_p50_s']*1e3:6.1f}/"
-              f"{lat['ttft_p99_s']*1e3:6.1f}ms "
-              f"tok p50/p99 {lat['tok_latency_p50_s']*1e3:6.1f}/"
-              f"{lat['tok_latency_p99_s']*1e3:6.1f}ms "
-              f"resident={kv_resident:>9d}B "
-              f"bytes/hit={kv['bytes_per_hit']:8.0f} "
-              f"bytes/miss={kv['bytes_per_miss']:8.0f} "
-              f"h2d={h2d:>9s}B uaf={uaf}")
-        assert eng.error is None, f"kv-compare {label} failed: {eng.error!r}"
-    assert outs["paged/host"] == outs["dense"], \
-        "paged/host and dense decode disagree on tokens"
-    assert outs["paged/device"] == outs["dense"], \
-        "paged/device and dense decode disagree on tokens"
-    by_storage = {r.get("kv_storage"): r for r in rows
-                  if r["kv_store"] == "paged"}
-    for r in by_storage.values():
-        assert r["bytes_per_hit"] == 0, \
-            f"paged cache hit copied {r['bytes_per_hit']} bytes (want 0)"
-    # the device-residency headline: resident pages move ZERO h2d KV bytes
-    # while the host reference re-uploads the pool every step
-    assert by_storage["device"]["bytes_h2d"] == 0, \
-        f"device storage uploaded {by_storage['device']['bytes_h2d']} bytes"
-    assert by_storage["host"]["bytes_h2d"] > 0
-    return rows
 
 
 def run_prefill_interference(schemes=("EpochPOP-pool", "EpochPOP"),
@@ -423,7 +291,7 @@ def run_prefill_interference(schemes=("EpochPOP-pool", "EpochPOP"),
     from repro.models.model import init_params
     from repro.serve.engine import ServeEngine
 
-    page, max_seq, max_batch = 4, 96, 4
+    page, max_batch = 4, 4
     cfg = ArchConfig(name="pf-bench", d_model=32, n_heads=4, n_kv_heads=2,
                      d_ff=64, vocab=64, groups=dense_stack(2), remat="none",
                      dtype="float32")
@@ -452,8 +320,7 @@ def run_prefill_interference(schemes=("EpochPOP-pool", "EpochPOP"),
                                  ping_timeout_s=60.0,
                                  policy=make_policy(scheme, backend=sb))
                 eng = ServeEngine(cfg, params, max_batch=max_batch,
-                                  page_size=page, max_seq=max_seq,
-                                  pool=pool, n_engines=1, kv_store="paged",
+                                  page_size=page, pool=pool, n_engines=1,
                                   prefill_workers=n_pw, prefill_chunk=chunk,
                                   trace=tracer)
                 eng.start()
@@ -608,23 +475,6 @@ def to_csv(rows) -> list:
                 f"prefill_tokens={r['prefill_tokens']};"
                 f"peak_unreclaimed={r['peak_unreclaimed']};uaf={r['uaf']}")
             continue
-        if r["workload"] == "kv-compare":
-            tag = f"serve_reclaim:kv:{r['kv_store']}"
-            if r.get("kv_storage"):
-                tag += f":{r['kv_storage']}"
-            tag += f":e{r['engines']}"
-            h2d = ("" if r.get("bytes_h2d") is None
-                   else f"bytes_h2d={r['bytes_h2d']};")
-            out.append(
-                f"{tag},{r['us_per_step']:.2f},"
-                f"tok_per_s={r['tok_per_s']:.1f};"
-                f"ttft_p99_ms={r['ttft_p99_s']*1e3:.1f};"
-                f"tok_latency_p99_ms={r['tok_latency_p99_s']*1e3:.1f};"
-                f"kv_resident_bytes={r['kv_resident_bytes']};"
-                f"bytes_per_hit={r['bytes_per_hit']:.0f};"
-                f"bytes_per_miss={r['bytes_per_miss']:.0f};"
-                f"{h2d}uaf={r['uaf']}")
-            continue
         tag = f"serve_reclaim:{r['scheme']}:e{r['engines']}:{r['pressure']}"
         if r["workload"] == "shared-prefix":
             tag += ":shared" + ("+cache" if r["prefix_cache"] else "")
@@ -653,10 +503,6 @@ def main():
                     help="simulator backend for the simulated schemes; "
                          "'vec' extends the default engines axis to 8")
     ap.add_argument("--duration", type=float, default=None)
-    ap.add_argument("--skip-kv", action="store_true",
-                    help="skip the paged-vs-dense model-traffic comparison "
-                         "(it runs real decode through the Pallas kernel in "
-                         "interpret mode, the slowest cells of the grid)")
     ap.add_argument("--skip-prefill", action="store_true",
                     help="skip the prefill-interference rows (real chunked "
                          "prefill traffic; full runs only -- --quick always "
@@ -685,17 +531,12 @@ def main():
                         duration=args.duration or 0.2,
                         sim_backend=args.sim_backend, asym=False,
                         tracer=tracer)
-        if not args.skip_kv:
-            rows += run_kv_compare(n_engines=min(engines or (2,)),
-                                   requests=4, max_new=4, tracer=tracer)
     else:
         # the vec backend is what makes the 8-engine column affordable
         full = (1, 2, 4, 8) if args.sim_backend == "vec" else (1, 2, 4)
         rows = run_grid(engines=engines or full,
                         duration=args.duration or 0.5,
                         sim_backend=args.sim_backend, tracer=tracer)
-        if not args.skip_kv:
-            rows += run_kv_compare(n_engines=2, tracer=tracer)
         if not args.skip_prefill:
             rows += run_prefill_interference(
                 chunks=chunks, prefill_workers=args.prefill_workers,
@@ -714,9 +555,6 @@ def main():
                 name = f"{r['scheme']}:{r['workload']}"
                 if r.get("prefill_mode"):
                     name += f":{r['prefill_mode']}:c{r['prefill_chunk']}"
-                elif r["workload"] == "kv-compare":
-                    name += ":" + (r["kv_store"] if not r.get("kv_storage")
-                                   else f"{r['kv_store']}/{r['kv_storage']}")
                 else:
                     name += f":e{r['engines']}:{r['pressure']}"
                 print(f"# metrics {name:44s} {cols}")

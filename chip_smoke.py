@@ -20,8 +20,8 @@ reports no error (a use-after-free is one), the pool is leak-free, the
 first-step logits of one request agree with a plain ``apply_model``
 forward at the same dtype, and every generated token of every request is
 that forward's top-1 or a near tie (see :data:`LOGIT_TOL`).  The
-reference feeds the last prompt token twice, as both serving paths do
-today, so it checks the paged path against the dense model, not that
+reference feeds the last prompt token twice, as the serving path does
+today, so it checks the paged path against the plain model, not that
 habit.  Its timings are the readings of one
 smoke run, not benchmark results.  The last line of stdout is::
 
@@ -125,7 +125,7 @@ class CompileLog:
 def _first_step_logits(params, cfg, prompt, *, page, chunk, impl):
     """The logits a worker's first decode step computes for ``prompt``:
     the chunked prefill, then one paged decode step that feeds the last
-    prompt token again (both serving paths do so today), on a private page
+    prompt token again (the serving path does so today), on a private page
     store."""
     import jax.numpy as jnp
 
@@ -134,7 +134,7 @@ def _first_step_logits(params, cfg, prompt, *, page, chunk, impl):
 
     n = len(prompt)
     blocks = list(range(-(-(n + 1) // page)))
-    store = PagedKVStore(cfg, len(blocks), page, storage="device")
+    store = PagedKVStore(cfg, len(blocks), page)
     for _ in prefill_kv_chunked(params, cfg, store, blocks, prompt, chunk,
                                 impl=impl):
         pass
@@ -256,8 +256,7 @@ def run_smoke(cfg, impl: str, *, prompt_lens: Sequence[int] = PROMPT_LENS,
         prompts = make_prompts(cfg.vocab, prompt_lens, page, seed)
         # the engine picks its kernel mode itself; the smoke holds it to
         # the one it expects
-        eng = ServeEngine(cfg, params, kv_store="paged", kv_storage="device",
-                          prefix_cache=True,
+        eng = ServeEngine(cfg, params, prefix_cache=True,
                           max_batch=len(prompts), page_size=page,
                           num_pages=num_pages, prefill_chunk=prefill_chunk)
         out["kernel_impl"] = eng.kernel_impl

@@ -6,12 +6,10 @@ feature).
     PYTHONPATH=src python examples/serve_paged.py                      # EpochPOP pool
     PYTHONPATH=src python examples/serve_paged.py --engines 2          # sharded runtime
     PYTHONPATH=src python examples/serve_paged.py --engines 2 --prefix-cache
-    PYTHONPATH=src python examples/serve_paged.py --kv-store paged     # physical pages
-    PYTHONPATH=src python examples/serve_paged.py --kv-store paged --prefix-cache
     PYTHONPATH=src python examples/serve_paged.py --smr HazardPtrPOP   # any registry scheme
     PYTHONPATH=src python examples/serve_paged.py --smr EBR
     PYTHONPATH=src python examples/serve_paged.py --smr EpochPOP --sim-backend vec
-    PYTHONPATH=src python examples/serve_paged.py --kv-store paged \
+    PYTHONPATH=src python examples/serve_paged.py \
         --prefill-workers 2 --prefill-chunk 16   # async chunked prefill stage
     PYTHONPATH=src python examples/serve_paged.py --engines 2 \
         --trace /tmp/serve.json --metrics        # Perfetto trace + histograms
@@ -20,16 +18,12 @@ feature).
     PYTHONPATH=src python examples/serve_paged.py --engines 4 \
         --place-policy static --migrate          # migration rescues skew
 
-``--kv-store paged`` stores K/V physically in the POP-managed block pool
-(runtime/kv_store.py) and decodes through the Pallas paged-attention kernel
-(interpret mode on CPU, compiled Pallas on a TPU; the engine's
-``kernel_impl`` says which); a prefix-cache hit then installs
-NO copies -- the shared pages enter the request's block table directly.
-``--kv-storage`` picks where those pages live: ``device`` (default --
-resident jax arrays updated in place by donated scatters, zero
-host->device KV bytes per steady-state decode step) or ``host`` (the numpy
-reference storage, which pays an O(pool) re-upload per layer per step; the
-``bytes_h2d`` line below shows the difference).
+K/V live physically in the POP-managed block pool's device-resident pages
+(runtime/kv_store.py), updated in place by donated writes, and every step
+decodes through the Pallas paged-attention kernel (interpret mode on CPU,
+compiled Pallas on a TPU; the engine's ``kernel_impl`` says which); a
+prefix-cache hit installs NO copies -- the shared pages enter the
+request's block table directly.
 
 ``--prefill-workers N`` splits prefill out of the decode loop into N
 dedicated threads (each a first-class SMR reader slot) running chunked
@@ -68,16 +62,6 @@ def main():
                     help="simulator backend for --smr schemes: 'gen' "
                          "(discrete-event reference) or 'vec' (batch-stepped "
                          "numpy arrays, ~5-10x faster)")
-    ap.add_argument("--kv-store", default="dense", choices=("dense", "paged"),
-                    help="KV storage: 'dense' (one private cache per "
-                         "request) or 'paged' (physical pages in the "
-                         "SMR-managed pool, Pallas paged-attention decode)")
-    ap.add_argument("--kv-storage", default="device",
-                    choices=("host", "device"),
-                    help="where the paged pages physically live: 'device' "
-                         "(resident jax arrays, in-place donated scatters) "
-                         "or 'host' (numpy reference storage, O(pool) "
-                         "re-upload per decode step)")
     ap.add_argument("--prefill-workers", type=int, default=0, metavar="N",
                     help="dedicated async-prefill threads (0 = prefill runs "
                          "inline in the decode loop, still chunked)")
@@ -138,10 +122,9 @@ def main():
                      reclaim_threshold=8, pressure_factor=2,
                      policy=make_policy(args.smr, backend=args.sim_backend,
                                         **policy_kw))
-    eng = ServeEngine(cfg, params, max_batch=4, page_size=8, max_seq=64,
+    eng = ServeEngine(cfg, params, max_batch=4, page_size=8,
                       pool=pool, n_engines=args.engines,
                       prefix_cache=args.prefix_cache,
-                      kv_store=args.kv_store, kv_storage=args.kv_storage,
                       prefill_workers=args.prefill_workers,
                       prefill_chunk=args.prefill_chunk,
                       sched_policy=args.sched_policy,
@@ -194,17 +177,11 @@ def main():
               f"prefill_tokens_skipped="
               f"{sum(w.prefill_tokens_skipped for w in actors)}")
     kv = eng.kv_copy_stats()
-    print(f"kv_store={kv['kv_store']}: "
+    print(f"kv_store={kv['kv_store']} kernel={eng.kernel_impl}: "
           f"bytes-copied/request hit={kv['bytes_per_hit']:.0f} "
-          f"miss={kv['bytes_per_miss']:.0f}"
-          + (f" | physical pool={eng.kv_store.nbytes} B (constant), "
-             f"pages poisoned={eng.kv_store.poisons}"
-             if eng.kv_store is not None else ""))
-    if kv["kv_storage"] is not None:
-        print(f"kv_storage={kv['kv_storage']}: "
-              f"bytes_h2d={kv['bytes_h2d']} "
-              f"({kv['bytes_h2d_per_step']:.0f}/step) "
-              f"bytes_d2h={kv['bytes_d2h']}")
+          f"miss={kv['bytes_per_miss']:.0f} | physical pool="
+          f"{eng.kv_store.nbytes} B (constant), pages poisoned="
+          f"{eng.kv_store.poisons} | bytes_h2d={kv['bytes_h2d']}")
     if args.metrics:
         print("\nlatency/stall histograms (merged on read):")
         for name, snap in {**eng.snapshot()["metrics"],

@@ -12,7 +12,7 @@ of a token side by side along the lanes.  A page is one contiguous
 ``(page, Hkv * D)`` tile, one DMA, and its minor dimension is lane-dense
 whenever ``Hkv * D`` is a multiple of 128 (stablelm-12b: 8 x 160 = 1280,
 where a head-major ``(page, 160)`` block is eight DMAs and takes 256 lanes
-in VMEM).  Measured alone on a v5e, the kernel and the page scatter ran
+in VMEM).  Measured alone on a v5e, the kernel and the page write ran
 faster on this layout at head dims 160 and 128 alike.
 
 grid = (B, blocks): each grid step folds one block of ``ppb`` pages of a
@@ -296,72 +296,3 @@ def paged_attention_pallas(
     out = out.reshape(B, Hkv, G, Hkv, Dv) * eye.astype(out.dtype)
     return out.sum(axis=3).reshape(B, H, Dv)
 
-
-def _scatter_kernel(blk_ref, slot_ref, vals_ref, page_ref, out_ref):
-    # grid=(T,): the index maps steered this step to the whole
-    # (page, Hkv * D) tile pages[blk[t]].  Tokens bound for one page arrive
-    # back to back, and while the output block index stays put Pallas keeps
-    # the tile in VMEM and skips the refetch -- so copy the page in on the
-    # first token of a run, then overwrite one row per token.
-    t = pl.program_id(0)
-    first = jnp.logical_or(t == 0, blk_ref[jnp.maximum(t - 1, 0)] != blk_ref[t])
-
-    @pl.when(first)
-    def _load():
-        out_ref[...] = page_ref[...]
-
-    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 0)
-    out_ref[0] = jnp.where(row == slot_ref[t], vals_ref[0], out_ref[0])
-
-
-def paged_scatter_pallas(
-    pages: jnp.ndarray,           # (P, page, Hkv * D) physical page pool
-    block_idx: jnp.ndarray,       # (T,) destination page per token
-    slot_idx: jnp.ndarray,        # (T,) destination slot per token
-    vals: jnp.ndarray,            # (T, Hkv, D) token K (or V) vectors
-    *,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Token scatter as a Pallas kernel: write T token vectors into the
-    physical page pool at ``pages[block_idx[t], slot_idx[t]]`` -- the
-    write half of the block-table contract :func:`paged_attention_pallas`
-    reads.
-
-    The destination indices are scalar-prefetch operands driving the
-    BlockSpec index maps (the same trick the gather kernel uses for its
-    page DMA).  Each grid step moves a whole ``(page, Hkv * D)`` tile and
-    rewrites one row of it.  ``input_output_aliases`` makes the pool buffer
-    the output buffer: untouched pages are preserved and -- when the caller
-    donates ``pages`` under jit, as the device KV storage does -- the
-    update is in place, O(tokens * page) moved instead of O(pool).
-
-    Tokens may come in any order.  The kernel keeps a page resident only
-    while consecutive tokens target it -- a page revisited after another
-    would be refetched while its first write may still be in flight -- so
-    the tokens are first stably sorted by page: each page's writes become
-    one run, and of two writes to one slot the later still wins.
-    """
-    T = vals.shape[0]
-    _, page, W = pages.shape
-    order = jnp.argsort(block_idx, stable=True)
-    block_idx, slot_idx, vals = block_idx[order], slot_idx[order], vals[order]
-    return pl.pallas_call(
-        _scatter_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,        # block_idx + slot_idx
-            grid=(T,),
-            in_specs=[
-                pl.BlockSpec((1, 1, W), lambda t, blk, slot: (t, 0, 0)),
-                pl.BlockSpec((1, page, W),
-                             lambda t, blk, slot: (blk[t], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, page, W),
-                                   lambda t, blk, slot: (blk[t], 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
-        # flattened input index 3 = pages (after the 2 prefetch operands
-        # and vals): alias it straight into the output pool
-        input_output_aliases={3: 0},
-        interpret=interpret,
-    )(block_idx.astype(jnp.int32), slot_idx.astype(jnp.int32),
-      vals.astype(pages.dtype).reshape(T, 1, W), pages)

@@ -2,8 +2,8 @@
 
 Runs the continuous-batching engine (POP-reclaimed paged KV pool) on the
 reduced config with a synthetic request stream and prints pool/reclamation
-stats.  The dense serve_step it executes is the same function the dry-run
-compiles for the production meshes.
+stats.  An architecture the paged path cannot serve (MLA, windowed, SSM,
+MoE, MTP, enc-dec) is refused at engine construction.
 """
 
 from __future__ import annotations
@@ -32,8 +32,7 @@ def main():
     cfg = get_smoke_config(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))
     pool = BlockPool(256, n_engines=1, reclaim_threshold=8)
-    eng = ServeEngine(cfg, params, max_batch=4, page_size=8, max_seq=64,
-                      pool=pool)
+    eng = ServeEngine(cfg, params, max_batch=4, page_size=8, pool=pool)
     eng.start()
     rng = random.Random(0)
     t0 = time.time()

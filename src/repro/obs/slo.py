@@ -213,15 +213,7 @@ def engine_probes(eng) -> Dict[str, Callable[[], float]]:
     pool = eng.pool
 
     def resident_kv_bytes() -> float:
-        store = getattr(eng, "kv_store", None)
-        if store is not None and hasattr(store, "nbytes"):
-            return float(store.nbytes)
-        # dense path: one full-length cache per active request
-        total = 0
-        for w in eng.workers:
-            per = getattr(w, "_dense_cache_bytes", 0) or 0
-            total += per * len(getattr(w, "_caches", ()))
-        return float(total)
+        return float(eng.kv_store.nbytes)
 
     return {
         "queue_depth": lambda: float(sum(w.load for w in eng.workers)),

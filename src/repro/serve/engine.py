@@ -25,10 +25,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence
 
-import jax
-
 from repro.configs.base import ArchConfig
-from repro.models.model import apply_model
 from repro.obs import MetricsRegistry, Tracer
 from repro.runtime.block_pool import BlockPool
 from repro.runtime.kv_store import PagedKVStore
@@ -43,18 +40,16 @@ class ServeEngine:
     """Facade: Scheduler + N EngineWorkers + optional PrefillWorkers +
     Reclaimer over one BlockPool.
 
-    ``kv_store`` selects the KV storage layer: ``"dense"`` keeps one private
-    jax cache per request (the historical path, any architecture);
-    ``"paged"`` stores K/V physically in a shared
+    K/V live physically in one shared
     :class:`~repro.runtime.kv_store.PagedKVStore` keyed by the pool's block
-    ids and decodes through the Pallas paged-attention kernel (GQA configs;
-    see serve/paged_model.py).  ``kv_storage`` picks where the paged
-    pages physically live: ``"device"`` (the default -- "paged" means
-    HBM-paged: jax arrays updated in place by donated scatters, zero
-    host->device bytes per steady-state decode step) or ``"host"`` (the
-    numpy reference storage, which re-uploads the pool to the device every
-    step -- kept for A/B measurement and CPU-light tests).  Both paths run
-    under every SMR policy, so they A/B cleanly in the benchmarks.
+    ids, as device-resident arrays updated in place by donated writes, and
+    every step decodes through the Pallas paged-attention kernel
+    (serve/paged_model.py).  A config that path cannot run (MLA, windowed,
+    SSM, MoE, MTP, enc-dec) is refused with a ``ValueError`` at
+    construction.  ``kv_store``/``kv_storage`` accept only ``"paged"`` and
+    ``"device"``, the one path there is; ``kernel_impl`` overrides the
+    kernel mode the backend picks (``"pallas"`` | ``"interpret"`` |
+    ``"xla"``).
 
     ``prefill_workers``/``prefill_chunk`` configure the async prefill
     pipeline: N dedicated prefill threads (each its own SMR reader slot in
@@ -79,12 +74,12 @@ class ServeEngine:
 
     def __init__(self, cfg: ArchConfig, params, *, max_batch: int = 8,
                  page_size: int = 16, num_pages: int = 256,
-                 max_seq: int = 256, pool: Optional[BlockPool] = None,
+                 pool: Optional[BlockPool] = None,
                  smr: Optional[str] = None, n_engines: int = 1,
                  prefix_cache: bool = False,
                  reclaim_interval_s: float = 0.002,
                  sim_backend: str = "gen", sim_costs=None,
-                 kv_store: str = "dense", kv_storage: str = "device",
+                 kv_store: str = "paged", kv_storage: str = "device",
                  kernel_impl: Optional[str] = None,
                  evict_policy: str = "lru",
                  prefill_workers: int = 0, prefill_chunk: int = 16,
@@ -105,12 +100,12 @@ class ServeEngine:
         # land in the same trace as the request lifecycle
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.tracer = trace
-        if kv_store not in ("dense", "paged"):
-            raise ValueError(f"kv_store must be 'dense' or 'paged', "
-                             f"got {kv_store!r}")
-        if kv_storage not in ("host", "device"):
-            raise ValueError(f"kv_storage must be 'host' or 'device', "
-                             f"got {kv_storage!r}")
+        if kv_store != "paged":
+            raise ValueError(f"kv_store must be 'paged' (the dense path was "
+                             f"removed), got {kv_store!r}")
+        if kv_storage != "device":
+            raise ValueError(f"kv_storage must be 'device' (host page "
+                             f"storage was removed), got {kv_storage!r}")
         if evict_policy not in ("lru", "refcount-aware"):
             # fail at construction, not asynchronously in a worker or the
             # reclaimer thread mid-run
@@ -120,6 +115,16 @@ class ServeEngine:
             raise ValueError(
                 f"need prefill_workers >= 0 and prefill_chunk >= 1, got "
                 f"{prefill_workers}/{prefill_chunk}")
+        from repro.kernels.paged_attention import paged_impl
+        from repro.serve.paged_model import check_paged_support
+        check_paged_support(cfg)
+        #: the paged-attention kernel mode every worker runs ("pallas" |
+        #: "interpret" | "xla") -- what a caller checks to know the
+        #: compiled kernel is really in use
+        self.kernel_impl = kernel_impl or paged_impl()
+        if self.kernel_impl not in ("pallas", "interpret", "xla"):
+            raise ValueError(f"kernel_impl must be 'pallas', 'interpret' "
+                             f"or 'xla', got {self.kernel_impl!r}")
         n_actors = n_engines + prefill_workers
         if pool is None:
             from repro.runtime.reclaim import make_policy
@@ -148,30 +153,12 @@ class ServeEngine:
         if trace is not None:
             pool.attach_tracer(trace)
         self.n_engines = n_engines
-        # paged KV mode: ONE physical page store shared by every worker,
-        # registered as a pool block listener so frees poison pages and
-        # (re)allocations clear them -- under whichever SMR policy decides
-        self.kv_store: Optional[PagedKVStore] = None
-        #: the paged-attention kernel mode every worker runs ("pallas" |
-        #: "interpret" | "xla"; None on the dense path) -- what a caller
-        #: checks to know the compiled kernel is really in use
-        self.kernel_impl: Optional[str] = None
-        if kv_store == "paged":
-            from repro.kernels.paged_attention import paged_impl
-            from repro.serve.paged_model import check_paged_support
-            check_paged_support(cfg)
-            self.kernel_impl = kernel_impl or paged_impl()
-            if self.kernel_impl not in ("pallas", "interpret", "xla"):
-                raise ValueError(f"kernel_impl must be 'pallas', 'interpret' "
-                                 f"or 'xla', got {self.kernel_impl!r}")
-            self.kv_store = PagedKVStore(cfg, pool.num_blocks, page_size,
-                                         storage=kv_storage,
-                                         metrics=self.metrics)
-            pool.add_block_listener(self.kv_store)
-        # one jitted decode shared by every worker (JAX execution is
-        # thread-safe; the compile cache is shared)
-        self._decode = jax.jit(
-            lambda p, c, t: apply_model(p, t, cfg=cfg, mode="decode", cache=c))
+        # ONE physical page store shared by every worker, registered as a
+        # pool block listener so frees poison pages and (re)allocations
+        # clear them -- under whichever SMR policy decides
+        self.kv_store = PagedKVStore(cfg, pool.num_blocks, page_size,
+                                     metrics=self.metrics)
+        pool.add_block_listener(self.kv_store)
         # desched-stall fault injection (the load harness's "frequently
         # delayed threads" cell): afflicted decode workers sleep stall_s
         # every stall_every-th step MID-step, reader session held.  Default
@@ -181,11 +168,9 @@ class ServeEngine:
             stall_workers = (0,)
         stall_set = set(stall_workers or ())
         self.workers: List[EngineWorker] = [
-            EngineWorker(i, cfg, params, pool, self._decode,
-                         max_batch=max_batch, page_size=page_size,
-                         max_seq=max_seq, prefix_cache=prefix_cache,
-                         kv_store=self.kv_store,
-                         kernel_impl=self.kernel_impl,
+            EngineWorker(i, cfg, params, pool, self.kv_store,
+                         self.kernel_impl, max_batch=max_batch,
+                         page_size=page_size, prefix_cache=prefix_cache,
                          evict_policy=evict_policy,
                          prefill_chunk=prefill_chunk,
                          tracer=trace, metrics=self.metrics,
@@ -194,10 +179,9 @@ class ServeEngine:
             for i in range(n_engines)]
         # prefill workers take the engine ids right after the decode fleet
         self.prefill_workers: List[PrefillWorker] = [
-            PrefillWorker(n_engines + j, cfg, params, pool, self._decode,
-                          page_size=page_size, max_seq=max_seq,
-                          prefix_cache=prefix_cache, kv_store=self.kv_store,
-                          kernel_impl=self.kernel_impl,
+            PrefillWorker(n_engines + j, cfg, params, pool, self.kv_store,
+                          self.kernel_impl, page_size=page_size,
+                          prefix_cache=prefix_cache,
                           evict_policy=evict_policy,
                           prefill_chunk=prefill_chunk,
                           tracer=trace, metrics=self.metrics)
@@ -275,29 +259,22 @@ class ServeEngine:
 
     def kv_copy_stats(self) -> dict:
         """Aggregate bytes-copied-per-request accounting across all pool
-        actors (decode workers and prefill workers): how many KV bytes
-        admission installed into per-request storage, split by prefix-cache
-        outcome.  The paged path's headline number is ``bytes_per_hit`` ~ 0
-        (shared pages enter the block table, nothing is copied); the dense
-        path pays a full cache per request."""
+        actors (decode workers and prefill workers): the KV bytes each
+        request's prefill wrote into its pages, split by prefix-cache
+        outcome.  The headline number is ``bytes_per_hit`` ~ 0 for a
+        whole-prompt hit (shared pages enter the block table, nothing is
+        copied); ``bytes_h2d`` counts the KV bytes uploaded from the host,
+        0 in serving, where every K/V is computed on the device."""
         actors = self.workers + self.prefill_workers
         hit_b = sum(w.kv_bytes_copied_hit for w in actors)
         miss_b = sum(w.kv_bytes_copied_miss for w in actors)
         hits = sum(w.admitted_hit for w in actors)
         misses = sum(w.admitted_miss for w in actors)
-        st = self.kv_store
         return {
-            "kv_store": "paged" if st is not None else "dense",
-            "kv_storage": st.storage if st is not None else None,
+            "kv_store": "paged",
             "admitted_hit": hits, "admitted_miss": misses,
             "bytes_hit": hit_b, "bytes_miss": miss_b,
             "bytes_per_hit": hit_b / max(hits, 1),
             "bytes_per_miss": miss_b / max(misses, 1),
-            # host<->device KV traffic through the page store: the device-
-            # residency headline (device storage: 0 h2d in steady-state
-            # decode; host storage: O(pool * layers) per step)
-            "bytes_h2d": st.bytes_h2d if st is not None else None,
-            "bytes_d2h": st.bytes_d2h if st is not None else None,
-            "bytes_h2d_per_step": (st.bytes_h2d / max(self.steps, 1)
-                                   if st is not None else None),
+            "bytes_h2d": self.kv_store.bytes_h2d,
         }

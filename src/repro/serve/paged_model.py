@@ -1,21 +1,21 @@
-"""Paged decode forward: the model math of a decode step driven through the
-physically paged KV store and the Pallas paged-attention kernel.
+"""Paged forward: the model math of a decode step or prefill chunk driven
+through the physically paged KV store and the Pallas paged-attention
+kernel -- the one way the serving runtime runs a model.
 
-The dense serving path runs :func:`repro.models.model.apply_model` in decode
-mode against a per-request ``(L, max_seq, Hkv, hd)`` cache -- every token
-functionally updates the whole cache and prefix "sharing" is a snapshot
-copy.  This module is the paged twin: attention reads K/V straight out of
-the :class:`~repro.runtime.kv_store.PagedKVStore`'s physical pages through a
-per-request block table (``kernels/paged_attention.py``), and the only
-per-token write is a single page-slot scatter.
+Attention reads K/V straight out of the
+:class:`~repro.runtime.kv_store.PagedKVStore`'s device-resident pages
+through a per-request block table (``kernels/paged_attention.py``), and the
+only per-token write is a single page-slot write.  The plain full-sequence
+forward, :func:`repro.models.model.apply_model`, is the reference the tests
+and the benchmark hold this path to.
 
-Scope: the paged path supports the GQA transformer family the serving demo
-and tests exercise -- every layer ``mixer="attn"`` with ``attn_kind="full"``
-and a dense MLP (qk_norm / post_norms / softcaps / partial rotary all
-honored).  MLA, sliding-window, SSM/RWKV mixers, MoE, cross-attention and
-weight-tied shared attention keep using the dense path;
-:func:`check_paged_support` rejects them up front so the failure mode is a
-clear error at engine construction, not silent wrong math.
+Scope: the GQA transformer family -- every layer ``mixer="attn"`` with
+``attn_kind="full"`` and a dense MLP (qk_norm / post_norms / softcaps /
+partial rotary / parallel residual all honored).  MLA, sliding-window,
+SSM/RWKV mixers, MoE, cross-attention, weight-tied shared attention,
+encoders and MTP heads are not served: :func:`check_paged_support` rejects
+them up front so the failure mode is a clear error at engine construction,
+not silent wrong math.
 
 The layer loop runs at host level, two launches a layer: the kernel call
 (``kops.paged_attention``, compiled Pallas on a TPU and interpret mode
@@ -24,14 +24,12 @@ default), and one jitted program for everything between two kernel calls
 -- layer i's output projection and MLP, then layer i+1's projections and
 its K/V page write (``_mlp_qkv``; ``_embed_qkv`` before the first layer,
 ``_mlp_logits`` after the last).  The step's table, lengths, positions,
-tokens and write indices go to the device in one ``jax.device_put``.  The
-data plane is storage-aware end to end: under device storage a layer's
-page arrays are donated into the program that writes them
+tokens and write indices go to the device in one ``jax.device_put``.  A
+layer's page arrays are donated into the program that writes them
 (:meth:`PagedKVStore.write_in`) -- an in-place write with ZERO host
 traffic -- and ``layer_pages`` hands the kernel the store's resident
-arrays; host storage (the reference) writes on the host between the two
-programs.  The whole forward runs under :meth:`PagedKVStore.write_guard`,
-so a concurrent writer's buffer donation can never invalidate the pages
+arrays.  The whole forward runs under :meth:`PagedKVStore.write_guard`, so
+a concurrent writer's buffer donation can never invalidate the pages
 mid-step.
 """
 
@@ -49,10 +47,9 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
 from repro.models.layers import apply_rope, rms_norm
-from repro.models.model import apply_model
-from repro.runtime.kv_store import PagedKVStore, kv_layer_order
+from repro.runtime.kv_store import PagedKVStore, _set_rows
 
-__all__ = ["check_paged_support", "prefill_kv", "prefill_kv_chunked",
+__all__ = ["check_paged_support", "prefill_kv_chunked",
            "prefill_chunk_step", "paged_decode_step", "kv_fetch_bytes",
            "forward_counts"]
 
@@ -79,35 +76,8 @@ def check_paged_support(cfg: ArchConfig) -> None:
                 problems.append(f"{where}: non-causal")
     if problems:
         raise ValueError(
-            "config not supported by the paged KV path (use kv_store="
-            "'dense'): " + "; ".join(problems))
-
-
-# ----------------------------------------------------------------------------
-# prefill: dense full-sequence forward, K/V extracted for the page writes
-# ----------------------------------------------------------------------------
-
-
-def prefill_kv(params, cfg: ArchConfig,
-               tokens: Sequence[int]) -> Tuple[np.ndarray, np.ndarray]:
-    """Prefill a prompt with the standard full-sequence forward and return
-    its per-layer post-rope K/V as ``(L, S, Hkv, hd)`` numpy arrays, in
-    :func:`~repro.runtime.kv_store.kv_layer_order` order -- ready for
-    :meth:`PagedKVStore.write_prefill`.
-
-    Prefill stays dense on purpose (one batched matmul pass beats S
-    single-token steps); only the *storage* of its result is paged.
-    """
-    toks = jnp.asarray([list(tokens)], jnp.int32)
-    _, cache, _ = apply_model(params, toks, cfg=cfg, mode="prefill")
-    ks, vs = [], []
-    for gi, pi, rep in kv_layer_order(cfg):
-        lc = cache["groups"][f"g{gi}"][f"p{pi}"]
-        # keep the cache's own dtype: the page arrays store exactly the
-        # values the dense path would (bit-for-bit for bf16 and f32 alike)
-        ks.append(np.asarray(lc["k"][rep, 0]))               # (S, Hkv, hd)
-        vs.append(np.asarray(lc["v"][rep, 0]))
-    return np.stack(ks), np.stack(vs)
+            "config not supported by the paged KV path, the only "
+            "serving path: " + "; ".join(problems))
 
 
 # ----------------------------------------------------------------------------
@@ -228,16 +198,12 @@ def kv_fetch_bytes(store: PagedKVStore, cfg: ArchConfig, blocks,
     return n * store.page_fetch_bytes * len(store.layer_order)
 
 
-def _project(qkv, gp, rep, x, positions, pages, blk, slot, *, cfg, rows,
-             write):
-    """Layer ``rep``'s q, and its K/V: written into ``pages`` by ``write``
-    (``(q, k_pages, v_pages)``), or returned for the host to write when
-    ``write`` is None (``(q, k, v)``)."""
+def _project(qkv, gp, rep, x, positions, pages, blk, slot, *, cfg, rows):
+    """Layer ``rep``'s q, and its K/V written into ``pages``: ``(q,
+    k_pages, v_pages)``."""
     q, k, v = qkv(gp, rep, x, positions, cfg=cfg, rows=rows)
-    if write is None:
-        return q, k, v
     kp, vp = pages
-    return q, write(kp, blk, slot, k), write(vp, blk, slot, v)
+    return q, _set_rows(kp, blk, slot, k), _set_rows(vp, blk, slot, v)
 
 
 # The programs of a forward.  The layer math comes in as static callables,
@@ -248,27 +214,27 @@ def _project(qkv, gp, rep, x, positions, pages, blk, slot, *, cfg, rows,
 
 
 @functools.partial(jax.jit, donate_argnames="pages",
-                   static_argnames=("cfg", "rows", "qkv", "write"))
+                   static_argnames=("cfg", "rows", "qkv"))
 def _embed_qkv(embed, toks, gp, rep, positions, pages, blk, slot, *,
-               cfg: ArchConfig, rows: int, qkv, write):
+               cfg: ArchConfig, rows: int, qkv):
     """Before the first kernel call: embed the fed tokens, then layer
     ``rep``'s projections and page write."""
     x = _embed(embed, toks, cfg=cfg)
     return (x,) + _project(qkv, gp, rep, x, positions, pages, blk, slot,
-                           cfg=cfg, rows=rows, write=write)
+                           cfg=cfg, rows=rows)
 
 
 @functools.partial(jax.jit, donate_argnames="pages",
                    static_argnames=("cfg", "rows", "parallel", "out_mlp",
-                                    "qkv", "write"))
+                                    "qkv"))
 def _mlp_qkv(gp, rep, x, attn, next_gp, next_rep, positions, pages, blk,
              slot, *, cfg: ArchConfig, rows: int, parallel: bool, out_mlp,
-             qkv, write):
+             qkv):
     """Between two kernel calls: layer ``rep``'s output projection and MLP,
     then layer ``next_rep``'s projections and page write."""
     x = out_mlp(gp, rep, x, attn, cfg=cfg, parallel=parallel)
     return (x,) + _project(qkv, next_gp, next_rep, x, positions, pages, blk,
-                           slot, cfg=cfg, rows=rows, write=write)
+                           slot, cfg=cfg, rows=rows)
 
 
 @functools.partial(jax.jit,
@@ -334,21 +300,12 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
              else 1.0 / math.sqrt(cfg.head_dim_))
     # the layer math as it stands in this module now
     qkv, out_mlp, logits = _qkv, _out_mlp, _logits
-    on_device = store.storage == "device"
 
     def layer_in(li, program, *args, **static):
-        """Launch ``program`` with layer ``li``'s pages: ``(x, q)``.  Under
-        device storage the program writes the layer's K/V into its donated
-        pages; under host storage the host writes them after it."""
-        if not on_device:
-            x, q, k, v = program(*args, None, blk_d, slot_d, write=None,
-                                 **static)
-            store.append_tokens(blk, slot, k, v, layer=li)  # (B, Hkv, hd)
-            return x, q
-
-        def run(write, kp, vp):
-            x, q, kp, vp = program(*args, (kp, vp), blk_d, slot_d,
-                                   write=write, **static)
+        """Launch ``program`` with layer ``li``'s pages donated into it; it
+        writes the layer's K/V there: ``(x, q)``."""
+        def run(kp, vp):
+            x, q, kp, vp = program(*args, (kp, vp), blk_d, slot_d, **static)
             return kp, vp, (x, q)
         return store.write_in(li, len(pos), run)
 
@@ -380,8 +337,7 @@ def _paged_forward(params, cfg: ArchConfig, store: PagedKVStore,
                                      out_mlp=out_mlp, logits=logits)
             launches += 2
     _counts.launches = launches
-    # host storage uploads each layer's K and V pages for the kernel
-    _counts.uploads = 1 if on_device else 1 + 2 * len(order)
+    _counts.uploads = 1
     return result
 
 

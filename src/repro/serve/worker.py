@@ -29,32 +29,22 @@ prefill inline at admission, so the chunk bound holds either way.
 Prefix sharing: when enabled, admitting a request first asks the pool's
 content-keyed prefix cache for the longest page-aligned prompt prefix
 already prefilled by any worker.  A hit reuses the shared blocks (refcounted
-by the pool) AND the prefilled KV state, so the worker skips both the
-allocation and the prefill compute for those tokens.  On finish, shared
-blocks are *released*, not retired; the pool retires them only when the
-last holder (cache entry included) lets go, and the SMR policy decides when
-recycling is actually safe.
+by the pool), whose pages already hold the prefix's KV, so the worker
+skips both the allocation and the prefill compute for those tokens.  On
+finish, shared blocks are *released*, not retired; the pool retires them
+only when the last holder (cache entry included) lets go, and the SMR
+policy decides when recycling is actually safe.
 
-KV storage is selectable per engine (``kv_store``):
-
-* **dense** -- the historical host-scale path: one private ``(L, max_seq,
-  Hkv, hd)`` jax cache per request, decode through ``apply_model``; a
-  prefix hit installs the cached KV *snapshot* (a whole-cache payload).
-* **paged** -- the physically paged path: K/V live ONLY in the shared
-  :class:`~repro.runtime.kv_store.PagedKVStore` pages keyed by the pool's
-  block ids, and a decode step batches every running request into one
-  ``(table, lens, q)`` call of the Pallas paged-attention kernel
-  (serve/paged_model.py).  A prefix hit installs *no copies at all*: the
-  shared physical pages enter the request's block table directly, and the
-  prefix-cache payload shrinks from a KV snapshot to just the prefilled
-  length (the block ids already live in the cache entry).  The pages
-  themselves are DEVICE-resident by default (``kv_storage="device"``):
-  every worker's writes are donated in-place scatters against the shared
-  device arrays and the decode gather reads them where they live, so a
-  steady-state decode step moves zero host->device KV bytes -- the
-  ``kv_storage="host"`` reference storage instead re-uploads the pool to
-  the device per layer per step (measured as ``bytes_h2d`` in
-  ``ServeEngine.kv_copy_stats``).
+KV storage: K/V live ONLY in the shared
+:class:`~repro.runtime.kv_store.PagedKVStore`'s device-resident pages keyed
+by the pool's block ids, and a decode step batches every running request
+into one ``(table, lens, q)`` call of the Pallas paged-attention kernel
+(serve/paged_model.py).  A prefix hit installs *no copies at all*: the
+shared physical pages enter the request's block table directly, and the
+prefix-cache payload is just the prefilled length (the block ids already
+live in the cache entry).  Every worker's writes are donated in-place
+writes into the shared device arrays and the kernel reads them where they
+live, so a steady-state decode step moves zero host->device KV bytes.
 """
 
 from __future__ import annotations
@@ -65,11 +55,9 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.model import init_cache
 from repro.obs import NULL_TRACER, MetricsRegistry, Tracer
 from repro.runtime.block_pool import BlockPool, OutOfBlocks, StaleHandoff
 from repro.runtime.kv_store import PagedKVStore
@@ -83,18 +71,16 @@ class Request:
     out: List[int] = field(default_factory=list)
     blocks: List[int] = field(default_factory=list)         # private
     shared_blocks: List[int] = field(default_factory=list)  # prefix-shared
-    # prefill pipeline state: how many prompt tokens have materialized KV
-    # (pages or dense cache), whether admission was a prefix-cache hit
-    # (the bytes-copied classification), how many prefix tokens are
-    # already published to the cache (hit_len -- also advanced when WE
-    # publish, so it cannot double-insert), which engine id currently
-    # owns the blocks (handoff transfers via BlockPool.adopt), and --
-    # dense mode only -- the cache being built (the handoff payload)
+    # prefill pipeline state: how many prompt tokens have their KV in the
+    # pages, whether admission was a prefix-cache hit (the bytes-copied
+    # classification), how many prefix tokens are already published to
+    # the cache (hit_len -- also advanced when WE publish, so it cannot
+    # double-insert), and which engine id currently owns the blocks
+    # (handoff transfers via BlockPool.adopt)
     prefilled: int = 0
     cache_hit: bool = False
     hit_len: int = 0
     owner: Optional[int] = None
-    cache: Optional[dict] = None
     # scheduling state: absolute monotonic deadline (None = best-effort,
     # sorts last under the deadline policy) and how often the scheduler
     # preempted/migrated this request (observability + test oracles)
@@ -117,14 +103,14 @@ class Request:
         return self.shared_blocks + self.blocks
 
     def reset_admission(self) -> None:
-        """Forget everything admission built (blocks, prefix hit, dense
-        cache, prefill progress) so the request can be re-admitted from
+        """Forget everything admission built (blocks, prefix hit, prefill
+        progress) so the request can be re-admitted from
         scratch.  The one caller is stale-handoff recovery: the pool
         refused an adopt because the source engine crashed and its blocks
         were already recovered, so this request's references to them are
         dangling by definition -- dropping them leaks nothing."""
         self.blocks, self.shared_blocks = [], []
-        self.owner, self.cache = None, None
+        self.owner = None
         self.prefilled = self.hit_len = 0
         self.cache_hit = False
 
@@ -163,11 +149,9 @@ class _PoolActor:
     identical whether it runs in the dedicated prefill stage or inline at
     decode admission."""
 
-    def __init__(self, engine_id: int, cfg, params, pool: BlockPool, decode,
-                 *, page_size: int = 16, max_seq: int = 256,
-                 prefix_cache: bool = False,
-                 kv_store: Optional[PagedKVStore] = None,
-                 kernel_impl: Optional[str] = None,
+    def __init__(self, engine_id: int, cfg, params, pool: BlockPool,
+                 kv_store: PagedKVStore, kernel_impl: str, *,
+                 page_size: int = 16, prefix_cache: bool = False,
                  evict_policy: str = "lru", prefill_chunk: int = 16,
                  tracer: Optional[Tracer] = None,
                  metrics: Optional[MetricsRegistry] = None):
@@ -176,32 +160,26 @@ class _PoolActor:
         self.params = params
         self.pool = pool
         self.page = page_size
-        self.max_seq = max_seq
         self.prefix_cache = prefix_cache
         self.evict_policy = evict_policy
         self.prefill_chunk = prefill_chunk
         self.tracer = NULL_TRACER if tracer is None else tracer
         self.metrics = metrics
-        self._decode = decode
-        # paged KV mode: physical pages + Pallas kernel instead of dense
-        # per-request caches (None = dense, the historical path)
+        # the shared physical pages, and the paged-attention kernel mode
+        # ("pallas" | "interpret" | "xla") every forward runs
         self.kv_store = kv_store
-        if kv_store is not None and kernel_impl is None:
-            raise ValueError("a paged worker needs an explicit kernel_impl")
         self.kernel_impl = kernel_impl
         self._stop = threading.Event()
         self.prefill_tokens = 0
         self.prefill_chunks = 0
         self.prefill_tokens_skipped = 0
-        # bytes of KV installed into per-request private storage at
-        # admission, split by prefix-cache outcome (the benchmark's
-        # bytes-copied-per-request axis); dense counts the request's whole
-        # materialized cache, paged counts only freshly written pages
+        # KV bytes of the pages a request's prefill wrote, split by
+        # prefix-cache outcome (the bytes-copied-per-request axis): a hit's
+        # shared pages enter the block table and are never copied
         self.kv_bytes_copied_hit = 0
         self.kv_bytes_copied_miss = 0
         self.admitted_hit = 0
         self.admitted_miss = 0
-        self._dense_cache_bytes: Optional[int] = None
         # voluntary chunk-level preemption: when set (by the scheduler, on
         # prefill workers ONLY -- an inline decode admission has no shared
         # queue to yield back to), consulted at every chunk boundary; a
@@ -219,26 +197,20 @@ class _PoolActor:
 
     def _lookup_prefix(self, r: Request):
         """Longest cached page-aligned prefix of r.prompt; returns
-        (shared_blocks, cache_snapshot, prefilled_len).  One logical lookup
-        = one hit or one miss in the stats, however many lengths it probes.
-
-        Payload shape differs by KV mode: dense entries carry a whole KV
-        snapshot ``(cache, plen)``; paged entries carry only ``plen`` -- the
-        physical pages ARE the KV, already named by the entry's block ids."""
+        (shared_blocks, prefilled_len).  One logical lookup = one hit or
+        one miss in the stats, however many lengths it probes.  An entry's
+        payload is its prefilled length: the physical pages ARE the KV,
+        already named by the entry's block ids."""
         n_full = len(r.prompt) // self.page
         for k in range(n_full, 0, -1):
             hit = self.pool.acquire_prefix(
                 self.engine_id, self._prefix_key(r.prompt[:k * self.page]),
                 count_miss=False)
             if hit is not None:
-                blocks, payload = hit
-                if self.kv_store is not None:
-                    return blocks, None, payload
-                cache, plen = payload
-                return blocks, cache, plen
+                return hit
         if n_full:
             self.pool.count_prefix_miss()
-        return [], None, 0
+        return [], 0
 
     def _allocate(self, n_blocks: int) -> List[int]:
         """Allocate with pressure fallbacks: reclaim, then (when the prefix
@@ -269,15 +241,14 @@ class _PoolActor:
         raise AssertionError("unreachable")
 
     def _admit_blocks(self, r: Request) -> bool:
-        """First-touch admission: prefix lookup + block allocation (and, in
-        dense mode, the private cache install).  Returns False -- with the
-        request rolled back untouched -- when the pool is out of blocks.
-        On success the caller's engine owns the request's blocks
-        (``r.owner``) and ``r.prefilled`` reflects the prefix hit."""
+        """First-touch admission: prefix lookup + block allocation.  Returns
+        False -- with the request rolled back untouched -- when the pool is
+        out of blocks.  On success the caller's engine owns the request's
+        blocks (``r.owner``) and ``r.prefilled`` reflects the prefix hit."""
         shared: List[int] = []
-        cache, plen = None, 0
+        plen = 0
         if self.prefix_cache:
-            shared, cache, plen = self._lookup_prefix(r)
+            shared, plen = self._lookup_prefix(r)
         n_total = (len(r.prompt) + r.max_new + self.page - 1) // self.page
         try:
             r.blocks = self._allocate(n_total - len(shared))
@@ -295,21 +266,6 @@ class _PoolActor:
             self.admitted_hit += 1
         else:
             self.admitted_miss += 1
-        if self.kv_store is None:
-            # the request's KV is a full private cache either way: a hit
-            # merely seeds it from the snapshot (which the first write
-            # copies); count the install bytes here, where the cache is born
-            if cache is None:
-                cache = init_cache(self.cfg, 1, self.max_seq, self.cfg.dtype)
-            r.cache = cache
-            if self._dense_cache_bytes is None:
-                self._dense_cache_bytes = sum(
-                    int(np.prod(leaf.shape)) * leaf.dtype.itemsize
-                    for leaf in jax.tree.leaves(cache))
-            if plen:
-                self.kv_bytes_copied_hit += self._dense_cache_bytes
-            else:
-                self.kv_bytes_copied_miss += self._dense_cache_bytes
         return True
 
     def _adopt(self, r: Request) -> None:
@@ -398,35 +354,17 @@ class _PoolActor:
         False if stopped mid-prompt -- the request is left resumable
         (``r.prefilled`` partial, blocks still owned) for a peer or a later
         admission to continue from."""
-        if r.prefilled >= len(r.prompt):
-            self._publish_prefix(r)          # full-hit: nothing to prefill
-            return True
-        if self.kv_store is not None:
-            return self._prefill_paged(r)
-        return self._prefill_dense(r)
-
-    def _publish_prefix(self, r: Request) -> None:
-        """Insert the full page-aligned prompt prefix into the pool's cache
-        once its KV is materialized -- at the boundary crossing, so a long
-        tail never delays publication (and a partial handoff publishes at
-        most once: ``hit_len`` records what is already covered)."""
-        n_full = len(r.prompt) // self.page
-        boundary = n_full * self.page
-        if (not self.prefix_cache or not n_full or r.hit_len >= boundary
-                or r.prefilled < boundary):
-            return
-        payload = boundary if self.kv_store is not None else (r.cache,
-                                                              boundary)
-        self._insert_prefix(r, n_full, payload=payload)
-        r.hit_len = boundary
-
-    def _prefill_paged(self, r: Request) -> bool:
-        """Chunked paged prefill: one batched forward per chunk through the
-        paged kernel (prefix-shared and earlier-chunk pages gathered in
-        place), each chunk's K/V written into the pages by its own forward."""
+        # read from the module when called, so a replaced entry point is
+        # the one that runs
         from repro.serve.paged_model import (forward_counts, kv_fetch_bytes,
                                              prefill_kv_chunked)
 
+        if r.prefilled >= len(r.prompt):
+            self._publish_prefix(r)          # full-hit: nothing to prefill
+            return True
+        # one batched forward per chunk through the paged kernel (prefix-
+        # shared and earlier-chunk pages gathered in place), each chunk's
+        # K/V written into the pages by its own forward
         store = self.kv_store
         hit = r.cache_hit
         tr = self.tracer
@@ -468,35 +406,18 @@ class _PoolActor:
                 return False
         return True
 
-    def _prefill_dense(self, r: Request) -> bool:
-        """Dense prefill of the uncached remainder, token by token (the
-        dense decode forward is single-token): the safepoint cadence is one
-        token, strictly tighter than the chunk bound."""
-        toks = jnp.asarray([r.prompt], jnp.int32)
-        start = r.prefilled
-        t0 = time.monotonic()
-        for t in range(r.prefilled, len(r.prompt)):
-            self.pool.safepoint(self.engine_id)
-            if self._stop.is_set():
-                return False
-            # voluntary preemption (prefill workers only); the ``t > start``
-            # guard guarantees at least one token of progress per pickup
-            if (self.preempt_check is not None and t > start
-                    and self.preempt_check(r)):
-                self._note_preempt(r)
-                return False
-            _, r.cache, _ = self._decode(self.params, r.cache,
-                                         toks[:, t:t + 1])
-            self.prefill_tokens += 1
-            r.prefilled = t + 1
-            self._publish_prefix(r)
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            tr.complete("prefill_dense", tr.wall_ts(t0),
-                        (time.monotonic() - t0) * 1e6, cat="serve",
-                        args={"rid": r.rid, "start": start,
-                              "end": r.prefilled})
-        return True
+    def _publish_prefix(self, r: Request) -> None:
+        """Insert the full page-aligned prompt prefix into the pool's cache
+        once its KV is materialized -- at the boundary crossing, so a long
+        tail never delays publication (and a partial handoff publishes at
+        most once: ``hit_len`` records what is already covered)."""
+        n_full = len(r.prompt) // self.page
+        boundary = n_full * self.page
+        if (not self.prefix_cache or not n_full or r.hit_len >= boundary
+                or r.prefilled < boundary):
+            return
+        self._insert_prefix(r, n_full, payload=boundary)
+        r.hit_len = boundary
 
     def _finalize(self, r: Request) -> None:
         """Fail/stop-path completion (see :func:`finalize_request`)."""
@@ -504,9 +425,9 @@ class _PoolActor:
 
     def _insert_prefix(self, r: Request, n_full: int, payload) -> None:
         """Publish the full page-aligned prompt prefix: blocks 0..n_full-1
-        of the request (cached-shared first, then private) plus the KV
-        payload (dense: ``(snapshot, plen)``; paged: ``plen`` -- the pages
-        themselves are the KV)."""
+        of the request (cached-shared first, then private) with its
+        prefilled length as the payload -- the pages themselves are the
+        KV."""
         k = len(r.shared_blocks)
         converts = r.blocks[:n_full - k]
         prefix_blocks = r.shared_blocks + converts
@@ -542,25 +463,15 @@ class EngineWorker(_PoolActor):
     upstream it only ever installs ready pages; without them it runs the
     same chunked prefill inline."""
 
-    def __init__(self, engine_id: int, cfg, params, pool: BlockPool, decode,
-                 *, max_batch: int = 8, page_size: int = 16,
-                 max_seq: int = 256, prefix_cache: bool = False,
-                 kv_store: Optional[PagedKVStore] = None,
-                 kernel_impl: Optional[str] = None,
-                 evict_policy: str = "lru", prefill_chunk: int = 16,
-                 tracer: Optional[Tracer] = None,
-                 metrics: Optional[MetricsRegistry] = None,
-                 stall_every: int = 0, stall_s: float = 0.0):
-        super().__init__(engine_id, cfg, params, pool, decode,
-                         page_size=page_size, max_seq=max_seq,
-                         prefix_cache=prefix_cache, kv_store=kv_store,
-                         kernel_impl=kernel_impl, evict_policy=evict_policy,
-                         prefill_chunk=prefill_chunk, tracer=tracer,
-                         metrics=metrics)
+    def __init__(self, engine_id: int, cfg, params, pool: BlockPool,
+                 kv_store: PagedKVStore, kernel_impl: str, *,
+                 max_batch: int = 8, stall_every: int = 0,
+                 stall_s: float = 0.0, **kw):
+        super().__init__(engine_id, cfg, params, pool, kv_store, kernel_impl,
+                         **kw)
         self.max_batch = max_batch
         self.queue: "queue.Queue[Request]" = queue.Queue()
         self.running: Dict[int, Request] = {}
-        self._caches: Dict[int, dict] = {}
         self.steps = 0
         # fault injection: every Nth decode step, sleep mid-step for
         # stall_s -- AFTER reserving the step's reader session and BEFORE
@@ -633,43 +544,73 @@ class EngineWorker(_PoolActor):
                 # waiter released -- instead of stranding it
                 self._finalize(r)
                 return
-            if self.kv_store is None:
-                self._caches[r.rid] = r.cache
-                r.cache = None
             self.running[r.rid] = r
 
     # -- decode step (POP reader) --
 
     def _step(self) -> int:
-        """One decode step over every running request; returns the rows
-        it decoded (0: nothing was running)."""
+        """One decode step over every running request -- ONE batched
+        (table, lens, q) forward through the paged kernel, every request a
+        block-table row over the shared physical pages (ragged lengths,
+        prefix pages included in place); returns the rows it decoded (0:
+        nothing was running)."""
+        # read from the module when called, so a replaced entry point is
+        # the one that runs
+        from repro.serve.paged_model import (decode_table_pages,
+                                             forward_counts, kv_fetch_bytes,
+                                             paged_decode_step)
+
         if not self.running:
             time.sleep(0.001)
             return 0
         tr = self.tracer
-        batch = len(self.running)
+        rs = list(self.running.values())
+        batch = len(rs)
         with tr.span("decode_step", cat="serve") as sp:
             # one batched reader session over the whole step's working
             # set: the paper's traversal-retention argument at serving
             # granularity (one publish on ping instead of a fence per block)
             with tr.span("pool_reserve", cat="smr"):
-                session = [b for r in self.running.values()
-                           for b in r.all_blocks]
+                session = [b for r in rs for b in r.all_blocks]
                 self.pool.reserve(self.engine_id, session)
             if self.stall_every and self.steps % self.stall_every == \
                     self.stall_every - 1:
                 self.injected_stalls += 1
                 with tr.span("desched_stall", cat="fault"):
                     time.sleep(self.stall_s)
-            if self.kv_store is not None:
-                finished = self._step_paged()
-            else:
-                finished = self._step_dense()
+            with tr.span("pool_tripwire", cat="smr"):
+                gather = [b for r in rs for b in r.all_blocks]
+                self.pool.touch(self.engine_id, gather)             # pool
+                self.kv_store.assert_alive(self.engine_id, gather)  # pages
+            # the host builds the inputs, the table and every launch; the
+            # device runs behind it
+            with tr.span("dispatch", cat="serve") as dsp:
+                blocks = [r.all_blocks for r in rs]
+                lens = [len(r.prompt) + len(r.out) for r in rs]
+                last = [r.out[-1] if r.out else r.prompt[-1] for r in rs]
+                logits = paged_decode_step(self.params, self.cfg,
+                                           self.kv_store, blocks, lens, last,
+                                           impl=self.kernel_impl)
+                if tr.enabled:
+                    live, slots = decode_table_pages(self.kv_store, lens)
+                    dsp.set_args({"live_pages": live, "table_pages": slots,
+                                  "kv_bytes": kv_fetch_bytes(
+                                      self.kv_store, self.cfg, blocks, lens),
+                                  **forward_counts()})
+            # the host waits for the device to finish the step
+            with tr.span("token_sync", cat="serve"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            now = time.monotonic()
+            finished = []
+            for r, tok in zip(rs, nxt):
+                r.out.append(int(tok))
+                self._note_token(r, now)
+                if len(r.out) >= r.max_new:
+                    finished.append(r.rid)
             # retire may run a whole publish-on-ping pass inline
             with tr.span("pool_retire", cat="smr"):
                 for rid in finished:
                     r = self.running.pop(rid)
-                    self._caches.pop(rid, None)
                     self.pool.retire(self.engine_id, r.blocks)      # -> SMR
                     if r.shared_blocks:
                         self.pool.release_shared(self.engine_id,
@@ -681,64 +622,6 @@ class EngineWorker(_PoolActor):
             if tr.enabled:
                 sp.set_args({"batch": batch, "finished": len(finished)})
         return batch
-
-    def _step_dense(self) -> List[int]:
-        """Per-request decode against private dense caches."""
-        finished = []
-        for rid, r in list(self.running.items()):
-            self.pool.touch(self.engine_id, r.all_blocks)   # UAF tripwire
-            cache = self._caches[rid]
-            last = r.out[-1] if r.out else r.prompt[-1]
-            tok = jnp.asarray([[last]], jnp.int32)
-            logits, cache, _ = self._decode(self.params, cache, tok)
-            nxt = int(jnp.argmax(logits[0, -1]))
-            r.out.append(nxt)
-            self._note_token(r, time.monotonic())
-            self._caches[rid] = cache
-            if len(r.out) >= r.max_new:
-                finished.append(rid)
-        return finished
-
-    def _step_paged(self) -> List[int]:
-        """ONE batched (table, lens, q) decode through the paged kernel:
-        every running request becomes a block-table row over the shared
-        physical pages -- ragged lengths, prefix pages included in place."""
-        from repro.serve.paged_model import (decode_table_pages,
-                                             forward_counts, kv_fetch_bytes,
-                                             paged_decode_step)
-
-        tr = self.tracer
-        rs = list(self.running.values())
-        with tr.span("pool_tripwire", cat="smr"):
-            gather = [b for r in rs for b in r.all_blocks]
-            self.pool.touch(self.engine_id, gather)             # pool
-            self.kv_store.assert_alive(self.engine_id, gather)  # pages
-        # the host builds the inputs, the table and every launch; the
-        # device runs behind it
-        with tr.span("dispatch", cat="serve") as sp:
-            blocks = [r.all_blocks for r in rs]
-            lens = [len(r.prompt) + len(r.out) for r in rs]
-            last = [r.out[-1] if r.out else r.prompt[-1] for r in rs]
-            logits = paged_decode_step(self.params, self.cfg, self.kv_store,
-                                       blocks, lens, last,
-                                       impl=self.kernel_impl)
-            if tr.enabled:
-                live, slots = decode_table_pages(self.kv_store, lens)
-                sp.set_args({"live_pages": live, "table_pages": slots,
-                             "kv_bytes": kv_fetch_bytes(
-                                 self.kv_store, self.cfg, blocks, lens),
-                             **forward_counts()})
-        # the host waits for the device to finish the step
-        with tr.span("token_sync", cat="serve"):
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        now = time.monotonic()
-        finished = []
-        for r, tok in zip(rs, nxt):
-            r.out.append(int(tok))
-            self._note_token(r, now)
-            if len(r.out) >= r.max_new:
-                finished.append(r.rid)
-        return finished
 
     def _loop(self) -> None:
         tr = self.tracer
@@ -778,9 +661,10 @@ class PrefillWorker(_PoolActor):
     whoever picks it up adopts the blocks and resumes from ``r.prefilled``.
     """
 
-    def __init__(self, engine_id: int, cfg, params, pool: BlockPool, decode,
-                 **kw):
-        super().__init__(engine_id, cfg, params, pool, decode, **kw)
+    def __init__(self, engine_id: int, cfg, params, pool: BlockPool,
+                 kv_store: PagedKVStore, kernel_impl: str, **kw):
+        super().__init__(engine_id, cfg, params, pool, kv_store, kernel_impl,
+                         **kw)
         self._scheduler = None            # bound by Scheduler.__init__
         self.requests = 0                 # completed prefills
 

@@ -13,14 +13,15 @@ jax = pytest.importorskip("jax")
 
 from repro.configs.base import ArchConfig, dense_stack  # noqa: E402
 from repro.models.model import init_params  # noqa: E402
-from repro.serve.engine import ServeEngine  # noqa: E402
+
+from model_ref import GreedyReference  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 import chip_smoke  # noqa: E402
 
-# a tiny GQA config (2 query heads per KV head); f32 so the paged and dense
-# paths agree token for token
+# a tiny GQA config (2 query heads per KV head); f32 so the paged path and
+# the reference forward agree token for token
 TINY = ArchConfig(name="smoke-tiny", d_model=32, n_heads=4, n_kv_heads=2,
                   d_ff=64, vocab=64, groups=dense_stack(2), remat="none",
                   dtype="float32", act="gelu")
@@ -87,18 +88,11 @@ def test_token_check_catches_a_wrong_row(tiny_run):
 
 
 def test_flow_tokens_match_dense_path(tiny_run):
-    """The head-major page layout is a storage change, not a model change:
-    the smoke's paged tokens equal the dense path's."""
+    """The page layout is a storage change, not a model change: the
+    smoke's paged tokens equal a greedy decode by the reference forward."""
     res, _ = tiny_run
     params = init_params(TINY, jax.random.PRNGKey(TINY_RUN["seed"]))
     prompts = chip_smoke.make_prompts(TINY.vocab, TINY_RUN["prompt_lens"],
                                       TINY_RUN["page"], TINY_RUN["seed"])
-    eng = ServeEngine(TINY, params, kv_store="dense", max_batch=8,
-                      page_size=TINY_RUN["page"], max_seq=32)
-    eng.start()
-    reqs = [eng.submit(p, max_new=TINY_RUN["max_new"]) for p in prompts]
-    for r in reqs:
-        assert r.done.wait(timeout=300)
-    eng.stop()
-    assert eng.error is None
-    assert [list(r.out) for r in reqs] == res["outputs"]
+    ref = GreedyReference(params, TINY, max_new=TINY_RUN["max_new"])
+    assert [ref(p) for p in prompts] == res["outputs"]

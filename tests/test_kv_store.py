@@ -1,11 +1,11 @@
-"""Device-paged KV store: paged-vs-dense decode parity, physical-page
+"""Device-paged KV store: paged-vs-reference parity, physical-page
 use-after-free tripwires, and block-table raggedness edge cases.
 
-Parity is the load-bearing contract: the paged path (physical pages +
+Parity is the load-bearing contract: the paged path (device pages +
 Pallas paged-attention kernel in interpret mode) must produce the SAME
-tokens as the dense per-request-cache path, config by config -- otherwise
-"physically shared prefixes" would be a different model, not a different
-storage layer.
+tokens and logits as the reference forward, ``apply_model`` against a
+per-request cache, config by config -- otherwise "physically shared
+prefixes" would be a different model, not a storage layer.
 """
 
 import numpy as np
@@ -26,8 +26,9 @@ from repro.runtime.kv_store import PagedKVStore, kv_layer_order  # noqa: E402
 from repro.runtime.reclaim import UnsafeEagerPolicy  # noqa: E402
 from repro.serve.engine import ServeEngine  # noqa: E402
 from repro.serve.paged_model import (check_paged_support,  # noqa: E402
-                                     paged_decode_step, prefill_kv,
-                                     prefill_kv_chunked)
+                                     paged_decode_step, prefill_kv_chunked)
+
+from model_ref import GreedyReference, prefill_kv  # noqa: E402
 
 RNG = np.random.default_rng(3)
 
@@ -45,13 +46,21 @@ CONFIGS = [CFG_PLAIN, CFG_FANCY]
 
 PAGE = 4
 
+#: (Hkv, head_dim) of the two benchmark cells: starcoder2-7b, stablelm-12b
+GEOMETRIES = [(4, 128), (8, 160)]
 
-def _engine(cfg, params, mode, **kw):
+
+def _geometry_cfg(Hkv, hd):
+    return ArchConfig(name="kv-wide", d_model=64, n_heads=2 * Hkv,
+                      n_kv_heads=Hkv, head_dim=hd, d_ff=64, vocab=64,
+                      groups=dense_stack(2), remat="none", dtype="float32")
+
+
+def _engine(cfg, params, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("page_size", PAGE)
     kw.setdefault("num_pages", 64)
-    kw.setdefault("max_seq", 32)
-    return ServeEngine(cfg, params, kv_store=mode, **kw)
+    return ServeEngine(cfg, params, **kw)
 
 
 def _run(eng, prompts, max_new=4):
@@ -65,45 +74,40 @@ def _run(eng, prompts, max_new=4):
 
 
 # ----------------------------------------------------------------------------
-# parity: paged and dense decode produce identical tokens
+# parity: the paged path and the reference forward agree
 # ----------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("cfg", CONFIGS, ids=lambda c: c.name)
 def test_paged_dense_token_parity(cfg):
+    """The paged engine's greedy tokens are the reference forward's."""
     params = init_params(cfg, jax.random.PRNGKey(1))
     # varied raggedness: single-token tail page (5 = PAGE+1), page-aligned
     # (8), minimal (1), and a longer multi-page prompt
     prompts = [[1, 9, 3, 5, 2], [7, 2, 8, 6, 4, 1, 3, 5], [11],
                [int(x) for x in RNG.integers(1, cfg.vocab, 11)]]
-    outs = {"dense": _run(_engine(cfg, params, "dense"), prompts)}
-    # storage must be a residency knob, not a model change: host- and
-    # device-backed pages both match the dense path token for token
-    for storage in ("host", "device"):
-        outs[storage] = _run(
-            _engine(cfg, params, "paged", kv_storage=storage), prompts)
-    assert outs["host"] == outs["dense"]
-    assert outs["device"] == outs["dense"]
+    ref = GreedyReference(params, cfg, max_new=4)
+    assert _run(_engine(cfg, params), prompts) == [ref(p) for p in prompts]
 
 
 @pytest.mark.parametrize("parallel", [False, True],
                          ids=["serial", "parallel"])
-@pytest.mark.parametrize("storage", ["host", "device"])
+@pytest.mark.parametrize("base", CONFIGS, ids=lambda c: c.name)
 @pytest.mark.parametrize("dtype,atol", [("float32", 2e-4),
                                         ("bfloat16", 5e-2)])
-def test_paged_decode_logits_match_dense(dtype, atol, storage, parallel):
-    """One decode step, same prompt: paged logits vs dense logits, on both
-    storages, with a serial and a parallel residual.  The bf16 case pins
-    the store to the MODEL dtype (pages must hold exactly the values the
-    dense cache would, not silently-upcast f32) -- for device storage that
-    means resident bf16 device arrays."""
-    cfg = CFG_PLAIN.scaled(dtype=dtype,
-                           groups=dense_stack(2, parallel=parallel))
+def test_paged_decode_logits_match_dense(dtype, atol, base, parallel):
+    """One decode step, same prompt: paged logits vs the reference's
+    decode logits, for both configs with a serial and a parallel residual.
+    The bf16 case pins the store to the MODEL dtype (pages must hold
+    exactly the values the reference cache would, not silently-upcast f32:
+    resident bf16 device arrays)."""
+    cfg = base.scaled(dtype=dtype, groups=dense_stack(
+        base.groups[0].repeats, parallel=parallel))
     prompt = [3, 1, 4, 1, 5, 9, 2]
     params = init_params(cfg, jax.random.PRNGKey(2))
     n = len(prompt)
 
-    # dense: token-by-token prefill (the worker's path), then one decode
+    # reference: token-by-token prefill, then one decode
     cache = init_cache(cfg, 1, 32, cfg.dtype)
     toks = jnp.asarray([prompt], jnp.int32)
     for t in range(n):
@@ -113,8 +117,8 @@ def test_paged_decode_logits_match_dense(dtype, atol, storage, parallel):
         params, jnp.asarray([[prompt[-1]]], jnp.int32), cfg=cfg,
         mode="decode", cache=cache)
 
-    # paged: dense prefill written into pages, then one paged step
-    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage=storage)
+    # paged: the reference prefill written into pages, then one paged step
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE)
     assert store.k.dtype == np.dtype(cfg.dtype)
     blocks = [0, 1, 2]
     k, v = prefill_kv(params, cfg, prompt)
@@ -128,22 +132,22 @@ def test_paged_decode_logits_match_dense(dtype, atol, storage, parallel):
 
 @pytest.mark.parametrize("parallel", [False, True],
                          ids=["serial", "parallel"])
-@pytest.mark.parametrize("storage", ["host", "device"])
+@pytest.mark.parametrize("base", CONFIGS, ids=lambda c: c.name)
 @pytest.mark.parametrize("dtype,atol", [("float32", 2e-4),
                                         ("bfloat16", 5e-2)])
-def test_paged_prefill_chunk_logits_match_dense(dtype, atol, storage,
+def test_paged_prefill_chunk_logits_match_dense(dtype, atol, base,
                                                 parallel):
     """A prompt prefilled in chunks of 3 through the paged forward: every
-    chunk row's logits against the dense full-sequence forward's at that
-    position, on both storages, serial and parallel residual."""
-    cfg = CFG_FANCY.scaled(dtype=dtype,
-                           groups=dense_stack(3, parallel=parallel))
+    chunk row's logits against the reference full-sequence forward's at
+    that position, for both configs, serial and parallel residual."""
+    cfg = base.scaled(dtype=dtype, groups=dense_stack(
+        base.groups[0].repeats, parallel=parallel))
     prompt = [2, 7, 1, 8, 2, 8, 1, 4, 5, 9, 3]
     params = init_params(cfg, jax.random.PRNGKey(12))
     want = apply_model(params, jnp.asarray([prompt], jnp.int32), cfg=cfg,
                        mode="train")[0]
     want = np.asarray(want[0, :, :cfg.vocab], np.float32)
-    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage=storage)
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE)
     start = 0
     for end, logits in prefill_kv_chunked(params, cfg, store, [5, 0, 3],
                                           prompt, 3, impl="interpret"):
@@ -154,19 +158,17 @@ def test_paged_prefill_chunk_logits_match_dense(dtype, atol, storage,
     assert start == len(prompt)
 
 
-@pytest.mark.parametrize("storage,uploads", [("device", 1), ("host", None)])
-def test_forward_launches_two_programs_a_layer(storage, uploads):
+@pytest.mark.parametrize("cfg", CONFIGS, ids=lambda c: c.name)
+def test_forward_launches_two_programs_a_layer(cfg):
     """A decode step and a prefill chunk of an L-layer model each launch
     2L + 1 programs -- the kernel, and one program between two kernel
-    calls -- and, on device pages, put the step's inputs on the device
-    in one upload; host pages upload each layer's K and V besides."""
+    calls -- and put the step's inputs on the device in one upload."""
     from repro.serve.paged_model import forward_counts, prefill_chunk_step
 
-    cfg = CFG_FANCY
     L = len(kv_layer_order(cfg))
     params = init_params(cfg, jax.random.PRNGKey(13))
-    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage=storage)
-    want = {"launches": 2 * L + 1, "uploads": uploads or 1 + 2 * L}
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE)
+    want = {"launches": 2 * L + 1, "uploads": 1}
     prefill_chunk_step(params, cfg, store, [1, 2], [3, 1, 4, 1, 5], 0,
                        impl="interpret")
     assert forward_counts() == want
@@ -179,12 +181,11 @@ def test_fused_layer_program_writes_its_pages_in_place():
     """The program between two kernel calls donates the next layer's K and
     V pages: the compiled program aliases both into its outputs, and a
     decode step leaves every layer's page buffers where they were."""
-    from repro.runtime.kv_store import _set_rows
     from repro.serve.paged_model import _mlp_qkv, _out_mlp, _qkv
 
     cfg = CFG_PLAIN
     params = init_params(cfg, jax.random.PRNGKey(14))
-    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage="device")
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE)
     gp = params["groups"]["g0"]["p0"]
     B, H, hd = 3, cfg.n_heads, cfg.head_dim_
     x = jnp.zeros((B, 1, cfg.d_model), cfg.dtype)
@@ -193,8 +194,7 @@ def test_fused_layer_program_writes_its_pages_in_place():
     pages = store.layer_pages(1)
     compiled = _mlp_qkv.lower(
         gp, 0, x, attn, gp, 1, idx[:, None], pages, idx, idx, cfg=cfg,
-        rows=8, parallel=False, out_mlp=_out_mlp, qkv=_qkv,
-        write=_set_rows).compile()
+        rows=8, parallel=False, out_mlp=_out_mlp, qkv=_qkv).compile()
     assert compiled.memory_analysis().alias_size_in_bytes == \
         pages[0].nbytes + pages[1].nbytes
 
@@ -238,12 +238,12 @@ def test_concurrent_prefill_write_during_decode_steps():
             store.write_prefill([3 + i % 4], *other)
             i += 1
 
-    serial = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage="device")
+    serial = PagedKVStore(cfg, num_blocks=8, page_size=PAGE)
     serial.write_prefill([0, 1, 2], k, v)
     want_tok = decode(serial, 6)
     writes(serial, 4)
 
-    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage="device")
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE)
     store.write_prefill([0, 1, 2], k, v)
     fetch = store.layer_pages
 
@@ -282,8 +282,9 @@ def test_concurrent_prefill_write_during_decode_steps():
 
 
 def test_prefill_kv_matches_decode_appends():
-    """The dense-prefill extraction and the per-token decode appends must
-    write the SAME physical pages (post-rope K/V, same layer order)."""
+    """The reference prefill's K/V and the per-token paged decode steps
+    must write the SAME physical pages (post-rope K/V, same layer
+    order)."""
     cfg, prompt = CFG_FANCY, [2, 7, 1, 8, 2, 8]
     params = init_params(cfg, jax.random.PRNGKey(3))
     a = PagedKVStore(cfg, num_blocks=4, page_size=PAGE)
@@ -306,7 +307,7 @@ def test_paged_prefix_hit_installs_zero_bytes():
     cfg = CFG_PLAIN
     params = init_params(cfg, jax.random.PRNGKey(4))
     prompt = [5, 3, 9, 1, 2, 6, 4, 8]          # exactly 2 pages at PAGE=4
-    eng = _engine(cfg, params, "paged", prefix_cache=True, n_engines=1)
+    eng = _engine(cfg, params, prefix_cache=True, n_engines=1)
     eng.start()
     r1 = eng.submit(prompt, max_new=3)
     assert r1.done.wait(timeout=300)
@@ -328,20 +329,20 @@ def test_paged_prefix_hit_installs_zero_bytes():
 # ----------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("storage", ["host", "device"])
-def test_poison_on_unsafe_free_trips_gather(storage):
+@pytest.mark.parametrize("Hkv,hd", GEOMETRIES)
+def test_poison_on_unsafe_free_trips_gather(Hkv, hd):
     """A freed-then-gathered page must be a hard UseAfterFree, exactly like
-    the simulated backends' FREED-state check.  On device storage the
-    poison fill is itself a device op (donated ``pages.at[blocks].set``),
-    so the tripwire survives the move off the host."""
-    cfg = CFG_PLAIN
+    the simulated backends' FREED-state check.  The poison fill is itself
+    a device op (donated ``pages.at[blocks].set``), at both cells' head
+    geometries."""
+    cfg = _geometry_cfg(Hkv, hd)
     pool = BlockPool(8, n_engines=2, policy=UnsafeEagerPolicy())
-    store = PagedKVStore(cfg, pool.num_blocks, PAGE, storage=storage)
+    store = PagedKVStore(cfg, pool.num_blocks, PAGE)
     pool.add_block_listener(store)
     blocks = pool.allocate(0, 2)
     L = len(kv_layer_order(cfg))
-    store.write_prefill(blocks, np.ones((L, PAGE, 2, 8), np.float32),
-                        np.ones((L, PAGE, 2, 8), np.float32))
+    store.write_prefill(blocks, np.ones((L, PAGE, Hkv, hd), np.float32),
+                        np.ones((L, PAGE, Hkv, hd), np.float32))
     # engine 1 opens a reader session over the blocks -- the unsafe policy
     # frees them on retire anyway
     pool.reserve(1, blocks)
@@ -376,11 +377,11 @@ def test_safe_policy_keeps_pages_alive_under_session():
         store.assert_alive(1, blocks)
 
 
-@pytest.mark.parametrize("storage", ["host", "device"])
-def test_realloc_unpoisons_and_zeroes(storage):
-    cfg = CFG_PLAIN
+@pytest.mark.parametrize("Hkv,hd", GEOMETRIES)
+def test_realloc_unpoisons_and_zeroes(Hkv, hd):
+    cfg = _geometry_cfg(Hkv, hd)
     pool = BlockPool(2, n_engines=1, policy=UnsafeEagerPolicy())
-    store = PagedKVStore(cfg, pool.num_blocks, PAGE, storage=storage)
+    store = PagedKVStore(cfg, pool.num_blocks, PAGE)
     pool.add_block_listener(store)
     blocks = pool.allocate(0, 2)
     pool.retire(0, blocks)                     # freed + poisoned
@@ -403,13 +404,13 @@ def test_device_steady_state_decode_moves_zero_kv_bytes():
     cfg = CFG_PLAIN
     params = init_params(cfg, jax.random.PRNGKey(6))
     prompt = [3, 1, 4, 1, 5]
-    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage="device")
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE)
     blocks = [0, 1, 2]                 # 12 slots: 5 prompt + 4 decode fits
     k, v = prefill_kv(params, cfg, prompt)
     store.write_prefill(blocks, k, v)
-    # prefill_kv returns device arrays in this process, but write_prefill
-    # may legitimately pay h2d for host-sourced prefill data elsewhere --
-    # the steady-state claim is about what happens AFTER this point
+    # the reference prefill's K/V come from the host, so write_prefill pays
+    # h2d for them -- the steady-state claim is about what happens AFTER
+    # this point
     baseline = store.bytes_h2d
     store.sync()
     ptr_before = store.layer_pages(0)[0].unsafe_buffer_pointer()
@@ -421,81 +422,82 @@ def test_device_steady_state_decode_moves_zero_kv_bytes():
     store.sync()
     assert store.bytes_h2d == baseline, (
         f"steady-state decode uploaded {store.bytes_h2d - baseline} KV bytes")
-    assert store.bytes_d2h == 0
     # donated scatters reuse the same device buffer: in place, O(tokens)
     assert store.layer_pages(0)[0].unsafe_buffer_pointer() == ptr_before
 
 
-def test_device_and_host_pages_hold_identical_values():
-    """Same writes through both storages -> bit-identical page pools (the
-    storage seam changes residency, not contents)."""
+def test_forward_writes_pages_where_numpy_puts_them():
+    """A prefill through the store, then two decode steps of the paged
+    forward, which writes each fed token's K/V inside its own programs:
+    the device pages equal a numpy page array this test fills itself from
+    the reference prefill of the same tokens, slot by slot, and untouched
+    pages stay zero."""
     cfg = CFG_FANCY
     params = init_params(cfg, jax.random.PRNGKey(7))
-    prompt = [2, 7, 1, 8, 2, 8, 1]
-    host = PagedKVStore(cfg, num_blocks=4, page_size=PAGE, storage="host")
-    dev = PagedKVStore(cfg, num_blocks=4, page_size=PAGE, storage="device")
+    prompt, fed = [2, 7, 1, 8, 2, 8, 1], [9, 4]
+    blocks = [3, 0, 5]                 # 7 prompt + 2 decode positions
+    store = PagedKVStore(cfg, num_blocks=6, page_size=PAGE)
     k, v = prefill_kv(params, cfg, prompt)
-    for st in (host, dev):
-        st.write_prefill([0, 1], k, v)
-        paged_decode_step(params, cfg, st, [[0, 1]], [len(prompt)], [9],
-                          impl="interpret")
-    np.testing.assert_array_equal(np.asarray(host.k), np.asarray(dev.k))
-    np.testing.assert_array_equal(np.asarray(host.v), np.asarray(dev.v))
+    store.write_prefill(blocks, k, v)
+    for i, tok in enumerate(fed):
+        paged_decode_step(params, cfg, store, [blocks], [len(prompt) + i],
+                          [tok], impl="interpret")
+    k, v = prefill_kv(params, cfg, prompt + fed)       # (L, S, Hkv, hd)
+    L, S = k.shape[:2]
+    want = {n: np.zeros((L, 6, PAGE, k.shape[2] * k.shape[3]), np.float32)
+            for n in "kv"}
+    for n, a in (("k", k), ("v", v)):
+        for pos in range(S):
+            want[n][:, blocks[pos // PAGE], pos % PAGE] = \
+                a[:, pos].reshape(L, -1)
+    np.testing.assert_allclose(np.asarray(store.k), want["k"],
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(store.v), want["v"],
+                               atol=2e-5, rtol=2e-5)
 
 
-def test_pallas_scatter_matches_jnp_scatter():
-    """The Pallas token-scatter kernel and the jnp ``.at[].set`` path write
-    identical pools (and both leave untouched pages untouched)."""
-    from repro.kernels.paged_attention import paged_scatter_pallas
-    P, page, Hkv, D, T = 6, 4, 2, 16, 7
-    pages = jnp.asarray(RNG.normal(size=(P, page, Hkv * D)), jnp.float32)
-    blk = jnp.asarray(RNG.integers(0, P, T), jnp.int32)
-    slot = jnp.asarray(RNG.integers(0, page, T), jnp.int32)
-    vals = jnp.asarray(RNG.normal(size=(T, Hkv, D)), jnp.float32)
-    want = pages.at[blk, slot].set(vals.reshape(T, Hkv * D))
-    got = paged_scatter_pallas(pages, blk, slot, vals, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+def test_set_rows_takes_pages_in_any_order():
+    """The page write, jitted and donated as the store runs it: tokens
+    whose pages interleave (a page revisited after another) land where a
+    numpy loop over the tokens puts them, and other pages are left as
+    they were."""
+    from repro.runtime.kv_store import _device_fns
 
-
-def test_pallas_scatter_takes_pages_in_any_order():
-    """Tokens whose pages interleave (a page revisited after another) land
-    where the jnp scatter puts them; the kernel keeps a page resident only
-    across consecutive tokens, so the wrapper must regroup them."""
-    from repro.kernels.paged_attention import paged_scatter_pallas
+    scatter, _ = _device_fns()
     P, page, Hkv, D = 5, 4, 2, 16
-    pages = jnp.asarray(RNG.normal(size=(P, page, Hkv * D)), jnp.float32)
-    blk = jnp.asarray([3, 1, 3, 0, 1, 3], jnp.int32)
-    slot = jnp.asarray([0, 2, 1, 3, 0, 2], jnp.int32)
-    vals = jnp.asarray(RNG.normal(size=(6, Hkv, D)), jnp.float32)
-    want = pages.at[blk, slot].set(vals.reshape(6, Hkv * D))
-    got = paged_scatter_pallas(pages, blk, slot, vals, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    pages = RNG.normal(size=(P, page, Hkv * D)).astype(np.float32)
+    blk = np.asarray([3, 1, 3, 0, 1, 3], np.int32)
+    slot = np.asarray([0, 2, 1, 3, 0, 2], np.int32)
+    vals = RNG.normal(size=(6, Hkv, D)).astype(np.float32)
+    want = pages.copy()
+    for t in range(6):
+        want[blk[t], slot[t]] = vals[t].reshape(-1)
+    got = scatter(jnp.asarray(pages), jnp.asarray(blk), jnp.asarray(slot),
+                  jnp.asarray(vals))
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
-@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("Hkv,hd", [(8, 160), (4, 128)])
-def test_store_writes_and_fills_token_major_pages(Hkv, hd, impl):
-    """A device store's prefill writes, decode appends, poison and zeroing
-    land where a plain numpy model of the ``(P, page, Hkv * hd)`` layout
-    puts them, with either scatter."""
-    cfg = ArchConfig(name="kv-wide", d_model=64, n_heads=2 * Hkv,
-                     n_kv_heads=Hkv, head_dim=hd, d_ff=64, vocab=64,
-                     groups=dense_stack(2), remat="none", dtype="float32")
+def test_store_writes_and_fills_token_major_pages(Hkv, hd, dtype):
+    """A store's prefill writes, decode appends, poison and zeroing land
+    where a plain numpy model of the ``(P, page, Hkv * hd)`` layout puts
+    them, in the model's dtype."""
+    cfg = _geometry_cfg(Hkv, hd).scaled(dtype=dtype)
+    dt = np.dtype(jnp.dtype(dtype))
     L, P, W = 2, 6, Hkv * hd
-    store = PagedKVStore(cfg, num_blocks=P, page_size=PAGE,
-                         storage="device", scatter_impl=impl)
-    want = {"k": np.zeros((L, P, PAGE, W), np.float32),
-            "v": np.zeros((L, P, PAGE, W), np.float32)}
+    store = PagedKVStore(cfg, num_blocks=P, page_size=PAGE)
+    want = {"k": np.zeros((L, P, PAGE, W), dt),
+            "v": np.zeros((L, P, PAGE, W), dt)}
     # a 6-token prefill into pages 4 then 1, all layers at once
-    kv = {n: RNG.normal(size=(L, 6, Hkv, hd)).astype(np.float32)
-          for n in "kv"}
+    kv = {n: RNG.normal(size=(L, 6, Hkv, hd)).astype(dt) for n in "kv"}
     store.write_prefill([4, 1], kv["k"], kv["v"])
     for n in "kv":
         for pos in range(6):
             want[n][:, [4, 1][pos // PAGE], pos % PAGE] = \
                 kv[n][:, pos].reshape(L, W)
     # one decode append of two rows into layer 1
-    kv = {n: RNG.normal(size=(2, Hkv, hd)).astype(np.float32) for n in "kv"}
+    kv = {n: RNG.normal(size=(2, Hkv, hd)).astype(dt) for n in "kv"}
     store.append_tokens([1, 3], [2, 0], jnp.asarray(kv["k"]),
                         jnp.asarray(kv["v"]), layer=1)
     for n in "kv":
@@ -506,19 +508,21 @@ def test_store_writes_and_fills_token_major_pages(Hkv, hd, impl):
     for n in "kv":
         want[n][:, 3] = PagedKVStore.POISON
         want[n][:, 4] = 0.0
-    np.testing.assert_array_equal(np.asarray(store.k), want["k"])
-    np.testing.assert_array_equal(np.asarray(store.v), want["v"])
+    assert store.k.dtype == dt
+    for n, got in (("k", store.k), ("v", store.v)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      want[n].astype(np.float32))
 
 
-@pytest.mark.parametrize("storage", ["host", "device"])
-def test_store_counts_its_device_and_element_bytes(storage):
+@pytest.mark.parametrize("Hkv,hd", GEOMETRIES)
+def test_store_counts_its_device_and_element_bytes(Hkv, hd):
     """The store puts the pool's bytes on the registry once, at
     construction; off a TPU nothing is padded."""
     from repro.obs import MetricsRegistry
 
     reg = MetricsRegistry()
-    store = PagedKVStore(CFG_FANCY, num_blocks=8, page_size=PAGE,
-                         storage=storage, metrics=reg)
+    store = PagedKVStore(_geometry_cfg(Hkv, hd), num_blocks=8,
+                         page_size=PAGE, metrics=reg)
     got = reg.counters()
     assert got["kv_pool_element_bytes"] == store.nbytes > 0
     assert got["kv_pool_device_bytes"] == store.device_bytes == store.nbytes
@@ -526,47 +530,37 @@ def test_store_counts_its_device_and_element_bytes(storage):
     assert store.page_fetch_bytes * L * 8 == store.device_bytes
 
 
-def test_device_store_pallas_scatter_matches_jnp_scatter():
-    """The store-level wiring of the Pallas write path: a device store
-    writing through ``scatter_impl="pallas"`` holds the same pages as one
-    writing through the jnp scatter, prefill and decode alike."""
-    cfg = CFG_FANCY
-    params = init_params(cfg, jax.random.PRNGKey(9))
-    prompt = [4, 2, 6, 1, 9, 3]
-    stores = [PagedKVStore(cfg, num_blocks=4, page_size=PAGE,
-                           storage="device", scatter_impl=impl)
-              for impl in ("jnp", "pallas")]
-    k, v = prefill_kv(params, cfg, prompt)
-    for st in stores:
-        st.write_prefill([2, 0], k, v)
-        paged_decode_step(params, cfg, st, [[2, 0]], [len(prompt)], [5],
-                          impl="interpret")
-    np.testing.assert_array_equal(np.asarray(stores[0].k),
-                                  np.asarray(stores[1].k))
-    np.testing.assert_array_equal(np.asarray(stores[0].v),
-                                  np.asarray(stores[1].v))
-
-
-def test_host_storage_pays_per_step_upload_device_does_not():
-    """The A/B the benchmark reports: identical traffic, host storage
-    re-uploads the pool every step while device storage moves nothing."""
-    cfg = CFG_PLAIN
-    params = init_params(cfg, jax.random.PRNGKey(8))
-    prompts = [[1, 9, 3, 5, 2], [7, 2, 8]]
-    stats = {}
-    for storage in ("host", "device"):
-        eng = _engine(cfg, params, "paged", kv_storage=storage)
-        _run(eng, prompts)
-        stats[storage] = eng.kv_copy_stats()
-    assert stats["host"]["bytes_h2d"] > 0
-    assert stats["device"]["bytes_h2d"] == 0
-    assert stats["device"]["bytes_h2d_per_step"] == 0
-
-
 def test_engine_rejects_bad_kv_storage():
     params = init_params(CFG_PLAIN, jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="kv_storage"):
         ServeEngine(CFG_PLAIN, params, kv_store="paged", kv_storage="hbm")
+
+
+@pytest.mark.parametrize("kw", [{"kv_store": "dense"},
+                                {"kv_storage": "host"}],
+                         ids=["dense", "host"])
+def test_engine_refuses_the_removed_kv_paths(kw):
+    """The dense path and host page storage are gone: asking for either is
+    a construction error that says so."""
+    with pytest.raises(ValueError, match="removed"):
+        ServeEngine(CFG_PLAIN, None, **kw)
+
+
+def test_kv_copy_stats_of_a_served_run():
+    """The engine's KV accounting after real traffic: every prompt's pages
+    written once, no host upload, no field of a removed storage."""
+    params = init_params(CFG_PLAIN, jax.random.PRNGKey(8))
+    eng = _engine(CFG_PLAIN, params)
+    prompts = [[1, 9, 3, 5, 2], [7, 2, 8]]
+    _run(eng, prompts)
+    stats = eng.kv_copy_stats()
+    assert set(stats) == {"kv_store", "admitted_hit", "admitted_miss",
+                          "bytes_hit", "bytes_miss", "bytes_per_hit",
+                          "bytes_per_miss", "bytes_h2d"}
+    assert stats["kv_store"] == "paged" and stats["bytes_h2d"] == 0
+    assert stats["admitted_miss"] == 2 and stats["bytes_hit"] == 0
+    assert stats["bytes_miss"] == \
+        sum(map(len, prompts)) * eng.kv_store.token_bytes
 
 
 # ----------------------------------------------------------------------------
@@ -642,9 +636,8 @@ def test_paged_serving_under_smr_policy(smr):
     params = init_params(cfg, jax.random.PRNGKey(5))
     pool = BlockPool(32, n_engines=2, reclaim_threshold=4,
                      pressure_factor=2, policy=make_policy(smr))
-    eng = ServeEngine(cfg, params, max_batch=4, page_size=PAGE, max_seq=32,
-                      pool=pool, n_engines=1, prefix_cache=True,
-                      kv_store="paged")
+    eng = ServeEngine(cfg, params, max_batch=4, page_size=PAGE,
+                      pool=pool, n_engines=1, prefix_cache=True)
     eng.start()
     prompt = [5, 3, 9, 1]
     reqs = [eng.submit(prompt + [i + 1], max_new=3) for i in range(4)]
@@ -668,7 +661,7 @@ def test_dispatch_span_counts_live_and_table_pages():
 
     tr = Tracer()
     params = init_params(CFG_PLAIN, jax.random.PRNGKey(6))
-    eng = _engine(CFG_PLAIN, params, "paged", trace=tr)
+    eng = _engine(CFG_PLAIN, params, trace=tr)
     # queued before the engine starts, so the first step admits all three
     reqs = [eng.submit(p, max_new=3) for p in ([1, 2], [3] * 9, [5] * 14)]
     eng.start()
@@ -714,7 +707,7 @@ def test_spans_carry_the_kv_bytes_they_fetch():
 
     tr = Tracer()
     params = init_params(CFG_PLAIN, jax.random.PRNGKey(6))
-    eng = _engine(CFG_PLAIN, params, "paged", trace=tr, prefill_chunk=4)
+    eng = _engine(CFG_PLAIN, params, trace=tr, prefill_chunk=4)
     _run(eng, [[1, 2, 3, 4, 5, 6], [3] * 9], max_new=3)
     eng.stop()
     assert eng.error is None, f"engine failed: {eng.error!r}"
@@ -730,14 +723,13 @@ def test_spans_carry_the_kv_bytes_they_fetch():
 
 def test_spans_carry_the_forward_counts():
     """Each traced ``dispatch`` and ``prefill_chunk`` span has the
-    forward's ``launches`` (2L + 1) and ``uploads`` (1, device pages)."""
+    forward's ``launches`` (2L + 1) and ``uploads`` (1)."""
     from repro.obs.trace import Tracer
 
     tr = Tracer()
     params = init_params(CFG_FANCY, jax.random.PRNGKey(6))
-    eng = _engine(CFG_FANCY, params, "paged", trace=tr, prefill_chunk=4)
+    eng = _engine(CFG_FANCY, params, trace=tr, prefill_chunk=4)
     _run(eng, [[1, 2, 3, 4, 5, 6], [3] * 9], max_new=3)
-    assert eng.kv_store.storage == "device"
     L = len(eng.kv_store.layer_order)
     got = {}
     for e in tr.to_dict()["traceEvents"]:
@@ -752,15 +744,14 @@ def test_forward_traced_whole_leaves_nothing_traced_behind():
     """A paged decode step traced whole into one program, as a compile
     check does, and then run eagerly: the layer indices the forward keeps
     on the device stay concrete, so no tracer escapes the trace."""
-    from repro.runtime.kv_store import _set_rows
     from repro.serve import paged_model
 
     cfg = CFG_PLAIN
     params = init_params(cfg, jax.random.PRNGKey(16))
-    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE, storage="device")
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE)
 
     class Traced:
-        layer_order, page, storage = store.layer_order, PAGE, "device"
+        layer_order, page = store.layer_order, PAGE
 
         def __init__(self, k, v):
             self.k, self.v = list(k), list(v)
@@ -769,8 +760,8 @@ def test_forward_traced_whole_leaves_nothing_traced_behind():
             return store.write_guard()
 
         def write_in(self, layer, tokens, program):
-            self.k[layer], self.v[layer], out = program(
-                _set_rows, self.k[layer], self.v[layer])
+            self.k[layer], self.v[layer], out = program(self.k[layer],
+                                                        self.v[layer])
             return out
 
         def layer_pages(self, li):
@@ -823,3 +814,14 @@ def test_unsupported_config_rejected_up_front():
         ServeEngine(bad, params, kv_store="paged")
     with pytest.raises(ValueError, match="kv_store"):
         ServeEngine(CFG_PLAIN, params, kv_store="blocked")
+
+
+@pytest.mark.parametrize("groups", [
+    dense_stack(2, attn_kind="local"), dense_stack(2, attn_kind="mla"),
+    dense_stack(2, mlp="moe")], ids=["local", "mla", "moe"])
+def test_engine_refuses_configs_the_paged_path_cannot_run(groups):
+    """No config is served another way: one the paged path refuses is a
+    construction error, with no ``kv_store`` asked for."""
+    bad = CFG_PLAIN.scaled(groups=groups)
+    with pytest.raises(ValueError, match="not supported"):
+        ServeEngine(bad, None)

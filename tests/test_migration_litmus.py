@@ -277,7 +277,7 @@ def test_serving_migration_smoke(scheme):
     params = init_params(cfg, jax.random.PRNGKey(0))
     smr = None if scheme == "EpochPOP-pool" else scheme
     eng = ServeEngine(cfg, params, max_batch=2, page_size=4, num_pages=96,
-                      max_seq=32, smr=smr, n_engines=3, sim_backend="vec",
+                      smr=smr, n_engines=3, sim_backend="vec",
                       place_policy="static", migrate=True,
                       migrate_interval_s=0.005, migrate_threshold=2,
                       stall_every=2, stall_s=0.05, stall_workers=(0,))

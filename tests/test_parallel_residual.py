@@ -95,8 +95,7 @@ def test_dense_forward_is_the_parallel_block(model):
 def test_paged_path_is_the_parallel_block(model):
     cfg, params, tokens, want = model
     n = 9                                   # prefilled, then decoded
-    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE,
-                         storage="device")
+    store = PagedKVStore(cfg, num_blocks=8, page_size=PAGE)
     blocks = list(range(1, 1 + -(-len(tokens) // PAGE)))
     for end, logits in prefill_kv_chunked(params, cfg, store, blocks,
                                           tokens[:n], 4, impl="interpret"):

@@ -10,7 +10,7 @@ batched forward per ``prefill_chunk`` tokens through the paged kernel, a
 reader).  So:
 
 1. chunked prefill writes the SAME pages (and final logits) as the
-   one-shot dense prefill extraction, config by config;
+   reference forward's one-shot prefill, config by config;
 2. a reclaimer ping that lands mid-prefill is serviced within ONE chunk
    boundary, not one prompt (the publish-on-ping delivery window);
 3. a request stopped mid-prefill is resumable: a peer worker adopts its
@@ -34,10 +34,12 @@ from repro.runtime.kv_store import PagedKVStore  # noqa: E402
 from repro.runtime.reclaim import EpochPOPPolicy, make_policy  # noqa: E402
 from repro.serve.engine import ServeEngine  # noqa: E402
 from repro.serve.paged_model import (paged_decode_step,  # noqa: E402
-                                     prefill_kv, prefill_kv_chunked)
+                                     prefill_kv_chunked)
 from repro.serve.worker import PrefillWorker, Request  # noqa: E402
 
 import jax.numpy as jnp  # noqa: E402
+
+from model_ref import prefill_kv  # noqa: E402
 
 # the same two architectures the kv-store parity suite pins: plain GQA and
 # one exercising qk_norm / post_norms / softcap / partial rotary / tying
@@ -54,7 +56,8 @@ PAGE = 4
 
 
 # ----------------------------------------------------------------------------
-# parity: chunked paged prefill == full dense prefill (pages and logits)
+# parity: chunked paged prefill == the reference's full prefill (pages and
+# logits)
 # ----------------------------------------------------------------------------
 
 
@@ -62,8 +65,8 @@ PAGE = 4
 @pytest.mark.parametrize("chunk", [1, 3, 16])
 def test_chunked_vs_full_prefill_page_and_logit_parity(cfg, chunk):
     """Chunk size must be a storage/scheduling knob, not a model change:
-    the pages after chunked prefill match the one-shot dense extraction,
-    and the final chunk's last-row logits match the dense prefill logits
+    the pages after chunked prefill match the reference's one-shot
+    prefill, and the final chunk's last-row logits match its logits
     (chunk=1 is the old token-by-token replay; 16 > prompt is one shot)."""
     params = init_params(cfg, jax.random.PRNGKey(7))
     prompt = [2, 7, 1, 8, 2, 8, 1, 4, 5, 9, 3]      # 11: ragged tail page
@@ -80,10 +83,10 @@ def test_chunked_vs_full_prefill_page_and_logit_parity(cfg, chunk):
     np.testing.assert_allclose(full.k, chunked.k, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(full.v, chunked.v, atol=2e-5, rtol=2e-5)
 
-    dense_logits, _, _ = apply_model(params, jnp.asarray([prompt], jnp.int32),
+    ref_logits, _, _ = apply_model(params, jnp.asarray([prompt], jnp.int32),
                                      cfg=cfg, mode="prefill")
     np.testing.assert_allclose(np.asarray(last[-1], np.float32),
-                               np.asarray(dense_logits[0, -1], np.float32),
+                               np.asarray(ref_logits[0, -1], np.float32),
                                atol=2e-4, rtol=2e-4)
 
     # and the next decode step over either store agrees on the token
@@ -134,9 +137,8 @@ def test_ping_mid_prefill_is_serviced_within_a_chunk():
     prompt = [1 + (i % 40) for i in range(40)]
     params = init_params(cfg, jax.random.PRNGKey(9))
     eng = ServeEngine(cfg, params, max_batch=2, page_size=PAGE,
-                      num_pages=32, max_seq=64, n_engines=1,
-                      prefill_workers=1, prefill_chunk=chunk,
-                      kv_store="paged")
+                      num_pages=32, n_engines=1,
+                      prefill_workers=1, prefill_chunk=chunk)
     policy = eng.pool.policy
     assert isinstance(policy, EpochPOPPolicy)
     prefill_eid = eng.prefill_workers[0].engine_id
@@ -184,10 +186,9 @@ def test_partial_prefill_resumable_across_workers():
     pool = BlockPool(16, n_engines=2, reclaim_threshold=4)
     store = PagedKVStore(cfg, pool.num_blocks, PAGE)
     pool.add_block_listener(store)
-    mk = lambda eid: PrefillWorker(eid, cfg, params, pool, None,  # noqa: E731
-                                   page_size=PAGE, max_seq=32,
-                                   kv_store=store, prefill_chunk=chunk,
-                                   kernel_impl="interpret")
+    mk = lambda eid: PrefillWorker(eid, cfg, params, pool,  # noqa: E731
+                                   store, "interpret", page_size=PAGE,
+                                   prefill_chunk=chunk)
     w0, w1 = mk(0), mk(1)
 
     r = Request(1, list(prompt), max_new=4)
@@ -205,8 +206,8 @@ def test_partial_prefill_resumable_across_workers():
     # the resuming worker only prefilled the remainder
     assert w1.prefill_tokens == len(prompt) - w0.prefill_tokens
 
-    # pages match an uninterrupted dense-extraction prefill bit-for-bit in
-    # the written range
+    # pages match the reference's uninterrupted prefill in the written
+    # range
     ref = PagedKVStore(cfg, pool.num_blocks, PAGE)
     k, v = prefill_kv(params, cfg, prompt)
     ref.write_prefill(r.all_blocks, k, v)
@@ -226,9 +227,8 @@ def test_stop_finalizes_stranded_prefill_queue():
     params = init_params(cfg, jax.random.PRNGKey(15))
     prompt = [1 + (i % 30) for i in range(30)]
     eng = ServeEngine(cfg, params, max_batch=2, page_size=PAGE,
-                      num_pages=32, max_seq=40, n_engines=1,
-                      prefill_workers=1, prefill_chunk=chunk,
-                      kv_store="paged")
+                      num_pages=32, n_engines=1,
+                      prefill_workers=1, prefill_chunk=chunk)
     eng.start()
     r = eng.submit(prompt, max_new=2)
     deadline = time.monotonic() + 120
@@ -250,9 +250,8 @@ def test_stop_finalizes_inline_prefill_too():
     params = init_params(cfg, jax.random.PRNGKey(17))
     prompt = [1 + (i % 30) for i in range(30)]
     eng = ServeEngine(cfg, params, max_batch=2, page_size=PAGE,
-                      num_pages=32, max_seq=40, n_engines=1,
-                      prefill_workers=0, prefill_chunk=chunk,
-                      kv_store="paged")
+                      num_pages=32, n_engines=1,
+                      prefill_workers=0, prefill_chunk=chunk)
     eng.start()
     r = eng.submit(prompt, max_new=2)
     deadline = time.monotonic() + 120
@@ -272,8 +271,8 @@ def test_reroute_hands_queued_requests_to_decode():
     cfg = CFG_PLAIN
     params = init_params(cfg, jax.random.PRNGKey(16))
     eng = ServeEngine(cfg, params, max_batch=2, page_size=PAGE,
-                      num_pages=32, max_seq=32, n_engines=1,
-                      prefill_workers=1, prefill_chunk=4, kv_store="paged")
+                      num_pages=32, n_engines=1,
+                      prefill_workers=1, prefill_chunk=4)
     r = Request(1, [5, 3, 9], max_new=2)
     eng.scheduler.prefill_queue.put(r)
     eng.scheduler.reroute_prefill_queue()
@@ -289,8 +288,8 @@ def test_scheduler_routes_around_dead_prefill_stage():
     cfg = CFG_PLAIN
     params = init_params(cfg, jax.random.PRNGKey(11))
     eng = ServeEngine(cfg, params, max_batch=2, page_size=PAGE,
-                      num_pages=32, max_seq=32, n_engines=1,
-                      prefill_workers=1, prefill_chunk=4, kv_store="paged")
+                      num_pages=32, n_engines=1,
+                      prefill_workers=1, prefill_chunk=4)
     eng.start()
     try:
         eng.prefill_workers[0].error = RuntimeError("injected")
@@ -318,20 +317,18 @@ def _run(eng, prompts, max_new=3):
     return [list(r.out) for r in reqs]
 
 
-@pytest.mark.parametrize("kv_store", ["dense", "paged"])
-def test_async_prefill_token_parity(kv_store):
+@pytest.mark.parametrize("cfg", [CFG_PLAIN, CFG_FANCY], ids=lambda c: c.name)
+def test_async_prefill_token_parity(cfg):
     """prefill_workers=2 must be a scheduling change only: same tokens as
-    the inline-prefill engine, on both KV storage layers."""
-    cfg = CFG_PLAIN
+    the inline-prefill engine, config by config."""
     params = init_params(cfg, jax.random.PRNGKey(12))
     prompts = [[1, 9, 3, 5, 2], [7, 2, 8, 6, 4, 1, 3, 5], [11],
                [5, 3, 9, 1, 2, 6, 4, 8, 7, 2, 9]]
     outs = {}
     for n_pw in (0, 2):
         eng = ServeEngine(cfg, params, max_batch=4, page_size=PAGE,
-                          num_pages=64, max_seq=32, n_engines=1,
-                          prefill_workers=n_pw, prefill_chunk=3,
-                          kv_store=kv_store)
+                          num_pages=64, n_engines=1,
+                          prefill_workers=n_pw, prefill_chunk=3)
         outs[n_pw] = _run(eng, prompts)
         if n_pw:
             # prefill genuinely ran in the dedicated stage
@@ -350,9 +347,9 @@ def test_async_prefill_no_uaf_under_reclaim_policies(smr):
     params = init_params(cfg, jax.random.PRNGKey(13))
     pool = BlockPool(48, n_engines=4, reclaim_threshold=4,
                      pressure_factor=2, policy=make_policy(smr))
-    eng = ServeEngine(cfg, params, max_batch=4, page_size=PAGE, max_seq=32,
+    eng = ServeEngine(cfg, params, max_batch=4, page_size=PAGE,
                       pool=pool, n_engines=1, prefill_workers=2,
-                      prefill_chunk=2, prefix_cache=True, kv_store="paged")
+                      prefill_chunk=2, prefix_cache=True)
     eng.start()
     hot = [5, 3, 9, 1]
     reqs = [eng.submit(hot + [i + 1, i + 2], max_new=2) for i in range(6)]
@@ -373,8 +370,8 @@ def test_prefill_worker_ownership_handoff_is_leak_free():
     cfg = CFG_PLAIN
     params = init_params(cfg, jax.random.PRNGKey(14))
     eng = ServeEngine(cfg, params, max_batch=2, page_size=PAGE,
-                      num_pages=32, max_seq=32, n_engines=1,
-                      prefill_workers=1, prefill_chunk=4, kv_store="paged")
+                      num_pages=32, n_engines=1,
+                      prefill_workers=1, prefill_chunk=4)
     outs = _run(eng, [[1, 2, 3, 4, 5, 6], [9, 8, 7]])
     assert all(len(o) == 3 for o in outs)
     eng.pool.policy.flush()

@@ -30,7 +30,6 @@ import threading
 import pytest
 
 jax = pytest.importorskip("jax")
-jnp = jax.numpy
 
 try:
     from hypothesis import HealthCheck, given, settings
@@ -40,15 +39,16 @@ except ImportError:  # container without hypothesis: seeded driver only
     HAVE_HYPOTHESIS = False
 
 from repro.configs.base import ArchConfig, dense_stack
-from repro.models.model import apply_model, init_cache, init_params
+from repro.models.model import init_params
 from repro.serve.engine import ServeEngine
 from repro.serve.scheduler import SCHED_POLICIES, PrefillQueue
 from repro.serve.worker import Request
 
+from model_ref import GreedyReference
+
 CFG = ArchConfig(name="sched-props", d_model=32, n_heads=4, n_kv_heads=2,
                  d_ff=64, vocab=64, groups=dense_stack(2), remat="none",
                  dtype="float32")
-MAX_SEQ = 32
 MAX_NEW = 4
 
 
@@ -57,38 +57,11 @@ def params():
     return init_params(CFG, jax.random.PRNGKey(0))
 
 
-class Oracle:
-    """Reference tokens from a plain single-threaded decode loop -- the
-    same per-token forward the dense worker path runs, no scheduler, no
-    pool.  Memoized: the drivers draw overlapping prompt sets."""
-
-    def __init__(self, params):
-        self.params = params
-        self._decode = jax.jit(lambda p, c, t: apply_model(
-            p, t, cfg=CFG, mode="decode", cache=c))
-        self._memo = {}
-
-    def __call__(self, prompt):
-        prompt = tuple(prompt)
-        if prompt in self._memo:
-            return self._memo[prompt]
-        cache = init_cache(CFG, 1, MAX_SEQ, CFG.dtype)
-        toks = jnp.asarray([list(prompt)], jnp.int32)
-        for t in range(len(prompt)):
-            _, cache, _ = self._decode(self.params, cache, toks[:, t:t + 1])
-        out, last = [], prompt[-1]
-        for _ in range(MAX_NEW):
-            logits, cache, _ = self._decode(
-                self.params, cache, jnp.asarray([[last]], jnp.int32))
-            last = int(jnp.argmax(logits[0, -1]))
-            out.append(last)
-        self._memo[prompt] = tuple(out)
-        return self._memo[prompt]
-
-
 @pytest.fixture(scope="module")
 def oracle(params):
-    return Oracle(params)
+    """Reference tokens from a plain single-threaded greedy decode by the
+    reference forward -- no scheduler, no pool, no pages."""
+    return GreedyReference(params, CFG, max_new=MAX_NEW)
 
 
 # -- the property --
@@ -101,7 +74,7 @@ def check_perturbed_run(prompts, policy, crash, deadlines, params, oracle):
     and assert byte-identical outputs to the unperturbed oracle, exactly
     once per request, leak-free."""
     eng = ServeEngine(CFG, params, max_batch=2, page_size=4, num_pages=96,
-                      max_seq=MAX_SEQ, n_engines=2, prefill_workers=2,
+                      n_engines=2, prefill_workers=2,
                       prefill_chunk=4, sched_policy=policy,
                       preempt_prefill=True, migrate=True,
                       migrate_interval_s=0.005, migrate_threshold=1)
@@ -133,7 +106,7 @@ def check_perturbed_run(prompts, policy, crash, deadlines, params, oracle):
         # exactly-once: a double decode would overshoot max_new, a lost
         # handoff would undershoot or time out above
         assert len(r.out) == MAX_NEW, (r.rid, r.out)
-        assert tuple(r.out) == oracle(p), (r.rid, p, r.out, oracle(p))
+        assert list(r.out) == oracle(p), (r.rid, p, r.out, oracle(p))
     eng.pool.reclaim()
     assert eng.pool.check_no_leaks()
     assert eng.pool.stats.stale_handoffs == 0  # no pool-level crash here
@@ -237,7 +210,7 @@ def test_stop_finalizes_queued_partials_with_zero_prefill_workers(params):
     its blocks through finalize_request, not reach into
     prefill_workers[0]."""
     eng = ServeEngine(CFG, params, max_batch=2, page_size=4, num_pages=32,
-                      max_seq=MAX_SEQ, n_engines=1, prefill_workers=0)
+                      n_engines=1, prefill_workers=0)
     w = eng.workers[0]
     r = Request(1, [1, 2, 3, 4, 5], MAX_NEW)
     assert w._admit_blocks(r)          # engine 0 owns blocks now
@@ -254,7 +227,7 @@ def test_stop_releases_unadmitted_queued_requests(params):
     """Same seam, un-admitted request (no blocks yet): the waiter is still
     released and nothing leaks."""
     eng = ServeEngine(CFG, params, max_batch=2, page_size=4, num_pages=32,
-                      max_seq=MAX_SEQ, n_engines=1, prefill_workers=0)
+                      n_engines=1, prefill_workers=0)
     r = Request(7, [1, 2, 3], MAX_NEW)
     eng.scheduler.prefill_queue.put(r)
     eng.scheduler.stop()
@@ -266,7 +239,7 @@ def test_stop_unblocks_concurrent_waiter(params):
     """A client thread blocked in done.wait on a stranded request is
     released by stop() -- the shutdown contract clients rely on."""
     eng = ServeEngine(CFG, params, max_batch=2, page_size=4, num_pages=32,
-                      max_seq=MAX_SEQ, n_engines=1, prefill_workers=0)
+                      n_engines=1, prefill_workers=0)
     r = Request(9, [1, 2], MAX_NEW)
     eng.scheduler.prefill_queue.put(r)
     woke = threading.Event()

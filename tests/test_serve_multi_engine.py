@@ -214,7 +214,7 @@ def test_scheduler_rid_thread_safe_and_places_across_engines():
     `_rid += 1` data race fix), and placement must spread work across
     workers."""
     eng = ServeEngine(TINY, params=None, n_engines=2, num_pages=32,
-                      page_size=8, max_seq=64)   # never started: no decode
+                      page_size=8)   # never started: no decode
     rids = []
     lock = threading.Lock()
 

@@ -37,7 +37,7 @@ def test_train_checkpoint_restore_serve(trained):
 
     # serve the restored model through the paged engine + POP pool
     pool = BlockPool(64, n_engines=1, reclaim_threshold=4, pressure_factor=2)
-    eng = ServeEngine(TINY, params, max_batch=4, page_size=8, max_seq=64,
+    eng = ServeEngine(TINY, params, max_batch=4, page_size=8,
                       pool=pool)
     eng.start()
     reqs = [eng.submit([1 + i, 5, 9], max_new=6) for i in range(6)]
@@ -55,7 +55,7 @@ def test_serve_deterministic_greedy(trained):
     tr, out = trained
     params = out["params"]
     pool = BlockPool(32, n_engines=1, reclaim_threshold=4)
-    eng = ServeEngine(TINY, params, max_batch=2, page_size=8, max_seq=64,
+    eng = ServeEngine(TINY, params, max_batch=2, page_size=8,
                       pool=pool)
     eng.start()
     a = eng.submit([3, 7], max_new=5)
@@ -72,7 +72,7 @@ def test_serve_multi_engine_prefix_cache(trained):
     leave the pool leak-free after eviction + reclamation."""
     tr, out = trained
     params = out["params"]
-    eng = ServeEngine(TINY, params, max_batch=2, page_size=8, max_seq=64,
+    eng = ServeEngine(TINY, params, max_batch=2, page_size=8,
                       num_pages=64, n_engines=2, prefix_cache=True)
     eng.start()
     prefix = [2, 4, 6, 8, 1, 3, 5, 7]           # exactly one full page

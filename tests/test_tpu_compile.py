@@ -9,9 +9,9 @@ Interpret-mode tests cannot see any of that.
 Covered, at the published widths of two configs the paged path serves
 (bf16 pages, 16-token pages):
 
-* the paged-attention kernel and the paged token scatter at starcoder2-7b
-  (Hkv=4, D=128) and stablelm-12b (Hkv=8, D=160), the kernel also at
-  decode table widths 21 and 44;
+* the paged-attention kernel and the page write at starcoder2-7b (Hkv=4,
+  D=128) and stablelm-12b (Hkv=8, D=160), the kernel also at decode table
+  widths 21 and 44, the write donating the pool into its output;
 * the program between two kernel calls at both widths, whose donated
   page arrays must alias its outputs;
 * one whole paged decode step of starcoder2-7b cut to 16 layers, against
@@ -34,8 +34,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs.base import dense_stack  # noqa: E402
 from repro.configs.registry import get_config  # noqa: E402
-from repro.kernels.paged_attention import (paged_attention_pallas,  # noqa: E402
-                                           paged_scatter_pallas)
+from repro.kernels.paged_attention import paged_attention_pallas  # noqa: E402
 from repro.models.model import abstract_params  # noqa: E402
 from repro.runtime.kv_store import _set_rows, kv_layer_order  # noqa: E402
 from repro.serve.paged_model import (_mlp_qkv, _out_mlp, _qkv,  # noqa: E402
@@ -112,14 +111,18 @@ def test_paged_attention_compiles_at_decode_table_widths(one_chip, name, H,
 
 @pytest.mark.parametrize("name,H,Hkv,D", WIDTHS, ids=[w[0] for w in WIDTHS])
 def test_paged_scatter_compiles_for_v5e(one_chip, name, H, Hkv, D):
+    """The store's page write of one prefill chunk of tokens, jitted with
+    the pool donated as the store runs it: it compiles for the chip, and
+    the pool aliases its output, so the write is in place."""
     T = 64                                   # one prefill chunk of tokens
-    fn = jax.jit(paged_scatter_pallas, donate_argnums=(0,))
+    fn = jax.jit(_set_rows, donate_argnums=(0,))
     compiled = fn.lower(_sds((POOL_PAGES, PAGE, Hkv * D), "bfloat16",
                              one_chip),
                         _sds((T,), "int32", one_chip),
                         _sds((T,), "int32", one_chip),
                         _sds((T, Hkv, D), "bfloat16", one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        POOL_PAGES * PAGE * Hkv * D * 2
 
 
 @pytest.mark.parametrize("name,layers,parallel",
@@ -144,18 +147,14 @@ def test_fused_layer_program_aliases_its_pages_on_v5e(one_chip, name, layers,
         gp, i32(()), _sds((B, 1, cfg.d_model), cfg.dtype, one_chip),
         _sds((rows, H, hd), "float32", one_chip), gp, i32(()), i32((B, 1)),
         (page, page), i32((B,)), i32((B,)), cfg=cfg, rows=rows,
-        parallel=parallel, out_mlp=_out_mlp, qkv=_qkv,
-        write=_set_rows).compile()
+        parallel=parallel, out_mlp=_out_mlp, qkv=_qkv).compile()
     page_bytes = POOL_PAGES * PAGE * cfg.n_kv_heads * hd * 2
     assert compiled.memory_analysis().alias_size_in_bytes == 2 * page_bytes
 
 
 class _TracedPages:
     """The page-store surface ``paged_decode_step`` uses, over functional
-    page arrays, so the whole step traces into one program.  Writes go
-    through the device storage's own page write."""
-
-    storage = "device"
+    page arrays, so the whole step traces into one program."""
 
     def __init__(self, cfg, k, v):
         self.layer_order = kv_layer_order(cfg)
@@ -166,8 +165,8 @@ class _TracedPages:
         return contextlib.nullcontext()
 
     def write_in(self, layer, tokens, program):
-        self.k[layer], self.v[layer], out = program(
-            _set_rows, self.k[layer], self.v[layer])
+        self.k[layer], self.v[layer], out = program(self.k[layer],
+                                                    self.v[layer])
         return out
 
     def layer_pages(self, li):
